@@ -135,12 +135,11 @@ def axis_size(axis_name):
 
 
 def _vma(x):
-    """The varying-manual-axes set of a value/aval ({} on older jax)."""
-    try:
-        aval = x if hasattr(x, "vma") else jax.typeof(x)
-        return getattr(aval, "vma", frozenset())
-    except Exception:  # noqa: BLE001 — outside shard_map / old jax
-        return frozenset()
+    """The varying-manual-axes set of a value/aval. ``jax.eval_shape``
+    returns ``ShapeDtypeStruct``s whose ``vma`` is None outside shard_map:
+    that is the empty set."""
+    aval = x if hasattr(x, "vma") else jax.typeof(x)
+    return aval.vma or frozenset()
 
 
 def match_carry_vma(step_fn, carry, *xs_protos, fallback_axis=None):
@@ -152,16 +151,13 @@ def match_carry_vma(step_fn, carry, *xs_protos, fallback_axis=None):
     ``axis_index`` touch — and scan requires carry types to be identical
     across iterations. This runs ``jax.eval_shape`` on one abstract step
     (zero FLOPs) and ``lax.pcast``s each init leaf up to the vma the body
-    produces. No-op when the vma system is absent (older jax).
+    produces.
 
     If the abstract eval itself fails, falls back to promoting every leaf
     over ``fallback_axis`` (the caller's primary ring axis) — the carry is
     guaranteed to vary over at least that axis, and an unpromoted carry
     would only re-surface later as an opaque scan carry-type mismatch.
     """
-    if not (hasattr(jax, "typeof") and hasattr(lax, "pcast")):
-        return carry
-
     def up(leaf, aval):
         need = tuple(sorted(_vma(aval) - _vma(leaf)))
         return lax.pcast(leaf, need, to="varying") if need else leaf

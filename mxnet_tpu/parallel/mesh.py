@@ -73,22 +73,11 @@ def shard_batch(mesh: Mesh, axis: str = AxisNames.DP) -> NamedSharding:
 
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across the jax API moves (experimental -> top level,
-    check_rep -> check_vma). Replication checking is disabled: the compiled
+    """``jax.shard_map`` with replication checking disabled: the compiled
     train step mixes per-shard values (``axis_index``-folded RNG keys) with
-    psum'ed results, which the static rep checker over-rejects on some
-    versions."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    psum'ed results, which the static vma checker over-rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def shard_1d(mesh: Mesh, axis: str = AxisNames.DP) -> NamedSharding:

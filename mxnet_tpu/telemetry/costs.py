@@ -44,18 +44,13 @@ _PEAK_CACHE = (None, None)  # (env string at resolve time, peak or None)
 
 
 def _cost_dict(compiled):
-    """Normalize ``cost_analysis()`` across jax versions: may return a
-    dict, a list of one dict per computation, or None/raise when the
-    backend has no analysis."""
+    """``compiled.cost_analysis()`` (a dict), or None when the backend
+    has no analysis."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 — analysis is best-effort by contract
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    return ca
+    return ca if isinstance(ca, dict) else None
 
 
 def record_program_cost(site, compiled):

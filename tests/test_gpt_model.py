@@ -72,9 +72,15 @@ def test_gpt_generate_modes():
 
 def test_gpt_padding_mask_regression():
     """Pad tokens must be invisible: a right-padded prompt with
-    valid_length produces bitwise the same logits (at valid positions)
-    and the same greedy tokens as the unpadded prompt. The old window
-    loop LEFT-padded with no mask, so pads leaked into attention."""
+    valid_length produces the same logits (at valid positions) and the
+    same greedy tokens as the unpadded prompt. The old window loop
+    LEFT-padded with no mask, so pads leaked into attention.
+
+    The two forwards are separately compiled programs at T=5 and T=12:
+    the masked softmax weights are bit-equal, but XLA:CPU sums the P@V
+    contraction over 12 keys (7 of them exact zeros) in another order
+    than over 5, so the logits agree to an ulp, not bitwise. A leaked
+    pad would move them by ~1e-2."""
     mx.random.seed(4)
     net = gpt_tiny(vocab_size=40, dropout=0.0, num_layers=2, units=32,
                    num_heads=4, max_length=64)
@@ -85,7 +91,7 @@ def test_gpt_padding_mask_regression():
     padded[0, :5] = x[0]
     masked = net(np.array(padded),
                  np.array(onp.asarray([5], "int32"))).asnumpy()
-    assert onp.abs(masked[0, :5] - plain[0]).max() == 0.0
+    assert onp.abs(masked[0, :5] - plain[0]).max() < 1e-6
 
     # the windowed loop right-pads+masks internally: greedy tokens must
     # match the cached path, which never pads at all
