@@ -64,14 +64,7 @@ _T_ITERS = 12
 _T_NBYTES = 16 * 1024 * 1024
 
 
-def _pin_cpu_child():
-    from mxnet_tpu.context import pin_process_to_cpu
-
-    pin_process_to_cpu()
-
-
 def _shm_sender(q):
-    _pin_cpu_child()
     from mxnet_tpu.gluon.data.dataloader import _to_shm
 
     arr = onp.ones(_T_SHAPE, "float32")
@@ -83,7 +76,6 @@ def _shm_sender(q):
 
 
 def _pickle_sender(q):
-    _pin_cpu_child()
     arr = onp.ones(_T_SHAPE, "float32")
     for _ in range(_T_ITERS):
         q.put(arr)

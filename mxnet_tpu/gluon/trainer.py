@@ -118,8 +118,12 @@ class Trainer:
         sums gradients over G stacked microbatches before each update.
         ``MXTPU_MULTI_STEP`` overrides ``multi_step`` from the environment
         (``0`` disables). See docs/DESIGN.md "Multi-step execution"."""
+        from ..context import enable_compilation_cache
         from ..train_step import CompiledTrainStep
 
+        # the step program is the most expensive compile a trainer makes:
+        # persist it where Predictor and DecodeEngine persist theirs
+        enable_compilation_cache()
         self._compiled_step = CompiledTrainStep(
             self, net, loss_fn, mesh=mesh, loss_scaler=loss_scaler,
             shard_update=shard_update, strict_batch=strict_batch,

@@ -25,7 +25,7 @@ from __future__ import annotations
 import numpy as onp
 
 from ..base import MXNetError, canonical_dtype
-from ..context import Context, current_context, ensure_backend
+from ..context import Context, current_context
 from ..ops.registry import apply_op
 from .. import engine
 
@@ -67,7 +67,6 @@ class NDArray:
     def __init__(self, data):
         import jax
 
-        ensure_backend()  # first device touch goes through the safe probe
         if not isinstance(data, jax.Array):
             import jax.numpy as jnp
 

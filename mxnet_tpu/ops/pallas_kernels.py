@@ -73,10 +73,7 @@ def flash_block_k() -> int:
 def _on_tpu() -> bool:
     from ..context import _is_tpu_platform, default_backend
 
-    try:
-        return _is_tpu_platform(default_backend())
-    except RuntimeError:
-        return False
+    return _is_tpu_platform(default_backend())
 
 
 def _interpret() -> bool:

@@ -179,14 +179,13 @@ _tls = _TLS()
 # invoke() is THE per-op dispatch chokepoint; function-level from-imports
 # cost ~4 µs/call through importlib (measured ~25% of bare eager-dispatch
 # overhead), so the circular-import-safe modules are resolved once and
-# memoized — backend resolution (ensure_backend) rides the same first call
+# memoized
 _hot_mods: dict = {}
 
 
 def _hot():
     mods = _hot_mods.get("m")
     if mods is None:
-        from ..context import ensure_backend
         from ..ndarray.ndarray import NDArray
         from .. import autograd as ag
         from .. import _deferred_compute as dc
@@ -194,7 +193,6 @@ def _hot():
         from .. import engine
         from .. import telemetry
 
-        ensure_backend()
         mods = _hot_mods["m"] = (NDArray, ag, dc, _amp, engine, telemetry)
     return mods
 

@@ -502,14 +502,14 @@ class DecodePrograms:
 
     # ------------------------------------------------------------- manifests
     def manifest_dict(self, cache_dir=None, graphs=None):
-        from ...context import _probe_env_signature
+        from ...context import env_signature
 
         import jax
 
         return {
             "version": MANIFEST_VERSION,
             "kind": "decode_engine",
-            "env_signature": _probe_env_signature(),
+            "env_signature": env_signature(),
             "jax_version": getattr(jax, "__version__", "?"),
             "num_slots": self.num_slots,
             "max_len": self.max_len,

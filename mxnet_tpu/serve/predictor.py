@@ -16,9 +16,9 @@ north-star's "heavy traffic from millions of users" leg). Three layers:
   only awaited after N+1 is dispatched), so transfer overlaps compute —
   PyGraph's capture-and-replay amortization (arxiv 2503.19779) applied
   to serving.
-- **Persistent compilation**: ``context.enable_compilation_cache`` points
-  jax's on-disk compilation cache at a directory keyed by the
-  backend-probe environment signature, and ``warmup()`` precompiles
+- **Persistent compilation**: ``context.enable_compilation_cache`` turns
+  on jax's on-disk compilation cache (at ``JAX_COMPILATION_CACHE_DIR``,
+  else a fixed directory in the checkout), and ``warmup()`` precompiles
   every bucket (recording a manifest), so a fresh process restores
   steady-state latency — zero recompiles from the first request on.
 
@@ -105,8 +105,9 @@ class Predictor:
         ``submit()`` traffic before dispatching it anyway.
     cache_dir : str | None | False
         Persistent compilation cache directory. None (default) resolves
-        through ``context.compilation_cache_dir()`` (keyed by the
-        backend-probe env signature); False disables persistence.
+        through ``context.compilation_cache_dir()``; a path is used only
+        where ``JAX_COMPILATION_CACHE_DIR`` is not set; False disables
+        persistence.
     manifest : str, optional
         Path to a warmup manifest from a previous process: adopts its
         ladder/input specs and precompiles every bucket immediately
@@ -287,13 +288,13 @@ class Predictor:
         return manifest
 
     def _manifest_dict(self):
-        from ..context import _probe_env_signature
+        from ..context import env_signature
 
         import jax
 
         return {
             "version": 1,
-            "env_signature": _probe_env_signature(),
+            "env_signature": env_signature(),
             "jax_version": getattr(jax, "__version__", "?"),
             "max_batch": self.max_batch,
             "buckets": list(self.buckets),

@@ -19,7 +19,7 @@ Two tiers:
   ``tune.fallback_xla``).
 - **Versioned JSON file** (``save``/``preload``): lives next to the
   persistent XLA compile cache (``context.tuning_cache_path()``), keyed
-  by the backend-probe environment signature. A file written under a
+  by ``context.env_signature()``. A file written under a
   different signature, an unknown schema version, or a corrupt entry is
   skipped with a warning and re-tuned — stale winners are never replayed
   into a different environment. Production processes ``preload()`` at
@@ -126,7 +126,7 @@ def _load_locked():
     _state["path"] = path
     if not path or not os.path.exists(path):
         return
-    from ..context import _probe_env_signature
+    from ..context import env_signature
 
     try:
         with open(path) as fh:
@@ -147,7 +147,7 @@ def _load_locked():
             "winners are re-tuned, not replayed", RuntimeWarning,
             stacklevel=3)
         return
-    sig = _probe_env_signature()
+    sig = env_signature()
     if doc.get("env_signature") != sig:
         warnings.warn(
             f"kernel tuning cache {path} was written under a different "
@@ -272,7 +272,7 @@ def save(path=None):
     """Atomically write the in-process entries, merged over any valid
     entries already on disk (last writer's keys win). Returns the path,
     or None when persistence is disabled."""
-    from ..context import _probe_env_signature
+    from ..context import env_signature
 
     import jax
 
@@ -282,7 +282,7 @@ def save(path=None):
             path = _state["path"] or cache_path()
         if not path:
             return None
-        sig = _probe_env_signature()
+        sig = env_signature()
         entries = {}
         try:
             with open(path) as fh:

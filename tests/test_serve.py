@@ -1,6 +1,6 @@
 """Inference fast path (ISSUE 4): shape-bucketed dynamic batcher,
 AOT-compiled bucket programs, warmup manifest / export round-trip, the
-zero-steady-state-recompile contract, and the probe fail-fast satellite."""
+and the zero-steady-state-recompile contract."""
 import json
 import os
 import threading
@@ -318,16 +318,10 @@ def test_export_import_predictor_roundtrip(tmp_path):
         pred2.close()
 
 
-def test_compilation_cache_dir_keyed_and_populated(tmp_path, monkeypatch):
-    from mxnet_tpu import context as ctx
-
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path / "root"))
-    d = ctx.compilation_cache_dir()
-    assert d is not None and d.startswith(str(tmp_path / "root"))
-    assert os.path.basename(d) == ctx._probe_env_signature()
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", "off")
-    assert ctx.compilation_cache_dir() is None
-
+def test_warmup_populates_compilation_cache(tmp_path, monkeypatch):
+    # a cache placed from outside wins over cache_dir= (tests/
+    # test_device_selection.py); here the argument must be the one in use
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     net = _make_net()
     cache = str(tmp_path / "xla")
     pred = net.predictor(example=mx.nd.array(_rows(2)), max_batch=2,
@@ -339,38 +333,6 @@ def test_compilation_cache_dir_keyed_and_populated(tmp_path, monkeypatch):
     assert pred.cache_dir == cache
     # warmup's AOT compiles must land in the persistent on-disk cache
     assert any(os.scandir(cache)), "persistent compilation cache is empty"
-
-
-# -- probe fail-fast satellite ----------------------------------------------
-def test_probe_failure_verdict_outlives_success_ttl(tmp_path, monkeypatch):
-    """The bench re-paid the full probe timeout every run because success
-    and failure verdicts shared the short TTL; failure verdicts (which
-    only ever pin to CPU) must persist on the long fail TTL."""
-    from mxnet_tpu import context as ctx
-
-    monkeypatch.setattr(ctx, "_probe_cache_path",
-                        lambda: str(tmp_path / "probe.json"))
-    monkeypatch.setenv("MXTPU_PROBE_CACHE_TTL_S", "600")
-    monkeypatch.setenv("MXTPU_PROBE_FAIL_TTL_S", "86400")
-    sig = "deadbeefcafe0123"
-    ctx._store_cached_probe(sig, "cpu", error="probe timed out (test)")
-    entry = json.loads((tmp_path / "probe.json").read_text())[sig]
-    # age the verdict beyond the 600 s success window
-    entry["ts"] -= 3600
-    (tmp_path / "probe.json").write_text(json.dumps({sig: entry}))
-    got = ctx._load_cached_probe(sig)
-    assert got is not None and got["error"], \
-        "aged failure verdict was dropped — the bench would re-probe"
-    # a SUCCESS verdict of the same age is stale (runtime may have died)
-    ctx._store_cached_probe(sig, "tpu")
-    entry = json.loads((tmp_path / "probe.json").read_text())[sig]
-    entry["ts"] -= 3600
-    (tmp_path / "probe.json").write_text(json.dumps({sig: entry}))
-    assert ctx._load_cached_probe(sig) is None
-    # fail TTL 0 disables cached failures entirely
-    ctx._store_cached_probe(sig, "cpu", error="boom")
-    monkeypatch.setenv("MXTPU_PROBE_FAIL_TTL_S", "0")
-    assert ctx._load_cached_probe(sig) is None
 
 
 # -- bench smoke (mirrors test_telemetry_overhead_under_budget) -------------

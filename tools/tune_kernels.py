@@ -17,7 +17,7 @@ measure candidates online:
 On a CPU-only box pass --interpret to exercise the Pallas paths through
 the interpreter (mechanism check; block winners only transfer from real
 hardware). The cache lands at ``context.tuning_cache_path()`` (override:
-``MXTPU_TUNE_CACHE``), keyed by the backend-probe env signature — a
+``MXTPU_TUNE_CACHE``), keyed by ``context.env_signature()`` — a
 cache tuned under one environment is never replayed into another.
 
 Exit code 0 on success; prints one JSON line per tuned spec and a
