@@ -190,9 +190,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
         if use_seg:
             # tokens attend within their segment only (padding tokens get a
             # segment id of their own, so padded keys never contribute)
-            qs = qs_ref[:].reshape(block_q, 1)
-            ks = ks_ref[0, pl.ds(kb * block_k, block_k)].reshape(1, block_k)
-            seg_ok = qs == ks
+            ks = ks_ref[0, :, pl.ds(kb * block_k, block_k)]   # (1, BK)
+            seg_ok = qs_ref[0] == ks                          # (BQ, BK)
             ok = seg_ok if ok is None else (ok & seg_ok)
         if ok is not None:
             s = jnp.where(ok, s, _NEG_INF)
@@ -234,6 +233,8 @@ def _flash_attention_tpu(q, k, v, scale, causal, block_q, block_k,
                          return_lse=False, q_seg=None, k_seg=None):
     """q,k,v: (B, H, T, D) with T % block == 0, D % 128 == 0 (pre-padded).
     q_seg/k_seg: optional (B, T) int32 segment ids."""
+    if q_seg is not None:
+        q_seg, k_seg = _seg_columns_rows(q_seg, k_seg)
     b, h, tq, d = q.shape
     tk = k.shape[2]
     qr = q.reshape(b * h, tq, d)
@@ -256,12 +257,12 @@ def _flash_attention_tpu(q, k, v, scale, causal, block_q, block_k,
     if use_seg:
         # segment ids are per-batch; grid dim 0 runs over b*h fused heads
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda bh, qb: (bh // h, qb),
+            pl.BlockSpec((1, block_q, 1), lambda bh, qb: (bh // h, qb, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk), lambda bh, qb: (bh // h, 0),
+            pl.BlockSpec((1, 1, tk), lambda bh, qb: (bh // h, 0, 0),
                          memory_space=pltpu.VMEM),
         ]
-        operands += [q_seg.astype(jnp.int32), k_seg.astype(jnp.int32)]
+        operands += [q_seg, k_seg]
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, tq // block_q),
@@ -292,6 +293,17 @@ def _flash_attention_tpu(q, k, v, scale, causal, block_q, block_k,
     if return_lse:
         return out, lse.reshape(b, h, tq, 1)
     return out
+
+
+def _seg_columns_rows(q_seg, k_seg):
+    """(B, T) segment ids in the layout the kernels read: query ids as a
+    column (B, Tq, 1), key ids as a row (B, 1, Tk). Mosaic wants the last
+    two dims of a block divisible by (8, 128) or equal to the array's, so
+    a (1, block) slice of a (B, T) array is refused; here the size-1 dim
+    equals the array's and the blocked dim sits where q/k/lse blocks
+    already tile it. The kernels compare column == row with no relayout."""
+    return (q_seg.astype(jnp.int32)[:, :, None],
+            k_seg.astype(jnp.int32)[:, None, :])
 
 
 def _pad_to(x, axis, multiple):
@@ -556,9 +568,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             s = jnp.where(ok, s, _NEG_INF)
         p = jnp.exp(s - lse)                                  # normalized
         if use_seg:
-            qs = qs_ref[0, pl.ds(qb * block_q, block_q)].reshape(block_q, 1)
-            ks = ks_ref[:].reshape(1, block_k)
-            ok = (qs == ks) if ok is None else (ok & (qs == ks))
+            qs = qs_ref[0, pl.ds(qb * block_q, block_q), :]   # (BQ, 1)
+            seg_ok = qs == ks_ref[0]                          # (BQ, BK)
+            ok = seg_ok if ok is None else (ok & seg_ok)
         if ok is not None:
             # mask p under the COMBINED mask: for a fully masked row lse
             # was clamped, so exp(s - lse) is not reliably ~0 there
@@ -614,9 +626,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             s = jnp.where(ok, s, _NEG_INF)
         p = jnp.exp(s - lse)
         if use_seg:
-            qs = qs_ref[:].reshape(block_q, 1)
-            ks = ks_ref[0, pl.ds(kb * block_k, block_k)].reshape(1, block_k)
-            ok = (qs == ks) if ok is None else (ok & (qs == ks))
+            ks = ks_ref[0, :, pl.ds(kb * block_k, block_k)]   # (1, BK)
+            seg_ok = qs_ref[0] == ks                          # (BQ, BK)
+            ok = seg_ok if ok is None else (ok & seg_ok)
         if ok is not None:
             p = jnp.where(ok, p, 0.0)
         dp = jax.lax.dot_general(
@@ -646,8 +658,7 @@ def _flash_bwd_tpu(q, k, v, out, lse, g, scale, causal, block_q, block_k,
     off = tk - tq
     use_seg = q_seg is not None
     if use_seg:
-        q_seg = q_seg.astype(jnp.int32)
-        k_seg = k_seg.astype(jnp.int32)
+        q_seg, k_seg = _seg_columns_rows(q_seg, k_seg)
 
     full_q = pl.BlockSpec((1, tq, d), lambda bh, blk: (bh, 0, 0),
                           memory_space=pltpu.VMEM)
@@ -661,9 +672,9 @@ def _flash_bwd_tpu(q, k, v, out, lse, g, scale, causal, block_q, block_k,
     dkv_operands = [qr, kr, vr, gr, lser, delta]
     if use_seg:
         dkv_in_specs += [
-            pl.BlockSpec((1, tq), lambda bh, kb: (bh // h, 0),
+            pl.BlockSpec((1, tq, 1), lambda bh, kb: (bh // h, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda bh, kb: (bh // h, kb),
+            pl.BlockSpec((1, 1, block_k), lambda bh, kb: (bh // h, 0, kb),
                          memory_space=pltpu.VMEM),
         ]
         dkv_operands += [q_seg, k_seg]
@@ -710,9 +721,9 @@ def _flash_bwd_tpu(q, k, v, out, lse, g, scale, causal, block_q, block_k,
     dq_operands = [qr, kr, vr, gr, lser, delta]
     if use_seg:
         dq_in_specs += [
-            pl.BlockSpec((1, block_q), lambda bh, qb: (bh // h, qb),
+            pl.BlockSpec((1, block_q, 1), lambda bh, qb: (bh // h, qb, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk), lambda bh, qb: (bh // h, 0),
+            pl.BlockSpec((1, 1, tk), lambda bh, qb: (bh // h, 0, 0),
                          memory_space=pltpu.VMEM),
         ]
         dq_operands += [q_seg, k_seg]
