@@ -409,6 +409,11 @@ class DecodePrograms:
             [getattr(x, "_data", x) for x in examples])
         return prog
 
+    def compiled_programs(self):
+        """``{program key: jax Compiled}`` for every executable compiled so
+        far — the handle for ``as_text()`` / ``memory_analysis()``."""
+        return dict(self._programs)
+
     def _cop_key(self, key):
         if key[0] == "decode":
             return f"decode:{key[1]}"

@@ -20,8 +20,8 @@ import threading
 import time
 
 __all__ = ["record_program_cost", "program_costs", "flops_for",
-           "device_peak_flops", "peak_flops_info", "cost_report",
-           "reset_costs"]
+           "device_peak_flops", "peak_flops_info", "table_peak_flops",
+           "cost_report", "reset_costs"]
 
 # peak dense-bf16 FLOP/s per chip by device-kind substring (same numbers
 # bench.py has always used for its MFU line; CPU has no meaningful dense
@@ -116,17 +116,22 @@ def peak_flops_info():
         import jax
 
         kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — no backend yet / probe failure
+    except Exception:  # noqa: BLE001 — no backend yet
         return {"peak": None, "source": None}
+    peak = table_peak_flops(kind)
+    return {"peak": peak, "source": "device-table" if peak else None}
+
+
+def table_peak_flops(device_kind):
+    """Peak bf16 FLOP/s of ``device_kind`` from the table alone (no env
+    override), or None for a device the table does not know."""
     # longest-match so "TPU v5" does not shadow "TPU v5 lite"
     best = None
     for sub, peak in PEAK_BF16.items():
-        if sub.lower() in str(kind).lower():
+        if sub.lower() in str(device_kind).lower():
             if best is None or len(sub) > len(best[0]):
                 best = (sub, peak)
-    if best is None:
-        return {"peak": None, "source": None}
-    return {"peak": best[1], "source": "device-table"}
+    return best[1] if best else None
 
 
 def device_peak_flops():

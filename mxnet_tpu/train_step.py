@@ -656,6 +656,12 @@ class CompiledTrainStep:
         self._resolve_shard_params(shard_params)
         self._resolve_shard_update(shard_update)
 
+    def compiled_programs(self):
+        """``{input signature: jax Compiled}`` for every program dispatched
+        so far — the handle for ``as_text()`` / ``memory_analysis()``."""
+        return {sig: p.compiled for sig, p in self._cache.items()
+                if p.compiled is not None}
+
     # -- support matrix -----------------------------------------------------
     def _check_supported(self):
         tr = self.trainer
