@@ -28,21 +28,27 @@ device — exits non-zero and prints no result line. Timings printed on the
 way are smoke readings around ``block_until_ready``, not a benchmark.
 """
 import argparse
+import gc
 import json
 import os
 import sys
 import time
 
 REAL = dict(
-    model="gpt2_medium", vocab=50257, seq=1024, batch=4,
-    pad_lengths=(1024, 700, 333, 90),
+    # tracing a step runs its forward eagerly, and every activation of that
+    # forward stays on the device until the trace ends. With parameters and
+    # Adam state resident a 4-row trace peaked at 15.70 of 15.75 GiB (and a
+    # second one did not fit); two rows leave a third of the HBM free
+    model="gpt2_medium", vocab=50257, seq=1024, batch=2,
+    pad_lengths=(1024, 333),
     steps=4, pad_steps=2, lr=3e-4,
-    num_slots=8, max_len=1024, max_prompt_len=32, prefill_batch=2,
+    # 5 requests on 4 slots: the fifth joins when a slot retires
+    num_slots=4, max_len=1024, max_prompt_len=32, prefill_batch=2,
     prompt_lens=(5, 9, 17, 12, 30), new_tokens=8, window=64,
     mesh_steps=3, mesh_lr=1e-4)
 TINY = dict(
     model="gpt_tiny", vocab=512, seq=128, batch=4,
-    pad_lengths=(128, 90, 40, 11),
+    pad_lengths=(128, 40),
     steps=4, pad_steps=2, lr=3e-3,
     num_slots=4, max_len=128, max_prompt_len=32, prefill_batch=2,
     prompt_lens=(5, 9, 17, 12, 30), new_tokens=8, window=64,
@@ -119,20 +125,30 @@ def build_net(cfg, dropout=None):
 
 
 def token_batch(rs, cfg, lengths=None):
-    """(inputs, labels) int32 of shape (batch, seq): next-token pairs over
-    random tokens. With ``lengths`` the INPUT rows are right-padded with
-    the last id; the labels stay random, so nothing teaches the model to
-    answer with the pad id."""
+    """(inputs, labels) int32 of shape (rows, seq): next-token pairs over
+    random tokens, ``batch`` rows or one per entry of ``lengths``. With
+    ``lengths`` the INPUT rows are right-padded with the last id; the
+    labels stay random, so nothing teaches the model to answer with the
+    pad id."""
     import numpy as onp
 
     pad_id = cfg["vocab"] - 1
-    toks = rs.randint(0, pad_id, size=(cfg["batch"], cfg["seq"] + 1))
+    rows = cfg["batch"] if lengths is None else len(lengths)
+    toks = rs.randint(0, pad_id, size=(rows, cfg["seq"] + 1))
     x = onp.ascontiguousarray(toks[:, :-1], dtype="int32")
     y = onp.ascontiguousarray(toks[:, 1:], dtype="int32")
-    if lengths is not None:
-        for row, n in enumerate(lengths[:cfg["batch"]]):
-            x[row, n:] = pad_id
+    for row, n in enumerate(lengths or ()):
+        x[row, n:] = pad_id
     return x, y
+
+
+def hbm_in_use(label):
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    if "bytes_in_use" in stats:
+        say(f"{label}: HBM in use {stats['bytes_in_use'] / 2**30:.2f} GiB, "
+            f"peak so far {stats['peak_bytes_in_use'] / 2**30:.2f} GiB")
 
 
 def run_steps(step, mx, x, y, n, label):
@@ -213,6 +229,7 @@ def train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog):
     check(losses[-1] < losses[0],
           f"train: loss did not fall on a repeated batch: {losses}")
     report_step_programs(step, "train", on_tpu, hbm_limit)
+    hbm_in_use("train")
 
     # the padded batch reaches flash attention with segment ids
     padded = trainer.compile_step(PaddedLM(net, cfg["vocab"] - 1), loss_fn)
@@ -221,6 +238,7 @@ def train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog):
     check(plosses[-1] < plosses[0],
           f"train-padded: loss did not fall on a repeated batch: {plosses}")
     report_step_programs(padded, "train-padded", on_tpu, hbm_limit)
+    hbm_in_use("train-padded")
     say(f"train: jax built {clog.since(mark)}")
 
 
@@ -271,6 +289,7 @@ def serve_phase(cfg, net, mx, rs, on_tpu, clog):
               f"serve: compiles after warm-up moved by {moved} "
               "(framework traces, jax programs)")
         say("serve: compiles after warm-up: 0")
+        hbm_in_use("serve")
     finally:
         eng.close()
     matched = 0
@@ -290,8 +309,6 @@ def serve_phase(cfg, net, mx, rs, on_tpu, clog):
 
 def mesh_phase(cfg, devices, on_tpu, clog):
     """The dp2 x tp2 sharded step against the same steps on one device."""
-    import gc
-
     import numpy as onp
 
     from mxnet_tpu import gluon, telemetry
@@ -429,6 +446,10 @@ def main():
         rs = onp.random.RandomState(args.seed)
         net, _ = build_net(cfg)
         train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog)
+        # the trainer and its Adam state went out of scope with the phase;
+        # the engine's traces need the room (they too run eagerly)
+        gc.collect()
+        hbm_in_use("before serve")
         serve_phase(cfg, net, mx, rs, on_tpu, clog)
     for d in devices[:args.chips]:
         stats = d.memory_stats() or {}

@@ -10,8 +10,7 @@ when a site has been busy longer than ``p99_multiple`` x its running p99
 the absolute bound ``MXTPU_STALL_TIMEOUT_S``, whichever is tighter.
 
 Firing dumps every thread's stack plus the last telemetry step rows to
-stderr and the event log — the artifact the BENCH_r05/r06 TPU probe hang
-never produced — bumps ``telemetry.stalls``, and re-arms only after the
+stderr and the event log, bumps ``telemetry.stalls``, and re-arms only after the
 site completes (one report per stall episode, not one per poll).
 """
 from __future__ import annotations

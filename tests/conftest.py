@@ -5,12 +5,18 @@ Tests run on a virtual 8-device CPU mesh so multi-chip sharding paths are
 exercised without TPU hardware (the driver separately dry-runs them).
 """
 import os
+import tempfile
 
 # the suite runs on the CPU backend with eight virtual devices (Pallas
 # kernels, where a test turns them on, in interpret mode), whatever the
 # machine has; the chip is exercised by chip_smoke.py. Subprocesses that
 # tests spawn inherit the variable.
 os.environ["JAX_PLATFORMS"] = "cpu"
+# the trainer, Predictor and DecodeEngine persist what they compile; the
+# suite places that cache outside the checkout (a full run writes ~200 MB)
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                      os.path.join(tempfile.gettempdir(),
+                                   "mxtpu_tests_jax_cache"))
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags +

@@ -8,6 +8,12 @@ jax.distributed (coordinator = worker 0). This is the local recipe the
 distributed tests use (SURVEY §4: multi-node-without-cluster), and the same
 env contract a real multi-host TPU job uses (one process per host).
 
+It is for several hosts, or for CPU workers on one. A chip belongs to one
+process at a time, and on a host with four chips ONE process must own all
+four: N workers started here would each reach for the same chips and fail
+or hang. There, build a mesh over ``jax.devices()`` in a single process
+(``chip_smoke.py --chips 4`` does) and do not use this launcher.
+
 Env contract consumed by mxnet_tpu.kvstore:
     MXTPU_DIST_COORD  - coordinator address host:port
     MXTPU_DIST_NPROC  - number of processes
