@@ -17,7 +17,8 @@ vocabulary 50257, context 1024; random weights from ``--seed``):
 
     python chip_smoke.py                  # one TPU chip; the driver's call
     python chip_smoke.py --chips 4        # ONLY the dp2 x tp2 sharded step
-                                          #   and its one-device comparison
+                                          #   and its one-device comparison,
+                                          #   at full width and cut depth
     python chip_smoke.py --cpu-rehearsal  # sandbox: gpt_tiny on the CPU,
                                           #   Pallas interpreted; never "ok"
 
@@ -45,14 +46,17 @@ REAL = dict(
     # 5 requests on 4 slots: the fifth joins when a slot retires
     num_slots=4, max_len=1024, max_prompt_len=32, prefill_batch=2,
     prompt_lens=(5, 9, 17, 12, 30), new_tokens=8, window=64,
-    mesh_steps=3, mesh_lr=1e-4)
+    # --chips 4 cuts DEPTH, not width: XLA needs ~20 minutes to compile the
+    # dp2 x tp2 FSDP step for a 2x2 v5e whatever the depth (2 layers: 1185 s,
+    # 4 layers: 1339 s in the sandbox), and every extra layer adds to it
+    mesh_layers=2, mesh_steps=3, mesh_lr=1e-4)
 TINY = dict(
     model="gpt_tiny", vocab=512, seq=128, batch=4,
     pad_lengths=(128, 40),
     steps=4, pad_steps=2, lr=3e-3,
     num_slots=4, max_len=128, max_prompt_len=32, prefill_batch=2,
     prompt_lens=(5, 9, 17, 12, 30), new_tokens=8, window=64,
-    mesh_steps=3, mesh_lr=1e-3)
+    mesh_layers=2, mesh_steps=3, mesh_lr=1e-3)
 
 
 class SmokeFailure(Exception):
@@ -98,13 +102,17 @@ class CompileLog:
                 "compile-or-load")
 
 
-def build_net(cfg, dropout=None):
+def build_net(cfg, dropout=None, layers=None):
+    """The model at its published size; ``layers`` cuts depth only."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon import model_zoo
 
     kw = {} if dropout is None else {"dropout": dropout}
-    if cfg["model"] == "gpt2_medium":
+    if cfg["model"] == "gpt2_medium" and layers is None:
         net = model_zoo.gpt2_medium(**kw)
+    elif cfg["model"] == "gpt2_medium":
+        net = model_zoo.GPTModel(vocab_size=cfg["vocab"], num_layers=layers,
+                                 units=1024, num_heads=16, **kw)
     else:
         # rehearsal: head_dim 64 and a 128-wide LayerNorm, so the same
         # kernels are reached (interpreted) as on the chip
@@ -119,7 +127,7 @@ def build_net(cfg, dropout=None):
         f"{net._dtype}")
     if cfg["model"] == "gpt2_medium":
         check((net._num_layers, net._units, net._num_heads, net.vocab_size,
-               net.max_length) == (24, 1024, 16, 50257, 1024),
+               net.max_length) == (layers or 24, 1024, 16, 50257, 1024),
               "gpt2_medium is not at its published size")
     return net, mx
 
@@ -327,7 +335,7 @@ def mesh_phase(cfg, devices, on_tpu, clog):
         import mxnet_tpu as mx
 
         mx.random.seed(cfg["seed"])
-        net, _ = build_net(cfg, dropout=0.0)
+        net, _ = build_net(cfg, dropout=0.0, layers=cfg["mesh_layers"])
         trainer = gluon.Trainer(net.collect_params(), "adam",
                                 {"learning_rate": cfg["mesh_lr"]})
         if mesh is None:
