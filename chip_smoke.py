@@ -104,7 +104,6 @@ class CompileLog:
 
 def build_net(cfg, dropout=None, layers=None):
     """The model at its published size; ``layers`` cuts depth only."""
-    import mxnet_tpu as mx
     from mxnet_tpu.gluon import model_zoo
 
     kw = {} if dropout is None else {"dropout": dropout}
@@ -129,7 +128,7 @@ def build_net(cfg, dropout=None, layers=None):
         check((net._num_layers, net._units, net._num_heads, net.vocab_size,
                net.max_length) == (layers or 24, 1024, 16, 50257, 1024),
               "gpt2_medium is not at its published size")
-    return net, mx
+    return net
 
 
 def token_batch(rs, cfg, lengths=None):
@@ -159,9 +158,11 @@ def hbm_in_use(label):
             f"peak so far {stats['peak_bytes_in_use'] / 2**30:.2f} GiB")
 
 
-def run_steps(step, mx, x, y, n, label):
+def run_steps(step, x, y, n, label):
     """n optimizer steps on one repeated batch; returns the losses."""
     import numpy as onp
+
+    import mxnet_tpu as mx
 
     xs, ys = mx.np.array(x), mx.np.array(y)
     losses, times = [], []
@@ -211,7 +212,7 @@ def report_step_programs(step, label, on_tpu, hbm_limit):
                   f"{hbm_limit}")
 
 
-def train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog):
+def train_phase(cfg, net, rs, on_tpu, hbm_limit, clog):
     from mxnet_tpu import gluon
 
     class PaddedLM(gluon.HybridBlock):
@@ -233,7 +234,7 @@ def train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog):
     mark = clog.mark()
     step = trainer.compile_step(net, loss_fn)
     x, y = token_batch(rs, cfg)
-    losses = run_steps(step, mx, x, y, cfg["steps"], "train")
+    losses = run_steps(step, x, y, cfg["steps"], "train")
     check(losses[-1] < losses[0],
           f"train: loss did not fall on a repeated batch: {losses}")
     report_step_programs(step, "train", on_tpu, hbm_limit)
@@ -242,7 +243,7 @@ def train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog):
     # the padded batch reaches flash attention with segment ids
     padded = trainer.compile_step(PaddedLM(net, cfg["vocab"] - 1), loss_fn)
     xp, yp = token_batch(rs, cfg, cfg["pad_lengths"])
-    plosses = run_steps(padded, mx, xp, yp, cfg["pad_steps"], "train-padded")
+    plosses = run_steps(padded, xp, yp, cfg["pad_steps"], "train-padded")
     check(plosses[-1] < plosses[0],
           f"train-padded: loss did not fall on a repeated batch: {plosses}")
     report_step_programs(padded, "train-padded", on_tpu, hbm_limit)
@@ -250,7 +251,7 @@ def train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog):
     say(f"train: jax built {clog.since(mark)}")
 
 
-def serve_phase(cfg, net, mx, rs, on_tpu, clog):
+def serve_phase(cfg, net, rs, on_tpu, clog):
     from mxnet_tpu import telemetry
     from mxnet_tpu.serve import DecodeEngine
 
@@ -319,6 +320,7 @@ def mesh_phase(cfg, devices, on_tpu, clog):
     """The dp2 x tp2 sharded step against the same steps on one device."""
     import numpy as onp
 
+    import mxnet_tpu as mx
     from mxnet_tpu import gluon, telemetry
     from mxnet_tpu.gluon.model_zoo.gpt import gpt_tp_rules
     from mxnet_tpu.parallel import make_mesh
@@ -332,10 +334,8 @@ def mesh_phase(cfg, devices, on_tpu, clog):
                 for d in devices]
 
     def run(mesh):
-        import mxnet_tpu as mx
-
         mx.random.seed(cfg["seed"])
-        net, _ = build_net(cfg, dropout=0.0, layers=cfg["mesh_layers"])
+        net = build_net(cfg, dropout=0.0, layers=cfg["mesh_layers"])
         trainer = gluon.Trainer(net.collect_params(), "adam",
                                 {"learning_rate": cfg["mesh_lr"]})
         if mesh is None:
@@ -346,7 +346,7 @@ def mesh_phase(cfg, devices, on_tpu, clog):
                 net, loss_fn, mesh=mesh, shard_params=True,
                 partition_rules=gpt_tp_rules("train"))
             label = "dp2xtp2"
-        losses = run_steps(step, mx, x, y, cfg["mesh_steps"], label)
+        losses = run_steps(step, x, y, cfg["mesh_steps"], label)
         return step, losses
 
     base = bytes_in_use()
@@ -452,13 +452,13 @@ def main():
     else:
         mx.random.seed(args.seed)
         rs = onp.random.RandomState(args.seed)
-        net, _ = build_net(cfg)
-        train_phase(cfg, net, mx, rs, on_tpu, hbm_limit, clog)
+        net = build_net(cfg)
+        train_phase(cfg, net, rs, on_tpu, hbm_limit, clog)
         # the trainer and its Adam state went out of scope with the phase;
         # the engine's traces need the room (they too run eagerly)
         gc.collect()
         hbm_in_use("before serve")
-        serve_phase(cfg, net, mx, rs, on_tpu, clog)
+        serve_phase(cfg, net, rs, on_tpu, clog)
     for d in devices[:args.chips]:
         stats = d.memory_stats() or {}
         say(f"device {d.id}: peak_bytes_in_use "

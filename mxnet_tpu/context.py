@@ -13,6 +13,7 @@ src/initialize.cc:71-97 for the class of bug this avoids).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 
 from .base import MXNetError
@@ -176,36 +177,28 @@ def env_signature() -> str:
     return hashlib.sha256("\0".join(parts).encode()).hexdigest()[:16]
 
 
+@contextlib.contextmanager
 def spawn_cpu_pinned_env():
     """Context manager setting ``JAX_PLATFORMS=cpu`` around
     ``Process.start()``: spawned children inherit the environment at exec
     time, so they come up on the CPU and never reach for the chip the
     parent holds. DataLoader and the benches use it."""
-    import contextlib
     import os
 
-    @contextlib.contextmanager
-    def _cm():
-        saved = os.environ.get("JAX_PLATFORMS")
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        try:
-            yield
-        finally:
-            if saved is None:
-                os.environ.pop("JAX_PLATFORMS", None)
-            else:
-                os.environ["JAX_PLATFORMS"] = saved
-
-    return _cm()
-
-
-def _is_tpu_platform(name: str) -> bool:
-    return name == "tpu"
+    saved = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if saved is None:
+            os.environ.pop("JAX_PLATFORMS", None)
+        else:
+            os.environ["JAX_PLATFORMS"] = saved
 
 
 def default_context() -> Context:
     """The default device: TPU if the runtime has one, else CPU."""
-    return tpu(0) if _is_tpu_platform(default_backend()) else cpu(0)
+    return tpu(0) if default_backend() == "tpu" else cpu(0)
 
 
 def current_context() -> Context:
@@ -315,10 +308,7 @@ def enable_compilation_cache(path=None):
     import jax
 
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if placed:
-        path = placed
-    elif path is None:
-        path = compilation_cache_dir()
+    path = placed or path or compilation_cache_dir()
     if _compile_cache_state["enabled"] and \
             _compile_cache_state["dir"] == path:
         return path

@@ -71,9 +71,9 @@ def flash_block_k() -> int:
 
 
 def _on_tpu() -> bool:
-    from ..context import _is_tpu_platform, default_backend
+    from ..context import default_backend
 
-    return _is_tpu_platform(default_backend())
+    return default_backend() == "tpu"
 
 
 def _interpret() -> bool:

@@ -30,12 +30,12 @@ class PallasKernel:
         datas = [a._data if isinstance(a, NDArray) else a for a in args]
         out_shape = [jax.ShapeDtypeStruct(s, d)
                      for s, d in zip(self._out_shapes, self._out_dtypes)]
-        from .context import _is_tpu_platform, default_backend
+        from .context import default_backend
 
         out = pl.pallas_call(
             self._fn,
             out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
-            interpret=not _is_tpu_platform(default_backend()),
+            interpret=default_backend() != "tpu",
         )(*datas)
         if isinstance(out, (tuple, list)):
             return tuple(NDArray(o) for o in out)

@@ -15,10 +15,10 @@ class Feature:
 
 
 def _detect():
-    from .context import _is_tpu_platform, default_backend
+    from .context import default_backend
 
     feats = {
-        "TPU": _is_tpu_platform(default_backend()),
+        "TPU": default_backend() == "tpu",
         "XLA": True,
         "PJRT": True,
         "PALLAS": True,

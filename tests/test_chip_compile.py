@@ -72,35 +72,23 @@ def _lse(chip):
     return jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32, sharding=chip)
 
 
-@pytest.mark.parametrize("seg", [False, True], ids=["plain", "segments"])
-def test_flash_forward_compiles(chip_kernels, seg):
-    q = _qkv(chip_kernels)
-    if seg:
-        n = _compiled_kernels(
-            lambda q, k, v, a, b: pk._flash_attention_tpu(
-                q, k, v, SCALE, True, BQ, BK, q_seg=a, k_seg=b),
-            q, q, q, _seg(chip_kernels), _seg(chip_kernels))
-    else:
-        n = _compiled_kernels(
-            lambda q, k, v: pk._flash_attention_tpu(
-                q, k, v, SCALE, True, BQ, BK), q, q, q)
-    assert n == 1
+def _seg_operands(chip, seg):
+    """Two (B, T) id operands for the segment variant, none for the plain."""
+    return (_seg(chip), _seg(chip)) if seg else ()
 
 
+def _seg_kw(ids):
+    return {"q_seg": ids[0], "k_seg": ids[1]} if ids else {}
+
+
+@pytest.mark.parametrize("lse", [False, True], ids=["out", "out_and_lse"])
 @pytest.mark.parametrize("seg", [False, True], ids=["plain", "segments"])
-def test_flash_forward_with_lse_compiles(chip_kernels, seg):
+def test_flash_forward_compiles(chip_kernels, seg, lse):
     q = _qkv(chip_kernels)
-    if seg:
-        n = _compiled_kernels(
-            lambda q, k, v, a, b: pk._flash_attention_tpu(
-                q, k, v, SCALE, True, BQ, BK, return_lse=True,
-                q_seg=a, k_seg=b),
-            q, q, q, _seg(chip_kernels), _seg(chip_kernels))
-    else:
-        n = _compiled_kernels(
-            lambda q, k, v: pk._flash_attention_tpu(
-                q, k, v, SCALE, True, BQ, BK, return_lse=True), q, q, q)
-    assert n == 1
+    assert _compiled_kernels(
+        lambda q, k, v, *ids: pk._flash_attention_tpu(
+            q, k, v, SCALE, True, BQ, BK, return_lse=lse, **_seg_kw(ids)),
+        q, q, q, *_seg_operands(chip_kernels, seg)) == 1
 
 
 @pytest.mark.parametrize("which", ["dq", "dkv"])
@@ -111,18 +99,11 @@ def test_flash_backward_compiles(chip_kernels, seg, which):
     q = _qkv(chip_kernels)
     pick = (lambda dq, dk, dv: dq) if which == "dq" \
         else (lambda dq, dk, dv: (dk, dv))
-    if seg:
-        n = _compiled_kernels(
-            lambda q, k, v, o, l, g, a, b: pick(*pk._flash_bwd_tpu(
-                q, k, v, o, l, g, SCALE, True, BQ, BK, q_seg=a, k_seg=b)),
-            q, q, q, q, _lse(chip_kernels), q,
-            _seg(chip_kernels), _seg(chip_kernels))
-    else:
-        n = _compiled_kernels(
-            lambda q, k, v, o, l, g: pick(*pk._flash_bwd_tpu(
-                q, k, v, o, l, g, SCALE, True, BQ, BK)),
-            q, q, q, q, _lse(chip_kernels), q)
-    assert n == 1
+    assert _compiled_kernels(
+        lambda q, k, v, o, l, g, *ids: pick(*pk._flash_bwd_tpu(
+            q, k, v, o, l, g, SCALE, True, BQ, BK, **_seg_kw(ids))),
+        q, q, q, q, _lse(chip_kernels), q,
+        *_seg_operands(chip_kernels, seg)) == 1
 
 
 def test_ragged_segments_take_the_padded_path(chip_kernels):
