@@ -46,9 +46,10 @@ REAL = dict(
     # 5 requests on 4 slots: the fifth joins when a slot retires
     num_slots=4, max_len=1024, max_prompt_len=32, prefill_batch=2,
     prompt_lens=(5, 9, 17, 12, 30), new_tokens=8, window=64,
-    # --chips 4 cuts DEPTH, not width: XLA needs ~20 minutes to compile the
-    # dp2 x tp2 FSDP step for a 2x2 v5e whatever the depth (2 layers: 1185 s,
-    # 4 layers: 1339 s in the sandbox), and every extra layer adds to it
+    # --chips 4 cuts DEPTH, not width, unless --mesh-layers 24 lifts the
+    # cut: the dp2 x tp2 FSDP step compiles slowly for four chips (2 layers:
+    # 217 s on the four-chip host, 1185 s in the sandbox; 24 layers on one
+    # chip: 150 s), and four chips are charged four times over
     mesh_layers=2, mesh_steps=3, mesh_lr=1e-4)
 TINY = dict(
     model="gpt_tiny", vocab=512, seq=128, batch=4,
@@ -116,7 +117,7 @@ def build_net(cfg, dropout=None, layers=None):
         # rehearsal: head_dim 64 and a 128-wide LayerNorm, so the same
         # kernels are reached (interpreted) as on the chip
         net = model_zoo.gpt_tiny(vocab_size=cfg["vocab"], units=128,
-                                 num_heads=2, num_layers=2,
+                                 num_heads=2, num_layers=layers or 2,
                                  max_length=cfg["seq"], **kw)
     net.initialize()
     n_params = sum(int(p.data().size) for p in net.collect_params().values())
@@ -395,6 +396,9 @@ def main():
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the dp2 x tp2 sharded step and its "
                          "one-device comparison")
+    ap.add_argument("--mesh-layers", type=int, default=None,
+                    help="depth of the --chips 4 model (default 2; 24 is "
+                         "GPT-2 medium's own)")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="sandbox rehearsal on the CPU at gpt_tiny with "
                          "Pallas interpreted; never prints ok: true")
@@ -445,6 +449,8 @@ def main():
     hbm_limit = (devices[0].memory_stats() or {}).get("bytes_limit")
 
     cfg = dict(TINY if args.cpu_rehearsal else REAL, seed=args.seed)
+    if args.mesh_layers:
+        cfg["mesh_layers"] = args.mesh_layers
     clog = CompileLog()
     if args.chips == 4:
         mesh_phase(cfg, devices[:4], on_tpu, clog)
