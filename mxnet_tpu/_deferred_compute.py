@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import weakref
 
 from .base import MXNetError
 from .symbol.symbol import SymNode, Literal
@@ -31,7 +32,11 @@ class _TraceCtx:
     def __init__(self):
         self.uses_rng = False
         self.aux_updates = []  # [(target NDArray, source entry)]
-        self.marked = []       # arrays whose _dc_sym we set (for cleanup)
+        # weak refs to the arrays whose _dc_sym we set (for cleanup). Weak,
+        # so the trace does not keep every intermediate of the eager
+        # forward alive on the device until it ends (on a 16 GB chip that
+        # bounded the traceable batch below what the compiled step runs)
+        self.marked = []
 
 
 class _State(threading.local):
@@ -61,8 +66,10 @@ def context():
     try:
         yield _state.ctx
     finally:
-        for arr in _state.ctx.marked:
-            arr._dc_sym = None
+        for ref in _state.ctx.marked:
+            arr = ref()
+            if arr is not None:
+                arr._dc_sym = None
         _state.ctx = None
 
 
@@ -85,7 +92,7 @@ def set_variable(arr, name: str) -> SymNode:
     node = SymNode(name=name,
                    attr_dict={"__shape__": str(tuple(arr.shape))})
     arr._dc_sym = (node, 0)
-    ctx.marked.append(arr)
+    ctx.marked.append(weakref.ref(arr))
     return node
 
 
@@ -108,7 +115,7 @@ def _record_op(op, attrs, inputs, outputs) -> None:
             if x._dc_sym is None:
                 # constant capture: array not marked as input -> bake value
                 x._dc_sym = (SymNode(value=x._data), 0)
-                ctx.marked.append(x)
+                ctx.marked.append(weakref.ref(x))
             entries.append(x._dc_sym)
         else:
             entries.append(Literal(x))
@@ -117,4 +124,4 @@ def _record_op(op, attrs, inputs, outputs) -> None:
     node = SymNode(op=op, attrs=attrs, inputs=entries, nout=len(outputs))
     for i, o in enumerate(outputs):
         o._dc_sym = (node, i)
-        ctx.marked.append(o)
+        ctx.marked.append(weakref.ref(o))

@@ -194,3 +194,25 @@ def test_np_random_fresh_under_hybridize():
     n3.hybridize()
     d = n3(mx.np.ones((2, 3))).asnumpy()
     assert (c == d).all()
+
+
+def test_trace_does_not_keep_intermediates_alive():
+    """The trace runs the forward eagerly at real size; an intermediate the
+    forward has dropped must die mid-trace, or a trace holds a whole
+    forward's activations on the device (found on a 16 GB chip)."""
+    import gc
+    import weakref
+
+    from mxnet_tpu import _deferred_compute as dc
+
+    x = np.ones((4, 4))
+    with dc.context():
+        dc.set_variable(x, "data0")
+        h = x * 2
+        gone = weakref.ref(h)
+        y = h + 1
+        del h
+        gc.collect()
+        assert gone() is None
+        assert y._dc_sym is not None
+    assert y._dc_sym is None and x._dc_sym is None
