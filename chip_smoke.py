@@ -36,10 +36,9 @@ import sys
 import time
 
 REAL = dict(
-    # tracing a step runs its forward eagerly, and every activation of that
-    # forward stays on the device until the trace ends. With parameters and
-    # Adam state resident a 4-row trace peaked at 15.70 of 15.75 GiB (and a
-    # second one did not fit); two rows leave a third of the HBM free
+    # 4 x 1024 also ran (0.20 s/step) but peaked at 15.70 of 15.75 GiB while
+    # traces still held every activation of their eager forward; at two
+    # rows the whole run peaks at 9.4 GiB
     model="gpt2_medium", vocab=50257, seq=1024, batch=2,
     pad_lengths=(1024, 333),
     steps=4, pad_steps=2, lr=3e-4,
@@ -443,9 +442,17 @@ def main():
               f"device kind {device['kind']!r} is not in "
               "telemetry.costs.PEAK_BF16")
     cache_dir = mx.context.enable_compilation_cache()
-    entries = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
-    say(f"compile cache at {cache_dir}: {entries} entries at start "
-        f"({'placed by JAX_COMPILATION_CACHE_DIR' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'in-checkout default'})")
+    files = [os.path.join(cache_dir, f) for f in os.listdir(cache_dir)] \
+        if os.path.isdir(cache_dir) else []
+    placed = "placed by JAX_COMPILATION_CACHE_DIR" \
+        if os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        else "in-checkout default"
+    # a size cap makes jax evict least-recently-used entries: this model's
+    # programs are large enough for one run to evict its own earlier ones
+    say(f"compile cache at {cache_dir} ({placed}): {len(files)} files, "
+        f"{sum(map(os.path.getsize, files)) / 2**20:.1f} MiB at start; "
+        f"size cap {jax.config.jax_compilation_cache_max_size} bytes "
+        "(-1: none)")
     hbm_limit = (devices[0].memory_stats() or {}).get("bytes_limit")
 
     cfg = dict(TINY if args.cpu_rehearsal else REAL, seed=args.seed)
