@@ -95,7 +95,7 @@ class CachedOp:
         # of this program (a new input signature) reports one compile
         self._jitted = jax.jit(_observe_compiles(fn, f"cached_op:{name}",
                                                  None))
-        self._donated_jits = {}  # donate_argnums tuple -> observed jit
+        self._donated_jits = {}  # (donate_argnums, name) -> observed jit
         self._telemetry = _telemetry
         self._uses_rng = uses_rng
         # wrap as a registered-op-shaped object so registry.invoke records it
@@ -126,7 +126,7 @@ class CachedOp:
             target._set_data(new._data)
         return main[0] if self._n_main == 1 else main
 
-    def lower(self, *example_inputs, donate=()):
+    def lower(self, *example_inputs, donate=(), name=None):
         """AOT-lower the program at the example signature (jax Lowered).
 
         The compiled program's leading argument for RNG graphs is the
@@ -139,30 +139,34 @@ class CachedOp:
         donate_argnums — the serve/decode KV-cache update contract:
         cache in, cache out, no second residency). Indices are in
         example-input space; the RNG-key offset is applied internally.
+
+        ``name``: the compiled module reads ``jit_<name>`` in a profiler
+        trace instead of ``jit_observed``.
         """
         datas = [getattr(x, "_data", x) for x in example_inputs]
         if self._uses_rng:
             datas.insert(0, jax.random.PRNGKey(0))
-        if not donate:
+        if not donate and not name:
             return self._jitted.lower(*datas)
         off = 1 if self._uses_rng else 0
         argnums = tuple(sorted(int(i) + off for i in donate))
-        jitted = self._donated_jits.get(argnums)
+        jitted = self._donated_jits.get((argnums, name))
         if jitted is None:
             from .ops.registry import _observe_compiles
 
             jitted = jax.jit(
                 _observe_compiles(self._raw_fn,
-                                  f"cached_op:{self._name}", None),
+                                  f"cached_op:{self._name}", None,
+                                  name=name),
                 donate_argnums=argnums)
-            self._donated_jits[argnums] = jitted
+            self._donated_jits[(argnums, name)] = jitted
         return jitted.lower(*datas)
 
     def lower_hlo(self, *example_inputs):
         """Return the StableHLO text for given example inputs (debugging)."""
         return self.lower(*example_inputs).as_text()
 
-    def aot_compile(self, *example_inputs, donate=()):
+    def aot_compile(self, *example_inputs, donate=(), name=None):
         """Ahead-of-time compile at the example signature; returns the
         executable (jax Compiled).
 
@@ -175,9 +179,10 @@ class CachedOp:
         a disk hit on every process after the first. ``donate`` marks
         example-input indices whose buffers the program may consume
         (see ``lower``); callers must rebind those arrays to the
-        program's outputs after every call.
+        program's outputs after every call. ``name``: see ``lower``.
         """
-        compiled = self.lower(*example_inputs, donate=donate).compile()
+        compiled = self.lower(*example_inputs, donate=donate,
+                              name=name).compile()
         from . import telemetry as _tm
 
         _tm.record_program_cost(f"cached_op:{self._name}", compiled)

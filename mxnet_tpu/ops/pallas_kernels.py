@@ -265,6 +265,7 @@ def _flash_attention_tpu(q, k, v, scale, causal, block_q, block_k,
         operands += [q_seg, k_seg]
     out, lse = pl.pallas_call(
         kernel,
+        name="mxtpu_flash_fwd",
         grid=(b * h, tq // block_q),
         in_specs=in_specs,
         out_specs=[
@@ -682,6 +683,7 @@ def _flash_bwd_tpu(q, k, v, out, lse, g, scale, causal, block_q, block_k,
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_q=tq,
                           causal_offset=off, use_seg=use_seg),
+        name="mxtpu_flash_bwd_dkv",
         grid=(b * h, tk // block_k),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -731,6 +733,7 @@ def _flash_bwd_tpu(q, k, v, out, lse, g, scale, causal, block_q, block_k,
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=tk,
                           causal_offset=off, use_seg=use_seg),
+        name="mxtpu_flash_bwd_dq",
         grid=(b * h, tq // block_q),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qb: (bh, qb, 0),
@@ -944,6 +947,7 @@ def _fused_ln(x, gamma, beta, eps, block_rows):
     xr, rows = _pad_rows(x.reshape(rows, d), br)
     out = pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
+        name="mxtpu_layer_norm",
         grid=(xr.shape[0] // br,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0),
@@ -1012,6 +1016,7 @@ def _fused_softmax_impl(x, block_rows):
     xr, rows = _pad_rows(x.reshape(rows, d), br)
     out = pl.pallas_call(
         _softmax_kernel,
+        name="mxtpu_softmax",
         grid=(xr.shape[0] // br,),
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0),
                                memory_space=pltpu.VMEM)],

@@ -105,11 +105,12 @@ class Op:
         return f"Op({self.name})"
 
 
-def _observe_compiles(f, site, attrs_key):
+def _observe_compiles(f, site, attrs_key, name=None):
     """Wrap ``f`` (pre-jit) so the telemetry recompile watchdog sees every
     trace. The wrapper body runs ONLY at trace time — cached calls execute
     the compiled program directly — so per-call overhead is zero and the
-    trace-time report short-circuits on telemetry.ON."""
+    trace-time report short-circuits on telemetry.ON. ``name`` becomes the
+    compiled module's name (``jit_<name>``) in a profiler trace."""
     from .. import telemetry as _telemetry
 
     attrs_repr = repr(attrs_key) if attrs_key else None
@@ -118,6 +119,8 @@ def _observe_compiles(f, site, attrs_key):
         _telemetry.record_compile(site, args, attrs_repr)
         return f(*args)
 
+    if name:
+        observed.__name__ = name
     return observed
 
 

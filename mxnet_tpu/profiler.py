@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import time
 
 __all__ = ["set_config", "start", "stop", "dump", "dumps", "pause", "resume",
            "scope", "record", "Profiler", "mark_step", "dump_memory_csv",
@@ -308,16 +307,14 @@ def scope(name="<unk>"):
     track = _config.get("profile_memory")
     if track:
         before = {id(a) for a in jax.live_arrays()}
-    wall0 = time.time()
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
-        yield
-    dt = time.perf_counter() - t0
-    tot, cnt = _ranges.get(name, (0.0, 0))
-    _ranges[name] = (tot + dt, cnt + 1)
     from . import telemetry as _telemetry
 
-    _telemetry._maybe_span("profiler." + name, wall0, dt)
+    # the one span primitive: the event is "mxtpu:<name>" in the xplane,
+    # the timer and the event-log span "profiler.<name>"
+    with _telemetry.span(name, timer="profiler." + name) as sp:
+        yield
+    tot, cnt = _ranges.get(name, (0.0, 0))
+    _ranges[name] = (tot + sp.seconds, cnt + 1)
     if track:
         live_now = jax.live_arrays()
         # prune attributions of freed buffers every scope exit — id() values

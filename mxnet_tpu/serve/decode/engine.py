@@ -55,6 +55,8 @@ import numpy as onp
 
 from ...base import MXNetError
 from ...telemetry.registry import Histogram
+from ...telemetry.spans import span
+from ...telemetry.trace import next_id
 from ...testing import chaos
 from ..bucketing import pick_bucket
 from .cache import PagedKVCache
@@ -108,6 +110,8 @@ class DecodeStream:
         self.expired = False
         self.truncated = False
         self.trace = None             # RequestTrace when telemetry is on
+        self.rid = next_id()          # the request's one identifier: the
+        # prefill span's ``rids`` and the trace's ``trace_id`` carry it
         self.t_submit = time.perf_counter()
         self._t_last = None           # engine: last emit time (TTFT/TPOT)
         self._on_token = on_token
@@ -430,6 +434,8 @@ class DecodeEngine:
         stream = DecodeStream(toks, min(int(max_new_tokens), budget),
                               deadline, on_token)
         stream.trace = trace
+        if trace is not None:
+            trace.trace_id = stream.rid
         if stream.max_new_tokens < max_new_tokens:
             stream.truncated = True
         self._start_worker()
@@ -466,8 +472,11 @@ class DecodeEngine:
         pending = deque()
         crash = None
         try:
+            # the spans below and in what this calls are flat leaves that
+            # tile this thread's time (telemetry/spans.py)
             while not self._gather(pending):
-                self._expire(pending)
+                with span("serve.expire"):
+                    self._expire(pending)
                 self._admit(pending)
                 if self._slot_req:
                     self._tick()
@@ -529,6 +538,13 @@ class DecodeEngine:
         otherwise drains without waiting (the decode tick itself is the
         coalescing window once slots are live). Returns True on STOP."""
         idle = not self._slot_req and not pending
+        with span("serve.wait_queue" if idle else "serve.gather") as sp:
+            n0 = len(pending)
+            stop = self._take(pending, idle)
+            sp.note(n=len(pending) - n0)
+        return stop
+
+    def _take(self, pending, idle):
         try:
             item = self._q.get() if idle else self._q.get_nowait()
         except queue.Empty:
@@ -615,29 +631,33 @@ class DecodeEngine:
         cache = self._cache
         while pending and cache.slots.free_count:
             group, metas = [], []
-            while (pending and len(group) < self.prefill_batch
-                   and len(group) < cache.slots.free_count):
-                meta = self._prepare(pending[0])
-                if meta is None:
-                    if group or self._slot_req or (
-                            self._prefix is not None
-                            and self._prefix.evictable_pages() > 0):
-                        # pages will free up (retirements / evictions
-                        # racing pins); try again next tick
-                        break
-                    # nothing live, nothing evictable: this prompt can
-                    # never fit — shed it instead of spinning forever
-                    stream = pending.popleft()
-                    self._shed_one(admitted=True)
-                    self._tm.finish_trace(stream.trace, status="shed")
-                    stream._finish(ShedError(
-                        f"kv page pool exhausted: prompt needs "
-                        f"{-(-len(stream.prompt) // self.page_tokens)} "
-                        f"pages, pool has {cache.pages.free_count} free "
-                        f"of {self.kv_pages}"))
-                    continue
-                group.append(pending.popleft())
-                metas.append(meta)
+            starved = False   # a prompt found the page pool short
+            with span("serve.admit.prepare") as sp:
+                while (pending and len(group) < self.prefill_batch
+                       and len(group) < cache.slots.free_count):
+                    meta = self._prepare(pending[0])
+                    if meta is None:
+                        starved = True
+                        if group or self._slot_req or (
+                                self._prefix is not None
+                                and self._prefix.evictable_pages() > 0):
+                            # pages will free up (retirements / evictions
+                            # racing pins); try again next tick
+                            break
+                        # nothing live, nothing evictable: this prompt can
+                        # never fit — shed it instead of spinning forever
+                        stream = pending.popleft()
+                        self._shed_one(admitted=True)
+                        self._tm.finish_trace(stream.trace, status="shed")
+                        stream._finish(ShedError(
+                            f"kv page pool exhausted: prompt needs "
+                            f"{-(-len(stream.prompt) // self.page_tokens)} "
+                            f"pages, pool has {cache.pages.free_count} free "
+                            f"of {self.kv_pages}"))
+                        continue
+                    group.append(pending.popleft())
+                    metas.append(meta)
+                sp.note(n=len(group), starved=int(starved))
             if not group:
                 break
             # plain and join prefills are separate program families —
@@ -662,52 +682,66 @@ class DecodeEngine:
         cache = self._cache
         P = self.page_tokens
         ext = sub[0][1]["start"] > 0
-        slots = [cache.slots.alloc() for _ in sub]
-        B = pick_bucket(len(sub), self.programs.batch_ladder)
-        T = pick_bucket(max(len(s.prompt) - m["start"] for s, m in sub),
-                        self.programs.len_ladder)
-        tokens = onp.zeros((B, T), dtype="int32")
-        valid = onp.ones((B,), dtype="int32")
-        start = onp.zeros((B,), dtype="int32")
-        table = onp.full((B, cache.pages_per_slot + 1), cache.trash,
-                         dtype="int32")
-        t_q = time.perf_counter()  # queue phase: submit -> prefill pickup
-        for i, ((stream, meta), sid) in enumerate(zip(sub, slots)):
-            row = meta["shared"] + meta["own"]
-            cache.table[sid, :] = cache.trash
-            cache.table[sid, :len(row)] = row
-            self._cols[sid] = len(row)
-            self._slot_pages[sid] = list(meta["own"])
-            self._slot_handles[sid] = [meta["handle"]] if meta["handle"] \
-                else []
-            suffix = stream.prompt[meta["start"]:]
-            tokens[i, :len(suffix)] = suffix
-            valid[i] = len(suffix)
-            start[i] = meta["start"]
-            table[i] = cache.table[sid]
-            if stream.trace is not None:
-                stream.trace.mark("queue", t_q)
-        kind = "prefill_ext" if ext else "prefill"
-        key = (kind, B, T)
-        self.programs.ensure(kind, batch=B, length=T)
+        with span("serve.prefill.host") as sp:
+            slots = [cache.slots.alloc() for _ in sub]
+            B = pick_bucket(len(sub), self.programs.batch_ladder)
+            T = pick_bucket(max(len(s.prompt) - m["start"] for s, m in sub),
+                            self.programs.len_ladder)
+            tokens = onp.zeros((B, T), dtype="int32")
+            valid = onp.ones((B,), dtype="int32")
+            start = onp.zeros((B,), dtype="int32")
+            table = onp.full((B, cache.pages_per_slot + 1), cache.trash,
+                             dtype="int32")
+            t_q = time.perf_counter()  # queue phase: submit -> prefill pickup
+            for i, ((stream, meta), sid) in enumerate(zip(sub, slots)):
+                row = meta["shared"] + meta["own"]
+                cache.table[sid, :] = cache.trash
+                cache.table[sid, :len(row)] = row
+                self._cols[sid] = len(row)
+                self._slot_pages[sid] = list(meta["own"])
+                self._slot_handles[sid] = [meta["handle"]] \
+                    if meta["handle"] else []
+                suffix = stream.prompt[meta["start"]:]
+                tokens[i, :len(suffix)] = suffix
+                valid[i] = len(suffix)
+                start[i] = meta["start"]
+                table[i] = cache.table[sid]
+                if stream.trace is not None:
+                    stream.trace.mark("queue", t_q)
+            kind = "prefill_ext" if ext else "prefill"
+            key = (kind, B, T)
+            self.programs.ensure(kind, batch=B, length=T)
+            # ids are space-separated: the profiler splits attributes at
+            # commas
+            sp.note(batch=B, length=T,
+                    rids=" ".join(str(s.rid) for s, _ in sub),
+                    queue_wait_ms_max=max(
+                        t_q - s.t_submit for s, _ in sub) * 1e3)
         tm = self._tm
         hb_on = tm.ON
-        t_run = time.perf_counter()
         if hb_on:
             self._hb_prefill.begin()
         try:
-            args = [jax.device_put(tokens), jax.device_put(valid)]
-            if ext:
-                args.append(jax.device_put(start))
-            args += [jax.device_put(table), cache.k, cache.v]
-            outs = self._run_retry(key, args, point="decode.prefill")
-            cache.rebind(outs[1], outs[2])
-            first = onp.asarray(outs[0])  # device sync: the TTFT tokens
+            with span("serve.prefill.dispatch", batch=B, length=T) as sp:
+                args = [jax.device_put(tokens), jax.device_put(valid)]
+                if ext:
+                    args.append(jax.device_put(start))
+                args += [jax.device_put(table), cache.k, cache.v]
+                outs = self._run_retry(key, args, point="decode.prefill")
+                cache.rebind(outs[1], outs[2])
+            with span("serve.wait_prefill", timer="serve.prefill.call",
+                      after=sp):
+                first = onp.asarray(outs[0])  # device sync: the TTFT tokens
         finally:
             if hb_on:
                 self._hb_prefill.end()
-                tm.REGISTRY.timer("serve.prefill.call").record(
-                    time.perf_counter() - t_run)
+        with span("serve.prefill.commit"):
+            self._prefill_commit(sub, slots, first)
+
+    def _prefill_commit(self, sub, slots, first):
+        cache = self._cache
+        P = self.page_tokens
+        tm = self._tm
         if tm.ON:
             tm.record_dispatch()
         with self._stats_lock:
@@ -758,47 +792,59 @@ class DecodeEngine:
         # the pool can't serve is starved: it commits at most one more
         # token and retires truncated (shed capacity, never crash)
         starved = set()
-        for sid in live:
-            need = min(-(-(int(cache.lengths[sid]) + K) // P), W)
-            short = need - int(self._cols[sid])
-            if short > 0:
-                got = self._alloc_pages(short)
-                if got is None:
-                    starved.add(sid)
-                else:
-                    c = int(self._cols[sid])
-                    cache.table[sid, c:c + len(got)] = got
-                    self._cols[sid] = c + len(got)
-                    self._slot_pages[sid].extend(got)
-        tokens = onp.zeros((self.num_slots, K), dtype="int32")
-        tokens[:, 0] = self._last_tok
+        with span("serve.tick.grow", live=len(live)) as sp:
+            for sid in live:
+                need = min(-(-(int(cache.lengths[sid]) + K) // P), W)
+                short = need - int(self._cols[sid])
+                if short > 0:
+                    got = self._alloc_pages(short)
+                    if got is None:
+                        starved.add(sid)
+                    else:
+                        c = int(self._cols[sid])
+                        cache.table[sid, c:c + len(got)] = got
+                        self._cols[sid] = c + len(got)
+                        self._slot_pages[sid].extend(got)
+            tokens = onp.zeros((self.num_slots, K), dtype="int32")
+            tokens[:, 0] = self._last_tok
+            self.programs.ensure("decode")
+            sp.note(starved=len(starved))
         drafts = {}
         if K > 1:
-            for sid in live:
-                stream = self._slot_req[sid]
-                d = self._draft.propose(stream.prompt + stream.tokens,
-                                        K - 1)
-                drafts[sid] = d
-                tokens[sid, 1:] = d
+            with span("serve.tick.draft"):
+                for sid in live:
+                    stream = self._slot_req[sid]
+                    d = self._draft.propose(stream.prompt + stream.tokens,
+                                            K - 1)
+                    drafts[sid] = d
+                    tokens[sid, 1:] = d
         key = ("decode", K)
-        self.programs.ensure("decode")
         tm = self._tm
         hb_on = tm.ON
-        t_run = time.perf_counter()
         if hb_on:
             self._hb_tick.begin()
         try:
-            outs = self._run_retry(key, [
-                jax.device_put(tokens), jax.device_put(cache.lengths),
-                jax.device_put(cache.table), cache.k, cache.v],
-                point="decode.tick")
-            cache.rebind(outs[1], outs[2])
-            rows = onp.asarray(outs[0])   # device sync: this tick's tokens
+            with span("serve.tick.dispatch", live=len(live)) as sp:
+                outs = self._run_retry(key, [
+                    jax.device_put(tokens), jax.device_put(cache.lengths),
+                    jax.device_put(cache.table), cache.k, cache.v],
+                    point="decode.tick")
+                cache.rebind(outs[1], outs[2])
+            with span("serve.wait_tick", timer="serve.decode_tick.call",
+                      after=sp):
+                rows = onp.asarray(outs[0])   # device sync: the tokens
         finally:
             if hb_on:
                 self._hb_tick.end()
-                tm.REGISTRY.timer("serve.decode_tick.call").record(
-                    time.perf_counter() - t_run)
+        with span("serve.tick.commit") as sp:
+            n0 = self._n_tokens
+            self._tick_commit(live, starved, drafts, rows)
+            sp.note(tokens=self._n_tokens - n0)
+
+    def _tick_commit(self, live, starved, drafts, rows):
+        cache = self._cache
+        K = self.speculate_k
+        tm = self._tm
         if tm.ON:
             tm.record_dispatch()
         occ = cache.occupancy()

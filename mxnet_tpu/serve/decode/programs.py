@@ -57,14 +57,14 @@ def load_decode_manifest(path):
     return m
 
 
-def _compile(cop, examples, donate):
+def _compile(cop, examples, donate, name):
     """AOT-compile suppressing the backend's 'donation not implemented'
     warning (CPU): the fallback is a copy, which is correct — the donation
-    request is for the TPU path."""
+    request is for the TPU path. The module is named ``jit_<name>``."""
     with warnings.catch_warnings():
         warnings.filterwarnings("ignore", message=".*donat.*",
                                 category=UserWarning)
-        return cop.aot_compile(*examples, donate=donate)
+        return cop.aot_compile(*examples, donate=donate, name=name)
 
 
 class DecodePrograms:
@@ -352,6 +352,14 @@ class DecodePrograms:
             return f"serve.prefill_ext_b{key[1]}_t{key[2]}"
         return f"serve.prefill_b{key[1]}_t{key[2]}"
 
+    @staticmethod
+    def _program_name(key):
+        """The compiled module's name in a profiler trace, less ``jit_``:
+        ``mxtpu_serve_decode_k1``, ``mxtpu_serve_prefill_b2_t128``."""
+        if key[0] == "decode":
+            return f"mxtpu_serve_decode_k{key[1]}"
+        return f"mxtpu_serve_{key[0]}_b{key[1]}_t{key[2]}"
+
     def ensure(self, kind, batch=None, length=None):
         """Compile (memoized) and return one executable."""
         if kind == "decode":
@@ -394,7 +402,7 @@ class DecodePrograms:
             args = examples + [self._params[n]
                                for n in self._graph_params[
                                    self._cop_key(key)]]
-            prog = _compile(cop, args, donate)
+            prog = _compile(cop, args, donate, self._program_name(key))
         self._programs[key] = prog
         # per-program XLA cost, captured once per compile; run() credits
         # the flops counter with it at every dispatch
@@ -448,8 +456,13 @@ class DecodePrograms:
         off = 1 if cop._uses_rng else 0
         if off:
             in_specs = (P(),) + in_specs
-        fn = shard_map_compat(cop._raw_fn, self._mesh,
-                              in_specs=in_specs, out_specs=out_specs)
+        inner = shard_map_compat(cop._raw_fn, self._mesh,
+                                 in_specs=in_specs, out_specs=out_specs)
+
+        def fn(*args):
+            return inner(*args)
+
+        fn.__name__ = self._program_name(key)
         shardings = tuple(NamedSharding(self._mesh, s) for s in in_specs)
         self._in_shardings[key] = shardings
         argnums = tuple(sorted(int(i) + off for i in donate))

@@ -42,7 +42,8 @@ from . import numerics as _numerics
 __all__ = ["enable", "disable", "is_enabled", "configure", "reset",
            "counter", "gauge", "timer", "histogram", "metrics", "event",
            "events", "dump_events", "export_chrome_trace", "mark_step",
-           "program_timer", "step_report", "last_step", "watchdog_stats",
+           "program_timer", "span", "SPANS", "step_report", "last_step",
+           "watchdog_stats",
            "record_fsdp", "record_flops", "record_program_cost",
            "new_trace", "finish_trace", "traces", "latency_report",
            "cost_report", "program_costs", "device_peak_flops",
@@ -216,28 +217,20 @@ def last_step():
     return STEPS.last()
 
 
-import contextlib as _contextlib
+from .spans import SPANS, span  # noqa: E402 — needs ON and REGISTRY above
 
 
-@_contextlib.contextmanager
-def program_timer(site):
-    """Attribute one compiled-program call's host time to ``<site>.compile``
-    or ``<site>.call``: a trace of the program reports record_compile
-    synchronously inside the call, so the compile-counter delta tells the
-    two apart. Shared by CachedOp and the compiled train step; callers
-    guard on ``telemetry.ON`` (the manager itself is trace-cost only)."""
-    import time as _time
-
+def program_timer(site, span_name=None):
+    """A :func:`span` over one compiled-program call whose host time goes
+    to ``<site>.compile`` or ``<site>.call``: a trace of the program
+    reports record_compile synchronously inside the call, so the
+    compile-counter delta tells the two apart. ``span_name`` is the
+    profiler-trace name (default ``<site>.call``). Shared by CachedOp and
+    the compiled train step."""
     c0 = compile_count()
-    wall0 = _time.time()
-    t0 = _time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = _time.perf_counter() - t0
-        name = f"{site}.compile" if compile_count() > c0 else f"{site}.call"
-        REGISTRY.timer(name).record(dt)
-        _maybe_span(name, wall0, dt)  # trace timeline lane
+    return span(span_name or site + ".call",
+                timer=lambda: (site + ".compile" if compile_count() > c0
+                               else site + ".call"))
 
 
 # -- compile observation (called from INSIDE traced bodies) -----------------

@@ -27,6 +27,12 @@ __all__ = ["RequestTrace", "TraceCollector"]
 _IDS = itertools.count(1)
 
 
+def next_id():
+    """The next request identifier; traces and the decode engine's streams
+    draw from the one counter."""
+    return next(_IDS)
+
+
 class RequestTrace:
     """Phase timestamps for one request. Not thread-safe per instance —
     each request is owned by one pipeline stage at a time (queue → batcher
@@ -36,7 +42,7 @@ class RequestTrace:
                  "extra")
 
     def __init__(self, kind):
-        self.trace_id = next(_IDS)
+        self.trace_id = next_id()
         self.kind = kind
         self.wall0 = time.time()
         self.t0 = time.perf_counter()

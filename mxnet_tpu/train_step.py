@@ -99,6 +99,7 @@ import os
 
 from .base import MXNetError, warn_once
 from . import telemetry as _telemetry
+from .telemetry import span as _span
 
 __all__ = ["CompiledTrainStep"]
 
@@ -1236,6 +1237,9 @@ class CompiledTrainStep:
                  f"opt={type(opt).__name__} scaler={scaler_on} "
                  f"mesh={mesh is not None} sharded={sharded} pad={pad}")
 
+        # the scopes reach each operation's op_name in the HLO: the step's
+        # phases in a device trace
+        @jax.named_scope("grad")
         def grad_part(ws, fs, xb, yb, wv, key, loss_scale):
             # forward + loss + backward for ONE microbatch: returns the
             # (reduced) loss, the all_reduce'd aux updates and the LOCAL
@@ -1629,6 +1633,7 @@ class CompiledTrainStep:
         # must SUM their pre-divided local grads, whole batches pmean
         grad_op = "sum" if weighted else "mean"
 
+        @jax.named_scope("optimizer")
         def update_part(ws, ss, grads, lrs, wds, ts, rescale):
             # dp-reduce the gradients and run the optimizer recurrence —
             # the second half of the step body, shared by the single-step
@@ -1918,6 +1923,8 @@ class CompiledTrainStep:
                  bs.padded * onp.dtype(dt).itemsize * gathers if sh else 0,
                  bs.padded * onp.dtype(dt).itemsize if sh else 0)
                 for layer, dt, _, bs, sh in groups)
+        # a stable module name for the profiler's trace: jit_mxtpu_train_step
+        fn.__name__ = f"mxtpu_train_step_k{k}" if multi else "mxtpu_train_step"
         return _Program(jax.jit(fn, donate_argnums=train_donate_argnums()),
                         uses_rng,
                         aux_targets, sharded=bucketed, fsdp=fsdp,
@@ -2045,7 +2052,15 @@ class CompiledTrainStep:
         return ws, ss, fs
 
     def _dispatch(self, prog, args):
-        """Compile on first use, account the dispatch, run the program."""
+        """Compile on first use, account the dispatch, run the program:
+        the ``train.dispatch`` span, whose time feeds ``train_step.call``
+        (``.compile`` when the call traced). Returns (outputs, the span's
+        seconds)."""
+        with _telemetry.program_timer("train_step", "train.dispatch") as sp:
+            out = self._compile_and_call(prog, args)
+        return out, sp.seconds
+
+    def _compile_and_call(self, prog, args):
         self._dispatches += 1
         if prog.compiled is None:
             # first dispatch of this signature: lower + compile explicitly
@@ -2070,32 +2085,26 @@ class CompiledTrainStep:
         # admission check + OOM forensics bracket BOTH dispatch paths: a
         # set lookup when admitted, a ledger dump when the device OOMs
         _telemetry.check_memory_admission("train_step")
-        if not _telemetry.ON:
-            try:
-                return prog.compiled(*args)
-            except Exception as e:
-                _telemetry.memory_oom_forensics("train_step", e)
-                raise
-        # ONE compiled-program call per (super-)step; this bypasses the
-        # invoke() chokepoint, so count the dispatch here
-        _telemetry.record_dispatch()
-        _telemetry.record_flops(prog.flops, prog.bytes_accessed)
-        rs_b, ag_b, ps_b = prog.coll_bytes
-        if prog.sharded and not self.shard_update:
-            # replicated residency: the host-side state reshard is
-            # scatter + gather traffic on top of the program's own
-            rs_b += self._state_bucket_bytes
-            ag_b += self._state_bucket_bytes
-        _telemetry.record_collective(rs_b, ag_b, ps_b,
-                                     tp_bytes=prog.coll_bytes_tp)
-        if prog.fsdp:
-            _telemetry.record_fsdp(self._fsdp_layer_bytes)
-        with _telemetry.program_timer("train_step"):
-            try:
-                return prog.compiled(*args)
-            except Exception as e:
-                _telemetry.memory_oom_forensics("train_step", e)
-                raise
+        if _telemetry.ON:
+            # ONE compiled-program call per (super-)step; this bypasses the
+            # invoke() chokepoint, so count the dispatch here
+            _telemetry.record_dispatch()
+            _telemetry.record_flops(prog.flops, prog.bytes_accessed)
+            rs_b, ag_b, ps_b = prog.coll_bytes
+            if prog.sharded and not self.shard_update:
+                # replicated residency: the host-side state reshard is
+                # scatter + gather traffic on top of the program's own
+                rs_b += self._state_bucket_bytes
+                ag_b += self._state_bucket_bytes
+            _telemetry.record_collective(rs_b, ag_b, ps_b,
+                                         tp_bytes=prog.coll_bytes_tp)
+            if prog.fsdp:
+                _telemetry.record_fsdp(self._fsdp_layer_bytes)
+        try:
+            return prog.compiled(*args)
+        except Exception as e:
+            _telemetry.memory_oom_forensics("train_step", e)
+            raise
 
     def _writeback(self, prog, new_ws, new_ss, aux):
         """Rebind the program's donated outputs into the host-visible
@@ -2135,20 +2144,29 @@ class CompiledTrainStep:
         """Fold the program's in-scan health outputs into the host-side
         numerics monitor. health = (grad_sq_norm, max_abs_update,
         nonfinite_counts[, group_sq_norms]) — scalars/[G] from the
-        single-step program, [K]/[K, G] stacked from the scan."""
+        single-step program, [K]/[K, G] stacked from the scan. Returns the
+        host seconds of the arithmetic (the read-back wait is not host
+        work)."""
         import numpy as onp
 
-        gsq = onp.atleast_1d(onp.asarray(health[0], onp.float64))
-        mx = onp.atleast_1d(onp.asarray(health[1], onp.float64))
-        nonfin = onp.asarray(health[2]).reshape(k_steps, -1)
-        gn = None
-        if len(health) > 3:
-            gn = onp.sqrt(onp.asarray(
-                health[3], onp.float64).reshape(k_steps, -1))
-        _telemetry.record_step_health(
-            prog.health_groups, onp.sqrt(gsq), mx, nonfin,
-            group_norms=gn, nmode=prog.health_mode)
+        with _span("train.wait_health"):
+            # device read-back: blocks until the step's program is done
+            health = [onp.asarray(h) for h in health]
+        with _span("train.health") as sp:
+            gsq = onp.atleast_1d(onp.asarray(health[0], onp.float64))
+            mx = onp.atleast_1d(onp.asarray(health[1], onp.float64))
+            nonfin = health[2].reshape(k_steps, -1)
+            gn = None
+            if len(health) > 3:
+                gn = onp.sqrt(onp.asarray(
+                    health[3], onp.float64).reshape(k_steps, -1))
+            _telemetry.record_step_health(
+                prog.health_groups, onp.sqrt(gsq), mx, nonfin,
+                group_norms=gn, nmode=prog.health_mode)
+        return sp.seconds
 
+    # _run and _run_multi: the spans are flat leaves that tile the caller's
+    # time inside the step call (telemetry/spans.py)
     def _run(self, prog, x, y):
         import jax.numpy as jnp
         import numpy as onp
@@ -2157,117 +2175,139 @@ class CompiledTrainStep:
         opt = tr._optimizer
         idxs = self._train_idx
         scaler = self.loss_scaler
-        ws, ss, fs = self._assemble_inputs(prog)
-        if prog.uses_rng:
-            from . import random as _rnd
+        with _span("train.assemble"):
+            ws, ss, fs = self._assemble_inputs(prog)
+        with _span("train.key"):
+            if prog.uses_rng:
+                from . import random as _rnd
 
-            key = _rnd._next_key()
-        else:
-            key = jnp.zeros((2,), jnp.uint32)
-        # scalar schedule inputs are RUNTIME operands (the trainer rule):
-        # counts are STAGED, not committed — an overflow-skipped step must
-        # leave the schedule exactly where the eager skip would
-        counts, num_update = opt._staged_counts(idxs)
-        ts = onp.asarray(counts, onp.float32)
-        lrs = onp.asarray([opt._get_lr(i, num_update=num_update)
-                           for i in idxs], onp.float32)
-        wds = onp.asarray([opt._get_wd(i) for i in idxs], onp.float32)
-        scale = float(scaler.loss_scale) if scaler is not None else 1.0
-        rescale = onp.float32(tr._scale / scale)
-        loss_scale = onp.float32(scale)
-        out = self._dispatch(prog, (ws, ss, fs, x._data, y._data, key, lrs,
-                                    wds, ts, rescale, loss_scale))
-        if prog.health_groups is not None:
-            loss_v, aux, new_ws, new_ss, overflow, health = out
-        else:
-            loss_v, aux, new_ws, new_ss, overflow = out
-            health = None
-        self._writeback(prog, new_ws, new_ss, aux)
+                key = _rnd._next_key()
+            else:
+                key = jnp.zeros((2,), jnp.uint32)
+        with _span("train.schedule"):
+            # scalar schedule inputs are RUNTIME operands (the trainer
+            # rule): counts are STAGED, not committed — an overflow-skipped
+            # step must leave the schedule exactly where the eager skip
+            # would
+            counts, num_update = opt._staged_counts(idxs)
+            ts = onp.asarray(counts, onp.float32)
+            lrs = onp.asarray([opt._get_lr(i, num_update=num_update)
+                               for i in idxs], onp.float32)
+            wds = onp.asarray([opt._get_wd(i) for i in idxs], onp.float32)
+            scale = float(scaler.loss_scale) if scaler is not None else 1.0
+            rescale = onp.float32(tr._scale / scale)
+            loss_scale = onp.float32(scale)
+        out, _ = self._dispatch(prog, (ws, ss, fs, x._data, y._data, key,
+                                       lrs, wds, ts, rescale, loss_scale))
+        with _span("train.writeback"):
+            if prog.health_groups is not None:
+                loss_v, aux, new_ws, new_ss, overflow, health = out
+            else:
+                loss_v, aux, new_ws, new_ss, overflow = out
+                health = None
+            self._writeback(prog, new_ws, new_ss, aux)
         if scaler is not None:
-            ovf = bool(overflow)  # the step's only host sync (1 byte)
-            scaler.update_scale(ovf)
+            with _span("train.wait_overflow"):
+                ovf = bool(overflow)  # the step's only host sync (1 byte)
         else:
             ovf = False
-        if not ovf:
-            opt._commit_counts(idxs)
+        with _span("train.commit"):
+            if scaler is not None:
+                scaler.update_scale(ovf)
+            if not ovf:
+                opt._commit_counts(idxs)
         if health is not None:
             # a few scalars riding the dispatch the step already paid for
             self._record_health(prog, health, k_steps=1)
-        if _telemetry.ON:
-            _telemetry.mark_step()
-        from .ndarray.ndarray import NDArray
+        with _span("train.mark"):
+            # the last references to the arrays the program consumed: some
+            # hundreds of objects are freed here, inside a span, and not at
+            # the frame's end outside every span
+            del ws, ss
+            if _telemetry.ON:
+                _telemetry.mark_step()
+            from .ndarray.ndarray import NDArray
 
-        return NDArray(loss_v)
+            return NDArray(loss_v)
 
     def _run_multi(self, prog, x, y):
-        import time as _time
-
         import jax.numpy as jnp
         import numpy as onp
 
-        t_host0 = _time.perf_counter()
         tr = self.trainer
         opt = tr._optimizer
         idxs = self._train_idx
         scaler = self.loss_scaler
         k, g = prog.k, prog.accum
-        ws, ss, fs = self._assemble_inputs(prog)
-        if prog.uses_rng:
-            from . import random as _rnd
+        with _span("train.assemble") as sp_a:
+            ws, ss, fs = self._assemble_inputs(prog)
+        with _span("train.key") as sp_k:
+            if prog.uses_rng:
+                from . import random as _rnd
 
-            # one key PER MICROBATCH, drawn in the exact order the
-            # sequential loop would draw them (RNG-trajectory parity)
-            flat = [_rnd._next_key() for _ in range(k * g)]
-            keys = jnp.stack(flat).reshape((k, g, 2) if g > 1 else (k, 2))
-        else:
-            keys = jnp.zeros((k, g, 2) if g > 1 else (k, 2), jnp.uint32)
-        # per-inner-step hyper table: row j = what the j-th COMMITTED step
-        # would stage; the program indexes rows by its in-scan committed
-        # counter, so overflow skips freeze the schedule exactly like the
-        # eager loop (and K sequential compiled steps)
-        rows, nus = opt._staged_counts_k(idxs, k)
-        ts = onp.asarray(rows, onp.float32)
-        lrs = onp.asarray(
-            [[opt._get_lr(i, num_update=nu) for i in idxs] for nu in nus],
-            onp.float32)
-        wd_row = [opt._get_wd(i) for i in idxs]
-        wds = onp.asarray([wd_row] * k, onp.float32)
-        scale = float(scaler.loss_scale) if scaler is not None else 1.0
-        rescale = onp.float32(tr._scale / scale)
-        loss_scale = onp.float32(scale)
-        out = self._dispatch(prog, (ws, ss, fs, x._data, y._data, keys, lrs,
-                                    wds, ts, rescale, loss_scale))
-        if prog.health_groups is not None:
-            losses, aux, new_ws, new_ss, ovfs, healths = out
-        else:
-            losses, aux, new_ws, new_ss, ovfs = out
-            healths = None
-        self._writeback(prog, new_ws, new_ss, aux)
-        # the super-step's only host sync: the K overflow flags (K bytes)
-        t_s0 = _time.perf_counter()
-        flags = onp.asarray(ovfs)
-        t_s1 = _time.perf_counter()
-        if scaler is not None:
-            clean = scaler.replay(flags)
-        else:
-            clean = k
-        for _ in range(clean):
-            opt._commit_counts(idxs)
+                # one key PER MICROBATCH, drawn in the exact order the
+                # sequential loop would draw them (RNG-trajectory parity)
+                flat = [_rnd._next_key() for _ in range(k * g)]
+                keys = jnp.stack(flat).reshape(
+                    (k, g, 2) if g > 1 else (k, 2))
+            else:
+                keys = jnp.zeros((k, g, 2) if g > 1 else (k, 2), jnp.uint32)
+        with _span("train.schedule") as sp_s:
+            # per-inner-step hyper table: row j = what the j-th COMMITTED
+            # step would stage; the program indexes rows by its in-scan
+            # committed counter, so overflow skips freeze the schedule
+            # exactly like the eager loop (and K sequential compiled steps)
+            rows, nus = opt._staged_counts_k(idxs, k)
+            ts = onp.asarray(rows, onp.float32)
+            lrs = onp.asarray(
+                [[opt._get_lr(i, num_update=nu) for i in idxs]
+                 for nu in nus], onp.float32)
+            wd_row = [opt._get_wd(i) for i in idxs]
+            wds = onp.asarray([wd_row] * k, onp.float32)
+            scale = float(scaler.loss_scale) if scaler is not None else 1.0
+            rescale = onp.float32(tr._scale / scale)
+            loss_scale = onp.float32(scale)
+        out, dispatch_s = self._dispatch(
+            prog, (ws, ss, fs, x._data, y._data, keys, lrs, wds, ts,
+                   rescale, loss_scale))
+        with _span("train.writeback") as sp_w:
+            if prog.health_groups is not None:
+                losses, aux, new_ws, new_ss, ovfs, healths = out
+            else:
+                losses, aux, new_ws, new_ss, ovfs = out
+                healths = None
+            self._writeback(prog, new_ws, new_ss, aux)
+        with _span("train.wait_overflow"):
+            # the super-step's only host sync: the K overflow flags (K
+            # bytes)
+            flags = onp.asarray(ovfs)
+        with _span("train.commit") as sp_c:
+            if scaler is not None:
+                clean = scaler.replay(flags)
+            else:
+                clean = k
+            for _ in range(clean):
+                opt._commit_counts(idxs)
+        health_s = 0.0
         if healths is not None:
             # [K]-stacked health rows ride the same dispatch; the overflow
             # sync above already waited out the device
-            self._record_health(prog, healths, k_steps=k)
-        if _telemetry.ON:
-            # host cost per trained step, the sync wait excluded (that
-            # time is the device computing, not the host dispatching)
-            host_ms = ((_time.perf_counter() - t_host0) -
-                       (t_s1 - t_s0)) * 1e3 / k
-            _telemetry.gauge("train.host_ms_per_step").set(host_ms)
-            _telemetry.gauge("train.dispatches_per_step").set(1.0 / k)
-            _telemetry.mark_step(inner_steps=k)
-        from .ndarray.ndarray import NDArray
+            health_s = self._record_health(prog, healths, k_steps=k)
+        with _span("train.mark"):
+            del ws, ss  # freed inside a span, as in _run
+            if _telemetry.ON:
+                # host cost per trained step: the spans that are host work
+                # (the waits are the device computing, not the host
+                # dispatching)
+                host_s = dispatch_s + health_s + sum(sp.seconds for sp in (
+                    sp_a, sp_k, sp_s, sp_w, sp_c))
+                _telemetry.gauge("train.host_ms_per_step").set(
+                    host_s * 1e3 / k)
+                _telemetry.gauge("train.dispatches_per_step").set(1.0 / k)
+                _telemetry.mark_step(inner_steps=k)
+            from .ndarray.ndarray import NDArray
 
-        return NDArray(losses)
+            return NDArray(losses)
 
     # -- the uncompiled fallback -------------------------------------------
     def _eager_step(self, x, y):
