@@ -376,11 +376,23 @@ class GPTModel(HybridBlock):
     # pool page id; the sentinel id ``num_pages`` (one past the pool)
     # marks unmapped columns.  Reads gather the row's first W columns into
     # a contiguous [W*P] view (sentinel clips to a real page whose
-    # positions the kv mask always excludes); writes scatter through
-    # one-hot einsums — ``one_hot(sentinel, num_pages)`` is the zero
-    # vector, so writes routed at an unmapped column vanish exactly
-    # instead of corrupting a live page.  All three programs keep fully
-    # static shapes, preserving the zero-recompile serving contract.
+    # positions the kv mask always excludes).  Writes are indexed
+    # updates of the (donated) pool, in place and a WHOLE PAGE of one or
+    # all layers at a time: ``np.index_update`` at the page ids the table
+    # maps.  (The chip keeps the pool with ``page_tokens`` as its fastest
+    # axis, head_dim being half a vector register wide; an update of a
+    # single position makes XLA lay the whole pool out anew and back, an
+    # update of whole pages does not.)  The tick therefore reads the
+    # pages its rows land in, puts the rows in and writes the pages back.
+    # A write routed at the sentinel id (an unmapped column, an inactive
+    # slot, a chunk past ``valid_length``) is out of range — one past the
+    # end, never negative — and jax's ``.at[].set`` drops out-of-range
+    # updates, so it vanishes exactly instead of corrupting a live page.
+    # The tick and the prefix join write each layer's k/v BEFORE that
+    # layer's gather, so the gathered view already holds the new
+    # positions.  Nothing but the updates has the pool's shape, and all
+    # three programs keep fully static shapes, preserving the
+    # zero-recompile serving contract.
 
     def init_paged_cache(self, num_pages, page_tokens):
         """Preallocated paged KV pool pair, each
@@ -393,60 +405,103 @@ class GPTModel(HybridBlock):
         return (np.zeros(shape, dtype=self._dtype),
                 np.zeros(shape, dtype=self._dtype))
 
-    def _pool_layer(self, pool, i):
-        """[NP, L, H, P, D] -> layer i's [NP, H, P, D]."""
+    @staticmethod
+    def _layer_id(i):
+        """Layer ``i`` as an int32 scalar ARRAY: an index of the pool that
+        is an operand, so that one eager program serves every layer when
+        these bodies are traced (an int in the key is a program a
+        layer). In the compiled graph it is a constant all the same."""
         from ... import numpy as np
 
-        return np.squeeze(
-            npx.slice_axis(pool, axis=1, begin=i, end=i + 1), axis=1)
+        return np.array(i, dtype="int32")
 
-    def _gather_page_view(self, pool_layer, flat_ids, W):
+    def _gather_page_view(self, pool, layer, flat_ids, W):
         """Gather page-table rows (W columns each, flattened into
-        ``flat_ids``) from one layer's pool into a contiguous
-        (rows, W*P, units) kv view. Batch-polymorphic: one traced graph
-        serves every batch bucket, so no reshape may bake the row count."""
+        ``flat_ids``) of layer ``layer`` (a ``_layer_id``) straight from
+        the pool (no slice of the layer is made first) into a contiguous
+        (rows, W*P, units) kv view; the sentinel clamps to the last page.
+        Batch-polymorphic: one traced graph serves every batch bucket, so
+        no reshape may bake the row count."""
         from ... import numpy as np
 
-        NP_, H, P, D = pool_layer.shape
-        view = np.take(pool_layer, flat_ids, axis=0, mode="clip")
+        H, P, D = pool.shape[2:]
+        view = pool[flat_ids, layer]                     # (rows*W, H, P, D)
         view = np.transpose(np.reshape(view, (-1, W, H, P, D)),
                             (0, 1, 3, 2, 4))
         return np.reshape(view, (-1, W * P, H * D))
 
     def _scatter_pages(self, k, v, valid_length, start, page_table,
-                       k_pool, v_pool):
-        """Write per-layer prompt k/v (B, layers, heads, T, head_dim) into
-        the pool at the pages ``page_table`` maps for logical pages
-        ``start//P + j``; chunks past ``valid_length`` (and any chunk
-        whose table column is the sentinel) are dropped exactly."""
+                       k_pool, v_pool, layer=None):
+        """Write prompt k/v into the pool, whole pages at a time, with one
+        indexed update per pool: (B, layers, heads, T, head_dim) of every
+        layer, or (B, heads, T, head_dim) of layer ``layer`` (a
+        ``_layer_id``).
+
+        Chunk j of a row lands in the page its ``page_table`` row maps
+        for logical page ``start//P + j``. A chunk past ``valid_length``
+        is routed at the sentinel id, like one whose table column holds
+        it, and the update drops both. The engine never maps one page to
+        two rows of a batch, so no two chunks share a page."""
         from ... import numpy as np
 
-        NP_, L, H, P, D = k_pool.shape
-        T = k.shape[3]
+        NP_, P = k_pool.shape[0], k_pool.shape[3]
+        T = k.shape[-2]
         W = page_table.shape[1] - 1
         J = -(-T // P)
-        pad = J * P - T
-        if pad:
-            widths = ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
-            k, v = np.pad(k, widths), np.pad(v, widths)
-        # (B, L, H, J*P, D) -> (B, L, H, J, P, D) page chunks; -1 keeps
-        # the graph batch-polymorphic across compile-time batch buckets
-        k = np.reshape(k, (-1, L, H, J, P, D))
-        v = np.reshape(v, (-1, L, H, J, P, D))
         j_idx = np.arange(J, dtype="int32").reshape(1, J)
-        # (valid * 0, not zeros_like: stays an op ON the input, so the
-        # traced graph keeps the batch dim symbolic across buckets)
-        base = (start.astype("int32") // P).reshape(-1, 1) if start is not None \
-            else (valid_length.astype("int32") * 0).reshape(-1, 1)
-        col = np.minimum(base + j_idx, W)
-        page_id = np.take_along_axis(page_table, col, axis=1)   # (B, J)
-        live = (j_idx * P < valid_length.astype("int32").reshape(-1, 1))
-        page_oh = np.one_hot(page_id, NP_, dtype=str(k_pool.dtype)) \
-            * live.astype(str(k_pool.dtype)).reshape(-1, J, 1)   # (B, J, NP)
-        wrote = np.einsum("bjp->p", page_oh).reshape(NP_, 1, 1, 1, 1) > 0
-        ck = np.einsum("bjp,blhjod->plhod", page_oh, k)
-        cv = np.einsum("bjp,blhjod->plhod", page_oh, v)
-        return np.where(wrote, ck, k_pool), np.where(wrote, cv, v_pool)
+        valid = valid_length.astype("int32").reshape(-1, 1)
+        # (valid * 0, not zeros: stays an op ON the input, so the traced
+        # graph keeps the batch dim symbolic across buckets)
+        base = (start.astype("int32") // P).reshape(-1, 1) \
+            if start is not None else valid * 0
+        page_id = np.take_along_axis(
+            page_table, np.minimum(base + j_idx, W), axis=1)     # (B, J)
+        page_id = np.reshape(np.where(j_idx * P < valid, page_id, NP_), (-1,))
+        key = (page_id,) if layer is None else (page_id, layer)
+        return (self._update_pool(k_pool, key, self._page_chunks(k, J, P)),
+                self._update_pool(v_pool, key, self._page_chunks(v, J, P)))
+
+    @staticmethod
+    def _update_pool(pool, key, value):
+        """``pool.at[key].set(value)`` of whole pages, waited for. In a
+        compiled program the update is in place. These bodies also run
+        EAGERLY, once, when a program is traced, and there every update
+        is a copy of the pool: without the wait the host runs layers
+        ahead of the device with a pool-sized buffer in flight for each
+        (the trace of a 3 GiB pool pair peaked at 15.1 of a v5e's 15.75
+        GiB)."""
+        from ... import numpy as np
+
+        return np.index_update(pool, key, value).wait_to_read()
+
+    @staticmethod
+    def _page_chunks(x, J, P):
+        """(B, ..., T, D) -> (B*J, ..., P, D): T zero-padded to J pages
+        and the page axis moved forward. -1 keeps the graph
+        batch-polymorphic across compile-time batch buckets."""
+        from ... import numpy as np
+
+        inner, (T, D) = tuple(x.shape[1:-2]), x.shape[-2:]
+        if J * P != T:
+            x = np.pad(x, ((0, 0),) * (x.ndim - 2)
+                       + ((0, J * P - T), (0, 0)))
+        x = np.moveaxis(np.reshape(x, (-1,) + inner + (J, P, D)), -3, 1)
+        return np.reshape(x, (-1,) + inner + (P, D))
+
+    def _write_rows(self, pool, layer, page_id, hits, rows):
+        """Put ``rows`` (S, K, heads, head_dim) into layer ``layer`` (a
+        ``_layer_id``) of the pages ``page_id`` (S*J,): read the pages,
+        set row k wherever ``hits[k]`` (S, J, 1, P, 1) says, write them
+        back. A page that no row hits goes back as it came."""
+        from ... import numpy as np
+
+        S, K, H, D = rows.shape
+        old = pool[page_id, layer]              # (S*J, H, P, D); clamps
+        new = np.reshape(old, (S, -1) + tuple(old.shape[1:]))
+        for k in range(K):
+            new = np.where(hits[k], rows[:, k].reshape(S, 1, H, 1, D), new)
+        return self._update_pool(pool, (page_id, layer),
+                                 np.reshape(new, old.shape))
 
     def forward_prefill_paged(self, tokens, valid_length, page_table,
                               k_pool, v_pool):
@@ -455,8 +510,8 @@ class GPTModel(HybridBlock):
 
         Runs the EXACT flash-path compute of ``forward_prefill`` (the
         last-valid logits are bitwise those of the slot-cache engine);
-        only the cache write changes, scattering page-sized k/v chunks at
-        the pages ``page_table`` (B, W+1) maps.
+        only the cache write changes: the k/v of all layers, cut into
+        whole pages, lands in the pages ``page_table`` (B, W+1) maps.
         Returns (last_logits (B, V), k_pool', v_pool').
         """
         last, k, v = self.forward_prefill(tokens, valid_length)
@@ -471,14 +526,15 @@ class GPTModel(HybridBlock):
 
         ``tokens`` (B, T) holds only the prompt SUFFIX (right-padded,
         ``valid_length`` real tokens); positions start..start+T-1. Each
-        query attends the gathered page view (prefix k/v already in the
-        pool) plus this suffix's own k/v, masked to absolute positions
-        <= its own. Suffix k/v then scatters into pages start//P + j.
+        layer first writes the suffix's k/v into pages start//P + j of
+        the pool, then each query attends the gathered page view — the
+        prefix already in the pool plus the suffix just written — masked
+        to absolute positions <= its own.
         Returns (last_logits (B, V), k_pool', v_pool').
         """
         from ... import numpy as np
 
-        NP_, L, H, P, D = k_pool.shape
+        P = k_pool.shape[3]
         B, T = tokens.shape
         W = page_table.shape[1] - 1
         WP = W * P
@@ -487,23 +543,16 @@ class GPTModel(HybridBlock):
         x = self._embed(tokens, np.minimum(pos, self.max_length - 1))
         ar = np.arange(WP, dtype="int32").reshape(1, 1, WP)
         mask = (ar <= pos.reshape(-1, T, 1)).reshape(-1, 1, T, WP)
-        pos_oh = np.one_hot(pos, WP, dtype=self._dtype)          # (B, T, WP)
-        wrote = np.einsum("btl->bl", pos_oh).reshape(-1, WP, 1) > 0
         flat_ids = np.reshape(
             npx.slice_axis(page_table, axis=1, begin=0, end=W), (-1,))
-        ks, vs = [], []
         for i, blk in enumerate(self.blocks):
+            lay = self._layer_id(i)
             q, k, v = blk._qkv(x)
-            ks.append(self._split_heads(k))
-            vs.append(self._split_heads(v))
-            viewk = self._gather_page_view(
-                self._pool_layer(k_pool, i), flat_ids, W)
-            viewv = self._gather_page_view(
-                self._pool_layer(v_pool, i), flat_ids, W)
-            viewk = np.where(wrote, np.einsum("btl,btu->blu", pos_oh, k),
-                             viewk)
-            viewv = np.where(wrote, np.einsum("btl,btu->blu", pos_oh, v),
-                             viewv)
+            k_pool, v_pool = self._scatter_pages(
+                self._split_heads(k), self._split_heads(v), valid_length,
+                start, page_table, k_pool, v_pool, layer=lay)
+            viewk = self._gather_page_view(k_pool, lay, flat_ids, W)
+            viewv = self._gather_page_view(v_pool, lay, flat_ids, W)
             attn = npx.multihead_attention(q, viewk, viewv, mask=mask,
                                            num_heads=_local_heads(
                                                self._num_heads),
@@ -514,9 +563,6 @@ class GPTModel(HybridBlock):
         onehot = np.one_hot(valid_length.astype("int32") - 1, T,
                             dtype=str(logits.dtype))
         last = np.einsum("btv,bt->bv", logits, onehot)
-        k_pool, v_pool = self._scatter_pages(
-            np.stack(ks, axis=1), np.stack(vs, axis=1), valid_length,
-            start, page_table, k_pool, v_pool)
         return last, k_pool, v_pool
 
     def forward_decode_paged(self, tokens, positions, page_table,
@@ -529,13 +575,19 @@ class GPTModel(HybridBlock):
         positions : (S,) int32 — column 0's write position (= current
             length); column i lands at positions + i.
         page_table : (S, W+1) int32 row per slot (sentinel = num_pages).
+
+        Each layer writes its S*K new k/v rows into the pool first (a
+        read-modify-write of the pages they land in, ``_write_rows``)
+        and then attends the gathered view, which already holds them. A
+        row whose page id is the sentinel (an inactive slot, a position
+        past the table) writes nothing.
         Returns (logits (S, K, V), k_pool', v_pool') where logits[:, i]
         scores the token AFTER tokens[:, i] — greedy verification accepts
         the longest draft prefix that matches argmax(logits).
         """
         from ... import numpy as np
 
-        NP_, L, H, P, D = k_pool.shape
+        H, P, D = k_pool.shape[2:]
         S, K = tokens.shape
         W = page_table.shape[1] - 1
         WP = W * P
@@ -544,43 +596,34 @@ class GPTModel(HybridBlock):
         x = self._embed(tokens, np.minimum(q_pos, self.max_length - 1))
         ar = np.arange(WP, dtype="int32").reshape(1, 1, WP)
         mask = (ar <= q_pos.reshape(S, K, 1)).reshape(S, 1, K, WP)
-        pos_oh = np.one_hot(q_pos, WP, dtype=self._dtype)         # (S, K, WP)
-        wrote = np.einsum("skl->sl", pos_oh).reshape(S, WP, 1) > 0
         flat_ids = np.reshape(
             npx.slice_axis(page_table, axis=1, begin=0, end=W), (-1,))
-        # pool write routing (shared by every layer)
-        page_slot = np.minimum(q_pos // P, W)
-        page_id = np.take_along_axis(page_table, page_slot, axis=1)
-        page_oh = np.one_hot(page_id, NP_, dtype=self._dtype)     # (S, K, NP)
-        off_oh = np.one_hot(q_pos % P, P, dtype=self._dtype)      # (S, K, P)
-        cells = np.einsum("skp,sko->po", page_oh, off_oh)
-        cell_mask = cells.reshape(NP_, 1, 1, P, 1) > 0
-        nk, nv = [], []
+        # pool write routing (shared by every layer): the J pages a
+        # slot's K rows can land in, and for each row the cell it takes
+        # (the cell whose position is k past the slot's)
+        J = 1 + -(-(K - 1) // P)
+        col = pos2 // P + np.arange(J, dtype="int32").reshape(1, J)  # (S, J)
+        page_id = np.reshape(np.take_along_axis(
+            page_table, np.minimum(col, W), axis=1), (-1,))
+        past = (col * P - pos2).reshape(S, J, 1, 1, 1) \
+            + np.arange(P, dtype="int32").reshape(1, 1, 1, P, 1)
+        hits = [past == k for k in range(K)]
         for i, blk in enumerate(self.blocks):
+            lay = self._layer_id(i)
             q, k, v = blk._qkv(x)
-            nk.append(np.reshape(k, (S, K, H, D)))
-            nv.append(np.reshape(v, (S, K, H, D)))
-            viewk = self._gather_page_view(
-                self._pool_layer(k_pool, i), flat_ids, W)
-            viewv = self._gather_page_view(
-                self._pool_layer(v_pool, i), flat_ids, W)
-            viewk = np.where(wrote, np.einsum("skl,sku->slu", pos_oh, k),
-                             viewk)
-            viewv = np.where(wrote, np.einsum("skl,sku->slu", pos_oh, v),
-                             viewv)
+            k_pool = self._write_rows(k_pool, lay, page_id, hits,
+                                      np.reshape(k, (S, K, H, D)))
+            v_pool = self._write_rows(v_pool, lay, page_id, hits,
+                                      np.reshape(v, (S, K, H, D)))
+            viewk = self._gather_page_view(k_pool, lay, flat_ids, W)
+            viewv = self._gather_page_view(v_pool, lay, flat_ids, W)
             attn = npx.multihead_attention(q, viewk, viewv, mask=mask,
                                            num_heads=_local_heads(
                                                self._num_heads),
                                            causal=False)
             x = blk._post_attention(x, attn)
         x = self.ln_f(x)
-        logits = self._lm_logits(x)                               # (S, K, V)
-        knew = np.stack(nk, axis=1)                               # (S,L,K,H,D)
-        vnew = np.stack(nv, axis=1)
-        ck = np.einsum("skp,sko,slkhd->plhod", page_oh, off_oh, knew)
-        cv = np.einsum("skp,sko,slkhd->plhod", page_oh, off_oh, vnew)
-        return (logits, np.where(cell_mask, ck, k_pool),
-                np.where(cell_mask, cv, v_pool))
+        return self._lm_logits(x), k_pool, v_pool                 # (S, K, V)
 
     # -- generation ----------------------------------------------------------
     def _sample(self, logits, temperature):

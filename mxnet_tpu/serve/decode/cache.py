@@ -147,9 +147,9 @@ class PagedKVCache:
     head_dim]``; a slot's cache is one int32 page-table row of width
     ``W+1`` (W = ceil(max_len / page_tokens)) mapping logical page index
     to pool page id. ``trash`` (= num_pages, one past the pool) marks
-    unmapped columns: in-program, ``one_hot(trash, num_pages)`` is the
-    zero vector so writes routed there vanish, and gathers clip to a real
-    page whose positions the kv mask never admits. Column W is
+    unmapped columns: in-program, an indexed update routed there is out
+    of range and is dropped, and gathers clip to a real page whose
+    positions the kv mask never admits. Column W is
     permanently trash — it absorbs the (clipped) routing of speculative
     writes past the slot's capacity. Memory now scales with live tokens:
     ``nbytes`` at equal capacity shrinks by the pool/reservation ratio,

@@ -5,16 +5,17 @@ applied to decoding — the host only feeds operands):
 
 - ``prefill(bucket_batch, bucket_len)``: forward the whole right-padded
   prompt batch once (the exact flash-path compute of the plain forward),
-  argmax the logits at each row's last valid position, and scatter the
-  per-layer k/v page-chunk-wise into the pool pages each row's page-table
-  operand maps. One traced graph per length bucket, compiled per batch
-  bucket.
+  argmax the logits at each row's last valid position, and write the
+  per-layer k/v, cut into whole pages, into the pool pages each row's
+  page-table operand maps: one indexed update per pool. One traced graph
+  per length bucket, compiled per batch bucket.
 - ``prefill_ext(bucket_batch, bucket_len)``: the radix prefix-cache join
-  — forward only the prompt SUFFIX from a page-aligned ``start`` offset,
-  attending the gathered page view (shared prefix pages already
-  resident) plus the suffix's own k/v, then scatter the suffix pages.
-  Traced only when the prefix cache is enabled.
-- ``decode_tick_k(num_slots, K)``: K tokens for EVERY slot against the
+  — forward only the prompt SUFFIX from a page-aligned ``start`` offset;
+  each layer writes the suffix's pages into the pool and then attends
+  the gathered page view (shared prefix pages already resident, the
+  suffix just written). Traced only when the prefix cache is enabled.
+- ``decode_tick_k(num_slots, K)``: K tokens for EVERY slot — each layer
+  puts its new rows into the pages they land in and attends the
   gathered page view — fixed shape, traced and compiled exactly once.
   K = 1 is the plain tick; K > 1 verifies a K-1-token draft in one
   batched pass (speculative decoding). Static K keeps the program set
@@ -23,7 +24,10 @@ applied to decoding — the host only feeds operands):
 
 All three donate the pool pair (pool in, pool out — a single device
 residency; on backends without donation support XLA falls back to
-copying). ``export``/``from_export`` round-trip the traced graphs through
+copying), and every write into it is an indexed update of whole pages,
+which the TPU compiles in place: no program holds a second value of the
+pool's size. A write routed at the sentinel page id is out of range and
+is dropped. ``export``/``from_export`` round-trip the traced graphs through
 Symbol JSON + a params npz, so a fresh process can serve without the
 model class — the SymbolBlock.imports analog for the decode engine.
 """

@@ -136,3 +136,72 @@ def test_fused_softmax_compiles(chip_kernels):
                              sharding=chip_kernels)
     assert _compiled_kernels(
         lambda x: pk._fused_softmax_impl(x, 128), x) == 1
+
+
+# -- the paged KV pool is updated in place, in the layout the chip keeps it --
+@pytest.fixture(scope="module")
+def serve_programs(chip_kernels):
+    """The serving graphs of a 2-layer model at GPT-2 medium's widths
+    (16 heads x 64, pages of 128 positions). The trace runs the forward
+    eagerly on the CPU, so the kernels are steered back for its length.
+    The pool is too large (128 MiB) for the compiler to stage it in fast
+    memory, as it is in any deployment: a staged pool shows as a copy."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
+    from mxnet_tpu.serve.decode import DecodePrograms
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pk, "_use_pallas", lambda: False)
+    try:
+        mx.random.seed(3)
+        net = GPTModel(vocab_size=512, num_layers=2, units=1024,
+                       num_heads=16, max_length=512, dropout=0.0)
+        net.initialize()
+        return DecodePrograms(net, num_slots=4, max_len=512,
+                              prefill_batch=2, max_prompt_len=128,
+                              min_prompt_bucket=128, page_tokens=128,
+                              kv_pages=128, speculate_k=2,
+                              prefix_cache=True)
+    finally:
+        mp.undo()
+
+
+@pytest.mark.parametrize("family", ["decode", "prefill", "prefill_ext"])
+def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
+    """The chip keeps ``f32[pages, layers, 16, 128, 64]`` with the page's
+    positions as its fastest axis. An update of single positions made the
+    TPU compiler lay the whole pool out anew and back (two copies a pool,
+    27 ms a tick at the benchmark's size); updates of whole pages compile
+    in place. Guard: nothing of the pool's shape but the updates."""
+    import re
+
+    progs = serve_programs
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        tuple(shape), dt, sharding=chip_kernels)
+    pool = sds(progs.cache_shape, jnp.float32)
+    S, Wt = progs.num_slots, progs.table_width
+    if family == "decode":
+        gkey, donate = "decode:2", progs._DECODE_DONATE
+        data = [sds((S, 2), jnp.int32), sds((S,), jnp.int32),
+                sds((S, Wt), jnp.int32)]
+    else:
+        gkey = f"{family}:128"
+        ext = family == "prefill_ext"
+        donate = progs._EXT_DONATE if ext else progs._PREFILL_DONATE
+        data = [sds((2, 128), jnp.int32), sds((2,), jnp.int32)] \
+            + ([sds((2,), jnp.int32)] if ext else []) \
+            + [sds((2, Wt), jnp.int32)]
+    args = data + [pool, pool] + [
+        sds(progs._params[n].shape, progs._params[n].dtype)
+        for n in progs._graph_params[gkey]]
+    text = progs._cops[gkey].lower(*args, donate=donate).compile().as_text()
+    shape = "f32[%s]" % ",".join(str(d) for d in progs.cache_shape)
+    ops = re.findall(r"= %s\S* ([\w\-]+)\(" % re.escape(shape), text)
+    inside = {"parameter", "get-tuple-element", "bitcast", "scatter",
+              "dynamic-update-slice", "fusion", "while"}
+    assert ops and set(ops) <= inside, sorted(set(ops) - inside)
+    # every pool-shaped fusion is an update fused with what feeds it
+    for name in re.findall(r"= %s\S* fusion\(.*calls=%%([\w.\-]+)"
+                           % re.escape(shape), text):
+        body = text.split("%" + name + " (", 1)[1].split("\n}", 1)[0]
+        assert re.search(r" (scatter|dynamic-update-slice)\(", body), name

@@ -491,6 +491,189 @@ def test_paged_pool_doubles_slots_at_equal_bytes(net):
     assert readings[4] == 16 * 2 * 4 * 8 * 8 * 4 * 2
 
 
+# -- pool writes: in place, exact, and nothing but them pool-shaped ----------
+POOL_PAGES = 11      # no other dimension of the toy programs is 11
+POOL_P = 8
+POOL_W = MAX_LEN // POOL_P
+
+
+def _random_pools(net, seed=5):
+    kp, vp = net.init_paged_cache(POOL_PAGES, POOL_P)
+    rs = onp.random.RandomState(seed)
+    return (rs.standard_normal(kp.shape).astype("float32"),
+            rs.standard_normal(vp.shape).astype("float32"))
+
+
+def _table(rows):
+    """Page-table rows of width W+1 from leading page ids; the rest (and
+    always column W) is the sentinel."""
+    tab = onp.full((len(rows), POOL_W + 1), POOL_PAGES, dtype="int32")
+    for r, ids in enumerate(rows):
+        tab[r, :len(ids)] = ids
+    return tab
+
+
+def _record_qkv(net, monkeypatch):
+    """Every block's ``_qkv`` also appends its (k, v) to a per-layer list."""
+    seen = []
+    for blk in net.blocks:
+        def qkv(x, _orig=blk._qkv):
+            q, k, v = _orig(x)
+            seen.append((k.asnumpy(), v.asnumpy()))
+            return q, k, v
+        monkeypatch.setattr(blk, "_qkv", qkv, raising=False)
+    return seen
+
+
+def _assert_only_cells(pool_in, pool_out, cells, what):
+    """``cells``: {(page, layer, offset): (H, D) row}. Those cells hold
+    their rows exactly; every other cell is bit-identical to the input."""
+    expect = pool_in.copy()
+    for (page, layer, off), row in cells.items():
+        expect[page, layer, :, off] = row
+    onp.testing.assert_array_equal(pool_out, expect, err_msg=what)
+
+
+@pytest.mark.parametrize("K", [1, 2])
+def test_decode_tick_writes_only_its_rows(net, monkeypatch, K):
+    H, D = 4, 8
+    kp, vp = _random_pools(net)
+    # slot 0 sits at offset P-1 (K=2 spills into its next page), slot 1 is
+    # inactive (all sentinel), slot 2's second row maps to column W, slot
+    # 3 writes mid-page
+    table = _table([[3, 5], [], [0, 1, 2, 4, 6, 7, 8, 9], [1, 10]])
+    positions = onp.array([POOL_P - 1, 5, MAX_LEN - 1, 10], "int32")
+    tokens = onp.arange(1, 4 * K + 1, dtype="int32").reshape(4, K)
+    seen = _record_qkv(net, monkeypatch)
+    _, kp2, vp2 = net.forward_decode_paged(
+        mx.np.array(tokens), mx.np.array(positions), mx.np.array(table),
+        mx.np.array(kp), mx.np.array(vp))
+    assert len(seen) == 2
+    for which, (before, after) in enumerate([(kp, kp2.asnumpy()),
+                                             (vp, vp2.asnumpy())]):
+        cells = {}
+        for layer in range(2):
+            rows = seen[layer][which].reshape(4, K, H, D)
+            for s in range(4):
+                for k in range(K):
+                    pos = int(positions[s]) + k
+                    page = int(table[s, min(pos // POOL_P, POOL_W)])
+                    if page < POOL_PAGES:
+                        cells[(page, layer, pos % POOL_P)] = rows[s, k]
+        assert len(cells) == 2 * {1: 3, 2: 5}[K]
+        if K == 2:   # slot 0's rows landed in two pages
+            assert (3, 0, POOL_P - 1) in cells and (5, 0, 0) in cells
+        _assert_only_cells(before, after, cells, "kv"[which])
+
+
+def _prefill_case():
+    # row 0: 12 of 16 tokens, both chunks live; row 1: 5 tokens, chunk 1 is
+    # past valid_length; row 2: full, but chunk 0's column is the sentinel
+    tokens = onp.random.RandomState(3).randint(1, VOCAB, (3, 16)) \
+        .astype("int32")
+    valid = onp.array([12, 5, 16], "int32")
+    return tokens, valid
+
+
+def _assert_pages(pool_in, pool_out, pages, what):
+    """``pages``: {page: (layers, heads, n, head_dim) k/v of its first n
+    positions}. Pages not named are bit-identical to the input."""
+    out = pool_out.copy()
+    for page, want in pages.items():
+        n = want.shape[2]
+        onp.testing.assert_array_equal(out[page][:, :, :n], want,
+                                       err_msg=f"{what} page {page}")
+        out[page] = pool_in[page]
+    onp.testing.assert_array_equal(out, pool_in, err_msg=what)
+
+
+def test_prefill_scatter_writes_only_live_pages(net):
+    kp, vp = _random_pools(net)
+    tokens, valid = _prefill_case()
+    table = _table([[2, 4], [6, 7], [POOL_PAGES, 8]])
+    _, k, v = net.forward_prefill(mx.np.array(tokens), mx.np.array(valid))
+    _, kp2, vp2 = net.forward_prefill_paged(
+        mx.np.array(tokens), mx.np.array(valid), mx.np.array(table),
+        mx.np.array(kp), mx.np.array(vp))
+    for what, new, before, after in (("k", k.asnumpy(), kp, kp2.asnumpy()),
+                                     ("v", v.asnumpy(), vp, vp2.asnumpy())):
+        _assert_pages(before, after, {
+            2: new[0][:, :, 0:8], 4: new[0][:, :, 8:12],
+            6: new[1][:, :, 0:5], 8: new[2][:, :, 8:16]}, what)
+
+
+def test_prefix_join_scatter_lands_at_start(net, monkeypatch):
+    H, D = 4, 8
+    kp, vp = _random_pools(net)
+    tokens, valid = _prefill_case()
+    # suffixes from page-aligned starts: pages start//P + j of each row
+    start = onp.array([8, 24, 0], "int32")
+    table = _table([[0, 2, 4], [1, 3, 5, 6, 7], [POOL_PAGES, 8]])
+    seen = _record_qkv(net, monkeypatch)
+    _, kp2, vp2 = net.forward_prefill_join(
+        mx.np.array(tokens), mx.np.array(valid), mx.np.array(start),
+        mx.np.array(table), mx.np.array(kp), mx.np.array(vp))
+    for which, (before, after) in enumerate([(kp, kp2.asnumpy()),
+                                             (vp, vp2.asnumpy())]):
+        # (B, T, units) per layer -> (B, layers, heads, T, head_dim)
+        new = onp.stack([seen[layer][which].reshape(3, 16, H, D)
+                         .transpose(0, 2, 1, 3) for layer in range(2)], 1)
+        _assert_pages(before, after, {
+            2: new[0][:, :, 0:8], 4: new[0][:, :, 8:12],
+            6: new[1][:, :, 0:5], 8: new[2][:, :, 8:16]}, "kv"[which])
+
+
+def _hlo_instructions(text):
+    """(result shape, opcode, line) of every instruction of an HLO text."""
+    import re
+
+    pat = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = (\S+) ([\w\-]+)\(")
+    return [(m.group(1), m.group(2), ln) for ln in text.splitlines()
+            for m in [pat.match(ln)] if m]
+
+
+@pytest.mark.parametrize("family", ["decode", "prefill", "prefill_ext"])
+def test_nothing_but_the_update_is_pool_shaped(net, family):
+    """Structural guard on the lowered programs: a one-hot matmul over
+    pages, a select over the pool or a copy of it would show here."""
+    import re
+
+    from mxnet_tpu.serve.decode import DecodePrograms
+
+    progs = DecodePrograms(net, num_slots=3, max_len=MAX_LEN,
+                           prefill_batch=2, max_prompt_len=16,
+                           page_tokens=POOL_P, kv_pages=POOL_PAGES,
+                           speculate_k=2, prefix_cache=True)
+    assert progs.cache_shape == (POOL_PAGES, 2, 4, POOL_P, 8)
+    pool = mx.np.zeros(progs.cache_shape)._data
+    i32 = lambda *shape: onp.zeros(shape, "int32")  # noqa: E731
+    Wt = progs.table_width
+    if family == "decode":
+        gkey = "decode:2"
+        data = [i32(3, 2), i32(3), i32(3, Wt)]
+    else:
+        gkey = f"{family}:16"
+        data = [i32(2, 16), i32(2)] + ([i32(2)] if family == "prefill_ext"
+                                       else []) + [i32(2, Wt)]
+    args = data + [pool, pool] + [progs._params[n]
+                                  for n in progs._graph_params[gkey]]
+    text = progs._cops[gkey].lower(*args).as_text(dialect="hlo")
+    pool_shape = "f32[%s]" % ",".join(str(d) for d in progs.cache_shape)
+    instrs = _hlo_instructions(text)
+    # (a ``call`` only wraps one registered op, whose own instructions
+    # are listed in the callee)
+    shaped = [(op, ln) for shape, op, ln in instrs
+              if shape.startswith(pool_shape)
+              and op not in ("parameter", "call")]
+    assert shaped, "the program no longer updates the pool"
+    assert all(op in ("scatter", "dynamic-update-slice")
+               for op, _ in shaped), shaped
+    dots = [ln for _, op, ln in instrs if op == "dot"]
+    assert dots
+    assert not [ln for ln in dots
+                if re.search(r"[\[,]%d[,\]]" % POOL_PAGES, ln)]
+
+
 # -- warmup manifest / export round trips -----------------------------------
 def test_decode_manifest_roundtrip(net, tmp_path):
     tm.enable()
