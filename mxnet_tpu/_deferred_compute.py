@@ -22,10 +22,18 @@ import contextlib
 import threading
 import weakref
 
+from .attribute import AttrScope
 from .base import MXNetError
 from .symbol.symbol import SymNode, Literal
 
 __all__ = ["is_tracing", "context", "set_variable"]
+
+# ``with AttrScope(__scope__="gdn"):`` around a part of a block's forward:
+# under a trace every op recorded inside carries the name, and the compiled
+# program replays it under ``jax.named_scope``, so the name reaches each
+# instruction's ``op_name`` in the HLO and a device trace can be cut by it.
+# Eagerly it does nothing.
+SCOPE_ATTR = "__scope__"
 
 
 class _TraceCtx:
@@ -121,7 +129,11 @@ def _record_op(op, attrs, inputs, outputs) -> None:
             entries.append(Literal(x))
     if op.needs_rng:
         ctx.uses_rng = True
-    node = SymNode(op=op, attrs=attrs, inputs=entries, nout=len(outputs))
+    # an ``AttrScope(__scope__=...)`` around part of a forward names its
+    # operations in the compiled program (cached_op.build_executor)
+    scope = AttrScope.current()._attrs.get(SCOPE_ATTR)
+    node = SymNode(op=op, attrs=attrs, inputs=entries, nout=len(outputs),
+                   attr_dict={SCOPE_ATTR: scope} if scope else None)
     for i, o in enumerate(outputs):
         o._dc_sym = (node, i)
         ctx.marked.append(weakref.ref(o))

@@ -16,11 +16,16 @@ updates (BN moving stats) are extra outputs written back post-call.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 
 from .base import MXNetError
 from .ops.registry import Op, invoke
 from .symbol.symbol import Literal, Symbol, topo_sort
+from ._deferred_compute import SCOPE_ATTR
+
+_NO_SCOPE = contextlib.nullcontext()
 
 __all__ = ["CachedOp", "build_executor", "trace"]
 
@@ -58,7 +63,9 @@ def build_executor(out_entries, var_nodes):
                 if node.op.needs_rng:
                     sub = jax.random.fold_in(key, rng_index[id(node)])
                     ins = [sub] + ins
-                out = node.op.fn(**node.attrs)(*ins)
+                scope = node.attr_dict.get(SCOPE_ATTR)
+                with jax.named_scope(scope) if scope else _NO_SCOPE:
+                    out = node.op.fn(**node.attrs)(*ins)
                 env[id(node)] = tuple(out) if isinstance(out, (tuple, list)) \
                     else (out,)
         return tuple(env[id(n)][i] for n, i in out_entries)

@@ -7,7 +7,10 @@ from . import rnn_lm
 from .rnn_lm import RNNModel
 from . import gpt
 from .gpt import GPTModel, gpt2_small, gpt2_medium, gpt_tiny
+from . import qwen3_next as _qwen3_next
+from .qwen3_next import Qwen3NextModel, qwen3_next, qwen3_next_tiny
 
 __all__ = ["vision", "get_model", "bert", "bert_base", "bert_large",
            "gpt", "GPTModel", "gpt2_small", "gpt2_medium", "gpt_tiny",
-           "BERTModel", "BERTForPretraining", "rnn_lm", "RNNModel"]
+           "BERTModel", "BERTForPretraining", "rnn_lm", "RNNModel",
+           "Qwen3NextModel", "qwen3_next", "qwen3_next_tiny"]

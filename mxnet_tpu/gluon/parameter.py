@@ -164,7 +164,26 @@ class Parameter:
         d = self.data()
         if d._grad is None:
             raise MXNetError(f"parameter {self.name} has grad_req='null'")
+        if d._grad._data.shape != d._data.shape:
+            # released (release_grad): whoever asks gets the full zeros
+            import jax.numpy as jnp
+
+            d._grad._set_data(jnp.zeros(d._data.shape, d._grad._data.dtype))
         return d._grad
+
+    def release_grad(self):
+        """Let go of the gradient buffer's device memory (4 bytes an
+        element in float32). A scalar zero stands in for it: an eager
+        backward binds (``write``) or broadcasts into (``add``) a gradient
+        of full size again, ``grad()`` gives full zeros back to whoever
+        asks, ``zero_grad`` leaves it released. For a caller that keeps its
+        gradients elsewhere, as the compiled train step does inside its
+        program."""
+        g = None if self._data is None else self._data._grad
+        if g is not None and g._data.ndim:
+            import jax.numpy as jnp
+
+            g._set_data(jnp.zeros((), g._data.dtype))
 
     def list_grad(self):
         return [self.grad()]

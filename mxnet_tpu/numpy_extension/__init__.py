@@ -256,6 +256,52 @@ def multihead_attention(query, key, value, mask=None, num_heads=1,
                num_kv_heads=num_kv_heads)
 
 
+def rope(data, rotary_dim=None, theta=10000.0, offset=0):
+    """Rotary position embedding (rotate-half) on the first ``rotary_dim``
+    entries of the last axis of ``data`` (B, T, H, D); TPU-native extension."""
+    return _op("rope", _nd(data), rotary_dim=rotary_dim, theta=theta,
+               offset=offset)
+
+
+def causal_conv1d(data, weight, activation=None):
+    """Depthwise causal convolution over time: ``data`` (B, T, C), ``weight``
+    (C, K), zeros left of the sequence, no bias; ``activation`` None or
+    "silu". TPU-native extension; see ops/delta_rule.py."""
+    return _op("causal_conv1d", _nd(data), _nd(weight),
+               activation=activation)
+
+
+def gated_delta_rule(query, key, value, g, beta, chunk=64, scale=None,
+                     l2norm=True):
+    """Linear attention by the gated delta rule, computed in chunks:
+    ``S = exp(g_t) S; S += k_t (beta_t (v_t - S^T k_t))^T; o_t = S^T q_t``
+    per value head. ``query``/``key``: (B, T, H_k, d_k); ``value``:
+    (B, T, H_v, d_v); ``g`` (log-decay) and ``beta``: (B, T, H_v).
+    TPU-native extension; see ops/delta_rule.py."""
+    return _op("gated_delta_rule", _nd(query), _nd(key), _nd(value), _nd(g),
+               _nd(beta), chunk=chunk, scale=scale, l2norm=l2norm)
+
+
+def moe_router(data, weight, top_k=1, norm_topk=True):
+    """``softmax(data weight^T)`` in float32 over the router's full width,
+    its ``top_k`` largest renormalised: ``(weights (N, k), experts (N, k)
+    int32, counts (E,))``. TPU-native extension; see ops/moe.py."""
+    return _op("moe_router", _nd(data), _nd(weight), top_k=top_k,
+               norm_topk=norm_topk)
+
+
+def routed_experts(data, weights, experts, gate_up, down, experts_held=None,
+                   tile=128):
+    """Dropless routed SwiGLU experts of a layer that holds the experts
+    ``experts_held = (lo, hi)`` of the router's width: the weighted sum over
+    each token's chosen experts that are held. TPU-native extension; see
+    ops/moe.py."""
+    return _op("routed_experts", _nd(data), _nd(weights), _nd(experts),
+               _nd(gate_up), _nd(down),
+               experts_held=None if experts_held is None
+               else tuple(int(e) for e in experts_held), tile=tile)
+
+
 def adaptive_avg_pool2d(data, output_size=1):
     return _op("adaptive_avg_pool2d", _nd(data), output_size=output_size)
 

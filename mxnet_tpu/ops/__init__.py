@@ -15,6 +15,8 @@ from . import graph_image_ops  # noqa: F401 — sldwin attention, dgl, image/cv
 from . import npi_manip  # noqa: F401 — dynamic-shape manip, control flow, contrib
 from . import warp_ops  # noqa: F401 — STN/deformable/correlation tier
 from . import tp_collectives  # noqa: F401 — megatron tp collectives
+from . import delta_rule  # noqa: F401 — chunked gated delta rule, causal conv
+from . import moe  # noqa: F401 — router and dropless routed experts
 from . import aliases as _aliases  # reference-name aliases (NNVM add_alias analog)
 
 _aliases._register_all()

@@ -596,6 +596,29 @@ def _multihead_attention(num_heads=1, dropout=0.0, causal=False, scale=None,
     return f
 
 
+@register("rope")
+def _rope(rotary_dim=None, theta=10000.0, offset=0):
+    """Rotary position embedding, rotate-half convention, on the first
+    ``rotary_dim`` entries of the last axis (default: all of it); the rest
+    passes through. ``x``: (B, T, H, D); position t is ``offset + t``."""
+    def f(x):
+        D, T = x.shape[-1], x.shape[1]
+        rot = D if rotary_dim is None else int(rotary_dim)
+        if rot % 2 or not 0 < rot <= D:
+            raise MXNetError(f"rope: rotary_dim {rot} is not an even number "
+                             f"in (0, {D}]")
+        half = rot // 2
+        inv = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        ang = (jnp.arange(T, dtype=jnp.float32) + offset)[:, None] * inv
+        cos = jnp.cos(ang)[None, :, None].astype(x.dtype)
+        sin = jnp.sin(ang)[None, :, None].astype(x.dtype)
+        x1, x2 = x[..., :half], x[..., half:rot]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                                x[..., rot:]], axis=-1)
+
+    return f
+
+
 @register("flash_attention")
 def _flash_attention_op(num_heads=1, causal=False, scale=None):
     def f(q, k, v, *segments):

@@ -229,6 +229,24 @@ except Exception:  # noqa: BLE001
     _HAVE_PALLAS = False
 
 
+# Mosaic's scoped VMEM default on the chip; a kernel that keeps whole
+# sequences resident asks for more only past this (v5e has 128 MiB)
+_VMEM_SCOPED_DEFAULT = 16 * 2**20
+
+
+def _vmem_params(resident_bytes):
+    """``compiler_params`` for a kernel whose double-buffered resident
+    operands take ``resident_bytes``: nothing while the chip's default scoped
+    limit holds them (the programs of shorter sequences stay as they were),
+    else a limit with room for the blocks and scratch beside them. At
+    T 4096, D 256 in float32 one head's K and V are 4 MiB each, 16 MiB
+    double-buffered, and the default refuses the kernel."""
+    if resident_bytes + 4 * 2**20 <= _VMEM_SCOPED_DEFAULT:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(resident_bytes + 16 * 2**20, 100 * 2**20))}
+
+
 def _flash_attention_tpu(q, k, v, scale, causal, block_q, block_k,
                          return_lse=False, q_seg=None, k_seg=None):
     """q,k,v: (B, H, T, D) with T % block == 0, D % 128 == 0 (pre-padded).
@@ -289,6 +307,7 @@ def _flash_attention_tpu(q, k, v, scale, causal, block_q, block_k,
             transcendentals=b * h * tq * tk,
         ),
         interpret=_interpret(),
+        **_vmem_params(4 * tk * d * kr.dtype.itemsize),
     )(*operands)
     out = out.reshape(b, h, tq, d)
     if return_lse:
@@ -706,6 +725,7 @@ def _flash_bwd_tpu(q, k, v, out, lse, g, scale, causal, block_q, block_k,
             transcendentals=b * h * tq * tk,
         ),
         interpret=_interpret(),
+        **_vmem_params(4 * tq * d * qr.dtype.itemsize),
     )(*dkv_operands)
     dk, dv = dkv
 
@@ -746,6 +766,7 @@ def _flash_bwd_tpu(q, k, v, out, lse, g, scale, causal, block_q, block_k,
             transcendentals=b * h * tq * tk,
         ),
         interpret=_interpret(),
+        **_vmem_params(4 * tk * d * kr.dtype.itemsize),
     )(*dq_operands)
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
             dv.reshape(b, h, tk, d))

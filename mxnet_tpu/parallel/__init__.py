@@ -21,7 +21,7 @@ from .partition import (match_partition_rules, named_tree_map, fsdp_rules,
 from .learner import Learner, to_optax
 from .ring_attention import ring_attention, ring_attention_sharded
 from .pipeline import pipeline_apply, pipeline_sharded
-from .moe import moe_apply, moe_sharded
+from .moe import moe_apply, moe_sharded, RoutedExperts, TopKRouter
 from .five_axis import (build_five_axis_train_step, init_five_axis_params,
                         five_axis_specs)
 
@@ -30,7 +30,8 @@ __all__ = ["make_mesh", "default_mesh", "replicated", "shard_batch",
            "reduce_scatter", "ppermute", "axis_index", "axis_size",
            "Learner", "to_optax", "ring_attention",
            "ring_attention_sharded", "pipeline_apply", "pipeline_sharded",
-           "moe_apply", "moe_sharded", "build_five_axis_train_step",
+           "moe_apply", "moe_sharded", "RoutedExperts", "TopKRouter",
+           "build_five_axis_train_step",
            "init_five_axis_params", "five_axis_specs",
            "match_partition_rules", "named_tree_map", "fsdp_rules",
            "spec_axes"]

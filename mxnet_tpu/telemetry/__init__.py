@@ -38,6 +38,7 @@ from .stall import StallMonitor
 from . import costs as _costs
 from . import memory as _memory
 from . import numerics as _numerics
+from . import moe as _moe
 
 __all__ = ["enable", "disable", "is_enabled", "configure", "reset",
            "counter", "gauge", "timer", "histogram", "metrics", "event",
@@ -50,7 +51,7 @@ __all__ = ["enable", "disable", "is_enabled", "configure", "reset",
            "record_program_memory", "program_memory", "memory_report",
            "check_memory_admission", "memory_oom_forensics",
            "memory_ledger_text", "numerics_mode", "record_step_health",
-           "numerics_report",
+           "numerics_report", "moe_report",
            "start_exporter", "stop_exporter", "exporter_url",
            "stall_heartbeat", "start_stall_watchdog", "stop_stall_watchdog",
            "stall_stats",
@@ -413,6 +414,13 @@ def record_step_health(groups, gnorms, max_upds, nonfin, group_norms=None,
 def numerics_report():
     """Host-side summary of the in-program numerics monitor."""
     return _numerics.numerics_report()
+
+
+def moe_report():
+    """Expert load of the live routed layers in their last step, read from
+    the device now (see telemetry/moe.py); also sets the ``moe.*`` gauges.
+    None where the process holds no routed layer."""
+    return _moe.report(REGISTRY)
 
 
 def device_peak_flops():

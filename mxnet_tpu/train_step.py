@@ -101,7 +101,20 @@ from .base import MXNetError, warn_once
 from . import telemetry as _telemetry
 from .telemetry import span as _span
 
-__all__ = ["CompiledTrainStep"]
+__all__ = ["CompiledTrainStep", "compiled_modules"]
+
+_COMPILED = {}   # HLO module name -> the newest jax Compiled of that name
+
+
+def compiled_modules():
+    """``{HLO module name: jax Compiled}``: the newest program of each name
+    (``jit_mxtpu_train_step``, ``jit_mxtpu_train_step_k<K>``) that a compiled
+    step of this process has built. The accessor for a tool that holds no
+    step instance, or outlives it, such as a reader that joins a device
+    trace (events named by instruction) with the compiled text (where each
+    instruction's ``op_name`` carries its named scopes). A handful of
+    entries at most; the steps pay one dict store a compile."""
+    return dict(_COMPILED)
 
 
 def train_donate_argnums():
@@ -969,12 +982,28 @@ class CompiledTrainStep:
         materialize/release."""
         st = self._fsdp_state
         if st is None:
-            return self._build_program(x, y, pad=pad, k=k, g=g)
+            prog = self._build_program(x, y, pad=pad, k=k, g=g)
+            if prog is not None:
+                self._release_eager_grads()
+            return prog
         st.materialize_into_params()
         try:
             return self._build_program(x, y, pad=pad, k=k, g=g)
         finally:
             st.release_params()
+
+    def _release_eager_grads(self):
+        """Let go of the trainables' eager gradient buffers
+        (``Parameter.release_grad``). ``initialize`` attaches a zero array
+        of the parameter's size to every trainable for the eager tape; the
+        compiled step keeps its gradients inside the program and never
+        writes them, so they are dead device memory: 4 bytes a parameter
+        (2.3 GiB for 626 M parameters, which was what kept a step that fits
+        by ``memory_analysis`` from loading). Under FSDP there is nothing
+        to release: ``release_params`` drops each parameter's array, and its
+        gradient buffer with it."""
+        for i in self._train_idx:
+            self.trainer._params[i].release_grad()
 
     def _make_fsdp_groups(self, train_idx):
         """Expand the partition rules over the named trainables and fold
@@ -2076,6 +2105,7 @@ class CompiledTrainStep:
                 _warnings.filterwarnings("ignore", message=".*donat.*",
                                          category=UserWarning)
                 prog.compiled = prog.fn.lower(*args).compile()
+            _COMPILED["jit_" + prog.fn.__name__] = prog.compiled
             cost = _telemetry.record_program_cost("train_step",
                                                   prog.compiled)
             if cost:

@@ -205,3 +205,101 @@ def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
                            % re.escape(shape), text):
         body = text.split("%" + name + " (", 1)[1].split("\n}", 1)[0]
         assert re.search(r" (scatter|dynamic-update-slice)\(", body), name
+
+
+# -- the Qwen3-Next cell's shapes: one row of 4096, float32 ------------------
+QT = 4096          # qwen3-next-80b-a3b.train-4k: 1 row x 4096 tokens
+QH, QD = 16, 256   # 16 query heads (2 KV heads, repeated) of 256
+
+
+def _q4k(chip):
+    return jax.ShapeDtypeStruct((1, QH, QT, QD), jnp.float32, sharding=chip)
+
+
+def test_flash_forward_compiles_at_head_256_and_4096_positions(chip_kernels):
+    """One head's K and V are 4 MiB each in float32: double-buffered they
+    pass the chip's default 16 MiB of scoped VMEM, and the kernels ask for
+    the room they need (``_vmem_params``) instead of falling to the dense
+    branch, which would hold 16 x 4096 x 4096 scores."""
+    q = _q4k(chip_kernels)
+    assert _compiled_kernels(
+        lambda q, k, v: pk._flash_attention_tpu(
+            q, k, v, QD ** -0.5, True, BQ, BK, return_lse=True),
+        q, q, q) == 1
+
+
+@pytest.mark.parametrize("which", ["dq", "dkv"])
+def test_flash_backward_compiles_at_head_256_and_4096_positions(
+        chip_kernels, which):
+    q = _q4k(chip_kernels)
+    lse = jax.ShapeDtypeStruct((1, QH, QT, 1), jnp.float32,
+                               sharding=chip_kernels)
+    pick = (lambda dq, dk, dv: dq) if which == "dq" \
+        else (lambda dq, dk, dv: (dk, dv))
+    assert _compiled_kernels(
+        lambda q, k, v, o, l, g: pick(*pk._flash_bwd_tpu(
+            q, k, v, o, l, g, QD ** -0.5, True, BQ, BK)),
+        q, q, q, q, lse, q) == 1
+
+
+def test_short_sequences_keep_the_default_vmem_limit():
+    """The programs of the cells that were there do not change."""
+    assert pk._vmem_params(4 * 1024 * 64 * 4) == {}
+    assert "compiler_params" in pk._vmem_params(4 * QT * QD * 4)
+
+
+def test_gated_attention_step_holds_no_score_matrix(chip_kernels):
+    """Forward and backward of the attention op as the model calls it
+    (16 query heads on 2 KV heads, causal): three kernels, and no array of
+    16 x 4096 x 4096 anywhere in the compiled text."""
+    from mxnet_tpu.ops.registry import get_op
+
+    attn = get_op("multihead_attention")._make_fn(
+        num_heads=QH, num_kv_heads=2, causal=True, scale=QD ** -0.5)
+    q = jax.ShapeDtypeStruct((1, QT, QH * QD), jnp.float32,
+                             sharding=chip_kernels)
+    kv = jax.ShapeDtypeStruct((1, QT, 2 * QD), jnp.float32,
+                              sharding=chip_kernels)
+    text = jax.jit(jax.value_and_grad(
+        lambda q, k, v: attn(q, k, v).sum(), argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+    import re
+
+    assert text.count("tpu_custom_call") >= 3
+    # (1, 4096, 16 x 256) is the layer's own input; scores would be
+    # heads x positions x positions
+    assert not re.search(r"\[(1,)?(16|2),4096,4096\]", text)
+
+
+def test_chunked_delta_rule_compiles_at_the_cells_shapes(chip_kernels):
+    """16 key heads and 32 value heads of 128 over 4096 positions in chunks
+    of 64, forward and backward (XLA: no kernel), within the chip's memory."""
+    from mxnet_tpu.ops.registry import get_op
+
+    rule = get_op("gated_delta_rule")._make_fn(chunk=64)
+    sds = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.float32, sharding=chip_kernels)
+    qk, v, gb = sds(1, QT, 16, 128), sds(1, QT, 32, 128), sds(1, QT, 32)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: rule(*a).sum(), argnums=(0, 1, 2, 3, 4))).lower(
+            qk, qk, v, gb, gb).compile()
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 3 * 2**30
+
+
+def test_routed_experts_compile_at_the_cells_shapes(chip_kernels):
+    """4096 tokens, top-10 of 512, experts 0-31 of width 512 held: the
+    dropless grouped products, forward and backward, as loops over the tiles
+    in use (a ``while`` each), and no buffer of tokens x top-k rows."""
+    from mxnet_tpu.ops.registry import get_op
+
+    op = get_op("routed_experts")._make_fn(experts_held=(0, 32))
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip_kernels)
+    args = (sds((QT, 2048)), sds((QT, 10)), sds((QT, 10), jnp.int32),
+            sds((32, 2048, 1024)), sds((32, 512, 2048)))
+    text = jax.jit(jax.value_and_grad(
+        lambda x, w, i, gu, dn: op(x, w, i, gu, dn).sum(),
+        argnums=(0, 1, 3, 4))).lower(*args).compile().as_text()
+    assert text.count(" while(") >= 2
+    assert "f32[40960,2048]" not in text and "f32[45056,2048]" not in text

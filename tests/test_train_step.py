@@ -330,3 +330,107 @@ def test_bench_train_step_small(monkeypatch):
     assert r["recompiles_after_warmup"] == 0, r
     assert r["compiled_programs"] == 1, r
     assert r["value"] > 0 and r["vs_baseline"] > 0, r
+
+
+def _released_dense(grad_req="write"):
+    """A Dense layer after one compiled step: its gradient buffers are
+    released. Returns (net, loss_fn, trainer, step, x, y, first loss)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import nn
+
+    mx.random.seed(0)
+    net = nn.Dense(4, in_units=6)
+    net.initialize()
+    if grad_req != "write":
+        for p in net.collect_params().values():
+            p.grad_req = grad_req
+    loss_fn = gluon.loss.L2Loss()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1})
+    x = mx.np.array(onp.random.RandomState(0).randn(8, 6).astype("float32"))
+    y = mx.np.array(onp.random.RandomState(1).randn(8, 4).astype("float32"))
+    step = trainer.compile_step(net, loss_fn)
+    first = float(step(x, y).asnumpy())
+    assert step.fallback_reason is None
+    return net, loss_fn, trainer, step, x, y, first
+
+
+def _held_bytes(param):
+    return param.data()._grad._data.nbytes
+
+
+def test_compiled_step_lets_go_of_the_eager_gradient_buffers():
+    """The compiled program keeps its gradients to itself: the per-parameter
+    buffers ``initialize`` attached for the eager tape are dead memory (4
+    bytes a parameter) once the step is built, and ``Parameter.release_grad``
+    leaves a scalar zero in their place. What a user sees does not change:
+    ``grad()`` is a zero array of the parameter's shape, and an eager
+    backward afterwards binds full-size gradients again, so the two ways to
+    train still mix."""
+    from mxnet_tpu import autograd
+
+    net, loss_fn, trainer, step, x, y, first = _released_dense()
+    assert _held_bytes(net.weight) == 4 and _held_bytes(net.bias) == 4
+    with autograd.record():
+        loss = loss_fn(net(x), y).mean()
+    loss.backward()
+    assert _held_bytes(net.weight) == 4 * 24
+    assert net.weight.grad().shape == (4, 6)
+    assert float(onp.abs(net.weight.grad().asnumpy()).max()) > 0
+    trainer.step(1)
+    assert float(step(x, y).asnumpy()) < first
+
+
+def test_a_released_gradient_reads_as_full_zeros():
+    """Whoever asks for a released gradient gets the zeros it held before,
+    at the parameter's shape (and the buffer is back from then on)."""
+    net = _released_dense()[0]
+    assert _held_bytes(net.weight) == 4
+    g = net.weight.grad()
+    assert g.shape == (4, 6) and not g.asnumpy().any()
+    assert _held_bytes(net.weight) == 4 * 24
+    assert [a.shape for a in net.bias.list_grad()] == [(4,)]
+
+
+def test_zero_grad_between_a_compiled_step_and_an_eager_one():
+    """``zero_grad`` on released buffers leaves them released and zero; the
+    eager step after it trains as it would have."""
+    from mxnet_tpu import autograd
+
+    net, loss_fn, trainer, step, x, y, first = _released_dense()
+    net.zero_grad()
+    assert _held_bytes(net.weight) == 4
+    with autograd.record():
+        loss = loss_fn(net(x), y).mean()
+    loss.backward()
+    eager = net.weight.grad().asnumpy().copy()
+    assert eager.shape == (4, 6) and onp.abs(eager).max() > 0
+    net.zero_grad()
+    assert not net.weight.grad().asnumpy().any()
+    assert net.weight.grad().shape == (4, 6)
+    trainer.allreduce_grads()
+    trainer.update(1)
+    assert float(step(x, y).asnumpy()) < first
+
+
+def test_grad_req_add_accumulates_into_a_released_buffer():
+    """``grad_req='add'``: the first eager backward after a compiled step
+    adds into the scalar zero that stands in (a broadcast), the second into
+    the full buffer: twice the gradient of one backward."""
+    from mxnet_tpu import autograd
+
+    net, loss_fn, trainer, step, x, y, _ = _released_dense("add")
+    assert _held_bytes(net.weight) == 4
+    for _ in range(2):
+        with autograd.record():
+            loss = loss_fn(net(x), y).mean()
+        loss.backward()
+    twice = net.weight.grad().asnumpy().copy()
+    net.zero_grad()
+    with autograd.record():
+        loss = loss_fn(net(x), y).mean()
+    loss.backward()
+    once = net.weight.grad().asnumpy()
+    assert once.shape == (4, 6) and onp.abs(once).max() > 0
+    onp.testing.assert_allclose(twice, 2 * once, rtol=1e-5, atol=1e-7)
