@@ -370,38 +370,50 @@ class GPTModel(HybridBlock):
     #
     # The paged variants replace the per-slot [max_len] reservation with a
     # shared pool of fixed-size pages, each [page_tokens] positions of one
-    # layer-stack:  pool shape [num_pages, layers, heads, page_tokens,
-    # head_dim].  A slot's cache is an int32 page-table ROW of width
-    # W+1 = ceil(max_len/page_tokens)+1 mapping logical page index ->
-    # pool page id; the sentinel id ``num_pages`` (one past the pool)
-    # marks unmapped columns.  Reads gather the row's first W columns into
-    # a contiguous [W*P] view (sentinel clips to a real page whose
-    # positions the kv mask always excludes).  Writes are indexed
-    # updates of the (donated) pool, in place and a WHOLE PAGE of one or
-    # all layers at a time: ``np.index_update`` at the page ids the table
-    # maps.  (The chip keeps the pool with ``page_tokens`` as its fastest
-    # axis, head_dim being half a vector register wide; an update of a
-    # single position makes XLA lay the whole pool out anew and back, an
-    # update of whole pages does not.)  The tick therefore reads the
-    # pages its rows land in, puts the rows in and writes the pages back.
-    # A write routed at the sentinel id (an unmapped column, an inactive
-    # slot, a chunk past ``valid_length``) is out of range — one past the
-    # end, never negative — and jax's ``.at[].set`` drops out-of-range
-    # updates, so it vanishes exactly instead of corrupting a live page.
-    # The tick and the prefix join write each layer's k/v BEFORE that
-    # layer's gather, so the gathered view already holds the new
-    # positions.  Nothing but the updates has the pool's shape, and all
-    # three programs keep fully static shapes, preserving the
-    # zero-recompile serving contract.
+    # layer-stack:  pool shape [num_pages, layers, heads, head_dim,
+    # page_tokens].  A page of one layer keeps its positions along the
+    # LAST axis: that is the layout the chip gives the pool anyway
+    # (head_dim 64 is half a vector register's lanes, so it made
+    # ``page_tokens`` the fastest axis of the older [.., page_tokens,
+    # head_dim] shape), and declared so, the decode kernel takes the pool
+    # as it stands, a page of a layer being one contiguous block in which
+    # ``q . K`` leaves the positions along the lanes.  A slot's cache is
+    # an int32 page-table ROW of width W+1 = ceil(max_len/page_tokens)+1
+    # mapping logical page index -> pool page id; the sentinel id
+    # ``num_pages`` (one past the pool) marks unmapped columns.
+    #
+    # Reads.  The tick never gathers: ``npx.paged_decode_attention`` walks
+    # the pages a slot's row maps, up to the slot's length, where they lie
+    # in the pool (Pallas kernel ``mxtpu_paged_decode`` on the chip, a
+    # gather + mask + softmax of the same numbers elsewhere).  Only the
+    # prefix join, with up to a bucket of queries a row, still gathers the
+    # row's first W columns into a contiguous [W*P] view for the dense
+    # masked attention (``_gather_page_view``; the sentinel clips to a
+    # real page whose positions the mask always excludes).
+    #
+    # Writes are indexed updates of the (donated) pool, in place and a
+    # WHOLE PAGE of one or all layers at a time: ``np.index_update`` at
+    # the page ids the table maps.  (An update of a single position makes
+    # XLA lay the whole pool out anew and back, an update of whole pages
+    # does not.)  The tick therefore reads the pages its rows land in,
+    # puts the rows in and writes the pages back.  A write routed at the
+    # sentinel id (an unmapped column, an inactive slot, a chunk past
+    # ``valid_length``) is out of range — one past the end, never negative
+    # — and jax's ``.at[].set`` drops out-of-range updates, so it vanishes
+    # exactly instead of corrupting a live page.  The tick and the prefix
+    # join write each layer's k/v BEFORE that layer's attention, so the
+    # pool already holds the new positions.  Nothing but the updates has
+    # the pool's shape, and all three programs keep fully static shapes,
+    # preserving the zero-recompile serving contract.
 
     def init_paged_cache(self, num_pages, page_tokens):
         """Preallocated paged KV pool pair, each
-        [num_pages, layers, heads, page_tokens, head_dim]."""
+        [num_pages, layers, heads, head_dim, page_tokens]."""
         from ... import numpy as np
 
         d = self._units // self._num_heads
         shape = (int(num_pages), self._num_layers,
-                 _local_heads(self._num_heads), int(page_tokens), d)
+                 _local_heads(self._num_heads), d, int(page_tokens))
         return (np.zeros(shape, dtype=self._dtype),
                 np.zeros(shape, dtype=self._dtype))
 
@@ -424,10 +436,10 @@ class GPTModel(HybridBlock):
         no reshape may bake the row count."""
         from ... import numpy as np
 
-        H, P, D = pool.shape[2:]
-        view = pool[flat_ids, layer]                     # (rows*W, H, P, D)
-        view = np.transpose(np.reshape(view, (-1, W, H, P, D)),
-                            (0, 1, 3, 2, 4))
+        H, D, P = pool.shape[2:]
+        view = pool[flat_ids, layer]                     # (rows*W, H, D, P)
+        view = np.transpose(np.reshape(view, (-1, W, H, D, P)),
+                            (0, 1, 4, 2, 3))
         return np.reshape(view, (-1, W * P, H * D))
 
     def _scatter_pages(self, k, v, valid_length, start, page_table,
@@ -444,7 +456,7 @@ class GPTModel(HybridBlock):
         two rows of a batch, so no two chunks share a page."""
         from ... import numpy as np
 
-        NP_, P = k_pool.shape[0], k_pool.shape[3]
+        NP_, P = k_pool.shape[0], k_pool.shape[4]
         T = k.shape[-2]
         W = page_table.shape[1] - 1
         J = -(-T // P)
@@ -476,9 +488,10 @@ class GPTModel(HybridBlock):
 
     @staticmethod
     def _page_chunks(x, J, P):
-        """(B, ..., T, D) -> (B*J, ..., P, D): T zero-padded to J pages
-        and the page axis moved forward. -1 keeps the graph
-        batch-polymorphic across compile-time batch buckets."""
+        """(B, ..., T, D) -> (B*J, ..., D, P): T zero-padded to J pages,
+        the page axis moved forward and each page's positions last, as
+        the pool keeps them. -1 keeps the graph batch-polymorphic across
+        compile-time batch buckets."""
         from ... import numpy as np
 
         inner, (T, D) = tuple(x.shape[1:-2]), x.shape[-2:]
@@ -486,20 +499,20 @@ class GPTModel(HybridBlock):
             x = np.pad(x, ((0, 0),) * (x.ndim - 2)
                        + ((0, J * P - T), (0, 0)))
         x = np.moveaxis(np.reshape(x, (-1,) + inner + (J, P, D)), -3, 1)
-        return np.reshape(x, (-1,) + inner + (P, D))
+        return np.reshape(np.swapaxes(x, -1, -2), (-1,) + inner + (D, P))
 
     def _write_rows(self, pool, layer, page_id, hits, rows):
         """Put ``rows`` (S, K, heads, head_dim) into layer ``layer`` (a
         ``_layer_id``) of the pages ``page_id`` (S*J,): read the pages,
-        set row k wherever ``hits[k]`` (S, J, 1, P, 1) says, write them
+        set row k wherever ``hits[k]`` (S, J, 1, 1, P) says, write them
         back. A page that no row hits goes back as it came."""
         from ... import numpy as np
 
         S, K, H, D = rows.shape
-        old = pool[page_id, layer]              # (S*J, H, P, D); clamps
+        old = pool[page_id, layer]              # (S*J, H, D, P); clamps
         new = np.reshape(old, (S, -1) + tuple(old.shape[1:]))
         for k in range(K):
-            new = np.where(hits[k], rows[:, k].reshape(S, 1, H, 1, D), new)
+            new = np.where(hits[k], rows[:, k].reshape(S, 1, H, D, 1), new)
         return self._update_pool(pool, (page_id, layer),
                                  np.reshape(new, old.shape))
 
@@ -529,12 +542,15 @@ class GPTModel(HybridBlock):
         layer first writes the suffix's k/v into pages start//P + j of
         the pool, then each query attends the gathered page view — the
         prefix already in the pool plus the suffix just written — masked
-        to absolute positions <= its own.
+        to absolute positions <= its own. (Up to a bucket of queries a
+        row is a matrix-unit problem: the dense masked attention over the
+        view stays, where the tick's one to K queries a slot read the
+        pages in place.)
         Returns (last_logits (B, V), k_pool', v_pool').
         """
         from ... import numpy as np
 
-        P = k_pool.shape[3]
+        P = k_pool.shape[4]
         B, T = tokens.shape
         W = page_table.shape[1] - 1
         WP = W * P
@@ -578,26 +594,26 @@ class GPTModel(HybridBlock):
 
         Each layer writes its S*K new k/v rows into the pool first (a
         read-modify-write of the pages they land in, ``_write_rows``)
-        and then attends the gathered view, which already holds them. A
-        row whose page id is the sentinel (an inactive slot, a position
-        past the table) writes nothing.
+        and then attends the pool itself, which already holds them:
+        ``npx.paged_decode_attention`` walks the pages each slot's row
+        maps, up to its length, and query i reads positions <=
+        positions + i. A row whose page id is the sentinel (an inactive
+        slot, a position past the table) writes nothing, and a slot with
+        no mapped page attends nothing (its logits are those of a zero
+        attention output; the engine never reads them).
         Returns (logits (S, K, V), k_pool', v_pool') where logits[:, i]
         scores the token AFTER tokens[:, i] — greedy verification accepts
         the longest draft prefix that matches argmax(logits).
         """
         from ... import numpy as np
 
-        H, P, D = k_pool.shape[2:]
+        H, D, P = k_pool.shape[2:]
         S, K = tokens.shape
         W = page_table.shape[1] - 1
-        WP = W * P
-        pos2 = positions.astype("int32").reshape(-1, 1)
+        positions = positions.astype("int32")
+        pos2 = positions.reshape(-1, 1)
         q_pos = pos2 + np.arange(K, dtype="int32").reshape(1, K)  # (S, K)
         x = self._embed(tokens, np.minimum(q_pos, self.max_length - 1))
-        ar = np.arange(WP, dtype="int32").reshape(1, 1, WP)
-        mask = (ar <= q_pos.reshape(S, K, 1)).reshape(S, 1, K, WP)
-        flat_ids = np.reshape(
-            npx.slice_axis(page_table, axis=1, begin=0, end=W), (-1,))
         # pool write routing (shared by every layer): the J pages a
         # slot's K rows can land in, and for each row the cell it takes
         # (the cell whose position is k past the slot's)
@@ -606,7 +622,7 @@ class GPTModel(HybridBlock):
         page_id = np.reshape(np.take_along_axis(
             page_table, np.minimum(col, W), axis=1), (-1,))
         past = (col * P - pos2).reshape(S, J, 1, 1, 1) \
-            + np.arange(P, dtype="int32").reshape(1, 1, 1, P, 1)
+            + np.arange(P, dtype="int32").reshape(1, 1, 1, 1, P)
         hits = [past == k for k in range(K)]
         for i, blk in enumerate(self.blocks):
             lay = self._layer_id(i)
@@ -615,12 +631,9 @@ class GPTModel(HybridBlock):
                                       np.reshape(k, (S, K, H, D)))
             v_pool = self._write_rows(v_pool, lay, page_id, hits,
                                       np.reshape(v, (S, K, H, D)))
-            viewk = self._gather_page_view(k_pool, lay, flat_ids, W)
-            viewv = self._gather_page_view(v_pool, lay, flat_ids, W)
-            attn = npx.multihead_attention(q, viewk, viewv, mask=mask,
-                                           num_heads=_local_heads(
-                                               self._num_heads),
-                                           causal=False)
+            attn = npx.paged_decode_attention(
+                np.reshape(q, (S, K, H, D)), k_pool, v_pool, lay,
+                page_table, positions)
             x = blk._post_attention(x, attn)
         x = self.ln_f(x)
         return self._lm_logits(x), k_pool, v_pool                 # (S, K, V)

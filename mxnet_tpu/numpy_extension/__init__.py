@@ -256,6 +256,20 @@ def multihead_attention(query, key, value, mask=None, num_heads=1,
                num_kv_heads=num_kv_heads)
 
 
+def paged_decode_attention(query, k_pool, v_pool, layer, page_table,
+                           positions, scale=None):
+    """Decode attention read straight from a paged KV pool: ``query``
+    (S, K, Hq, D) — query k of slot s at ``positions[s] + k`` — against
+    the pages ``page_table`` (S, W+1) maps in layer ``layer`` (an int32
+    scalar array) of the pools [pages, layers, Hkv, D, page_tokens].
+    Returns (S, K, Hq*D); an unmapped page contributes nothing, a slot
+    with none returns zeros. TPU-native extension; see
+    ops/pallas_kernels.py."""
+    return _op("paged_decode_attention", _nd(query), _nd(k_pool),
+               _nd(v_pool), _nd(layer), _nd(page_table), _nd(positions),
+               scale=scale)
+
+
 def rope(data, rotary_dim=None, theta=10000.0, offset=0):
     """Rotary position embedding (rotate-half) on the first ``rotary_dim``
     entries of the last axis of ``data`` (B, T, H, D); TPU-native extension."""

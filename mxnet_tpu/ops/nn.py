@@ -596,6 +596,24 @@ def _multihead_attention(num_heads=1, dropout=0.0, causal=False, scale=None,
     return f
 
 
+# decode attention against the paged KV pool — the pages a slot's table row
+# maps are read where they lie (Pallas on TPU), no view is gathered.
+@register("paged_decode_attention", differentiable=False)
+def _paged_decode_attention(scale=None):
+    """``q`` (S, K, Hq, D), the pools [pages, layers, Hkv, D, page_tokens],
+    ``layer`` (int32 scalar operand), ``page_table`` (S, W+1), ``positions``
+    (S,) -> (S, K, Hq*D). Grouped heads follow from the shapes (Hq a
+    multiple of Hkv). See ``pallas_kernels.paged_decode_attention``."""
+    def f(q, k_pool, v_pool, layer, page_table, positions):
+        from .pallas_kernels import paged_decode_attention
+
+        out = paged_decode_attention(q, k_pool, v_pool, layer, page_table,
+                                     positions, scale)
+        return out.reshape(out.shape[:2] + (-1,))
+
+    return f
+
+
 @register("rope")
 def _rope(rotary_dim=None, theta=10000.0, offset=0):
     """Rotary position embedding, rotate-half convention, on the first

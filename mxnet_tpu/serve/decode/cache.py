@@ -143,13 +143,16 @@ class PageAllocator:
 class PagedKVCache:
     """Device-resident paged KV pool pair + the host page tables.
 
-    The pool pair has shape ``[num_pages, layers, heads, page_tokens,
-    head_dim]``; a slot's cache is one int32 page-table row of width
+    The pool pair has shape ``[num_pages, layers, heads, head_dim,
+    page_tokens]`` (a page of a layer keeps its positions along the last
+    axis, as the chip lays it out and the decode kernel reads it); a
+    slot's cache is one int32 page-table row of width
     ``W+1`` (W = ceil(max_len / page_tokens)) mapping logical page index
     to pool page id. ``trash`` (= num_pages, one past the pool) marks
     unmapped columns: in-program, an indexed update routed there is out
-    of range and is dropped, and gathers clip to a real page whose
-    positions the kv mask never admits. Column W is
+    of range and is dropped, the tick's attention passes over such a
+    column, and the prefix join's gather clips to a real page whose
+    positions its mask never admits. Column W is
     permanently trash — it absorbs the (clipped) routing of speculative
     writes past the slot's capacity. Memory now scales with live tokens:
     ``nbytes`` at equal capacity shrinks by the pool/reservation ratio,
@@ -164,9 +167,9 @@ class PagedKVCache:
         if len(shape) != 5:
             raise MXNetError(
                 "paged KV pool shape must be [num_pages, layers, heads, "
-                f"page_tokens, head_dim], got {shape}")
+                f"head_dim, page_tokens], got {shape}")
         self.num_pages = shape[0]
-        self.page_tokens = shape[3]
+        self.page_tokens = shape[4]
         self.num_slots = int(num_slots)
         self.max_len = int(max_len)
         self.pages_per_slot = -(-self.max_len // self.page_tokens)  # W

@@ -13,14 +13,23 @@ applied to decoding — the host only feeds operands):
   — forward only the prompt SUFFIX from a page-aligned ``start`` offset;
   each layer writes the suffix's pages into the pool and then attends
   the gathered page view (shared prefix pages already resident, the
-  suffix just written). Traced only when the prefix cache is enabled.
+  suffix just written): with up to a bucket of queries a row this is a
+  matrix-unit problem, and the only place a view is still gathered.
+  Traced only when the prefix cache is enabled.
 - ``decode_tick_k(num_slots, K)``: K tokens for EVERY slot — each layer
-  puts its new rows into the pages they land in and attends the
-  gathered page view — fixed shape, traced and compiled exactly once.
-  K = 1 is the plain tick; K > 1 verifies a K-1-token draft in one
-  batched pass (speculative decoding). Static K keeps the program set
-  fixed, so steady state never recompiles regardless of drafts,
-  prefix hits, or which requests join or leave.
+  puts its new rows into the pages they land in and attends the pool
+  where it lies: one paged kernel a layer (``mxtpu_paged_decode``, via
+  ``npx.paged_decode_attention``) walks the pages a slot's table row
+  maps and stops at the slot's length — fixed shape, traced and compiled
+  exactly once. K = 1 is the plain tick; K > 1 verifies a K-1-token
+  draft in one batched pass (speculative decoding). Static K keeps the
+  program set fixed, so steady state never recompiles regardless of
+  drafts, prefix hits, or which requests join or leave.
+
+The pool pair is ``[kv_pages, layers, heads, head_dim, page_tokens]``:
+positions along the last axis, the layout the chip keeps and the kernel
+reads without a re-lay (manifest version 3; an artefact exported with
+the older ``[.., page_tokens, head_dim]`` pool is refused, not loaded).
 
 All three donate the pool pair (pool in, pool out — a single device
 residency; on backends without donation support XLA falls back to
@@ -45,7 +54,7 @@ from ..bucketing import bucket_ladder
 
 __all__ = ["DecodePrograms", "load_decode_manifest"]
 
-MANIFEST_VERSION = 2
+MANIFEST_VERSION = 3
 
 
 def load_decode_manifest(path):
@@ -56,8 +65,9 @@ def load_decode_manifest(path):
         raise MXNetError(
             f"unsupported decode manifest in {path}: version="
             f"{m.get('version')!r} kind={m.get('kind')!r} (this build "
-            f"reads version {MANIFEST_VERSION}; pre-paging manifests "
-            "must be re-exported)")
+            f"reads version {MANIFEST_VERSION}; older manifests describe "
+            "another KV pool layout — version 2 kept [pages, layers, "
+            "heads, page_tokens, head_dim] — and must be re-exported)")
     return m
 
 
@@ -128,7 +138,7 @@ class DecodePrograms:
         self._programs = {}     # ("decode", K) | ("prefill"[_ext], B, T)
         self._costs = {}        # program key -> (flops, bytes_accessed)
         self._signatures = {}   # str key -> trace signature
-        self.cache_shape = None  # [kv_pages, layers, heads, page_tokens, hd]
+        self.cache_shape = None  # [kv_pages, layers, heads, hd, page_tokens]
         self.cache_dtype = "float32"
         # tensor parallelism: the model's column-parallel serve layout,
         # traced at per-rank local shapes and replayed under shard_map

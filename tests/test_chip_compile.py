@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture — never at import —
 so that under pytest-xdist only the worker that runs this file loads libtpu.
 All of these tests stay in this one file for the same reason.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -143,9 +145,11 @@ def test_fused_softmax_compiles(chip_kernels):
 def serve_programs(chip_kernels):
     """The serving graphs of a 2-layer model at GPT-2 medium's widths
     (16 heads x 64, pages of 128 positions). The trace runs the forward
-    eagerly on the CPU, so the kernels are steered back for its length.
-    The pool is too large (128 MiB) for the compiler to stage it in fast
-    memory, as it is in any deployment: a staged pool shows as a copy."""
+    eagerly on the CPU, so the kernels are steered back for its length,
+    and the per-op jits forget what they traced then: the compiles below
+    take the kernels, as on the chip. The pool is too large (128 MiB) for
+    the compiler to stage it in fast memory, as it is in any deployment:
+    a staged pool shows as a copy."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
     from mxnet_tpu.serve.decode import DecodePrograms
@@ -157,22 +161,28 @@ def serve_programs(chip_kernels):
         net = GPTModel(vocab_size=512, num_layers=2, units=1024,
                        num_heads=16, max_length=512, dropout=0.0)
         net.initialize()
-        return DecodePrograms(net, num_slots=4, max_len=512,
+        return DecodePrograms(net, num_slots=5, max_len=512,
                               prefill_batch=2, max_prompt_len=128,
                               min_prompt_bucket=128, page_tokens=128,
                               kv_pages=128, speculate_k=2,
                               prefix_cache=True)
     finally:
         mp.undo()
+        jax.clear_caches()
 
 
 @pytest.mark.parametrize("family", ["decode", "prefill", "prefill_ext"])
 def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
-    """The chip keeps ``f32[pages, layers, 16, 128, 64]`` with the page's
-    positions as its fastest axis. An update of single positions made the
-    TPU compiler lay the whole pool out anew and back (two copies a pool,
-    27 ms a tick at the benchmark's size); updates of whole pages compile
-    in place. Guard: nothing of the pool's shape but the updates."""
+    """The pool is ``f32[pages, layers, 16, 64, 128]``, a page's positions
+    as its fastest axis: the layout the chip chose for the older
+    ``[.., 128, 64]`` shape, now the declared one, so that the decode
+    kernel takes the pool as it stands. An update of single positions
+    made the TPU compiler lay the whole pool out anew and back (two
+    copies a pool, 27 ms a tick at the benchmark's size); updates of
+    whole pages compile in place. Guard: nothing of the pool's shape but
+    the updates. The tick besides holds one paged kernel a layer and no
+    array of the gathered view's size (slots x max_len x units): the
+    view, its re-lay and the dense attention over it are gone."""
     import re
 
     progs = serve_programs
@@ -205,6 +215,15 @@ def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
                            % re.escape(shape), text):
         body = text.split("%" + name + " (", 1)[1].split("\n}", 1)[0]
         assert re.search(r" (scatter|dynamic-update-slice)\(", body), name
+    if family != "decode":
+        return
+    assert len(re.findall(r"%mxtpu_paged_decode[.\d]* = ", text)) == 2
+    view = S * (Wt - 1) * 128 * 16 * 64
+    sized = [ln.strip()[:120] for ln in text.splitlines()
+             for m in [re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]",
+                                ln)]
+             if m and math.prod(map(int, m.group(1).split(","))) == view]
+    assert not sized, sized
 
 
 # -- the Qwen3-Next cell's shapes: one row of 4096, float32 ------------------

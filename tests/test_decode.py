@@ -129,7 +129,7 @@ def test_page_allocator_alloc_free_reuse_exhaustion():
 
 
 def test_paged_kv_cache_tables_and_bytes():
-    cache = PagedKVCache((6, 2, 4, 8, 5), "float32", num_slots=3,
+    cache = PagedKVCache((6, 2, 4, 5, 8), "float32", num_slots=3,
                          max_len=16)
     assert cache.page_tokens == 8 and cache.pages_per_slot == 2
     assert cache.trash == 6
@@ -487,7 +487,7 @@ def test_paged_pool_doubles_slots_at_equal_bytes(net):
         finally:
             eng.close()
     assert readings[8] == readings[4]      # 2x slots, equal bytes
-    # pool-sized: [16 pages, 2 layers, 4 heads, 8 tok, 8 dim] f32 x k,v
+    # pool-sized: [16 pages, 2 layers, 4 heads, 8 dim, 8 tok] f32 x k,v
     assert readings[4] == 16 * 2 * 4 * 8 * 8 * 4 * 2
 
 
@@ -530,7 +530,7 @@ def _assert_only_cells(pool_in, pool_out, cells, what):
     their rows exactly; every other cell is bit-identical to the input."""
     expect = pool_in.copy()
     for (page, layer, off), row in cells.items():
-        expect[page, layer, :, off] = row
+        expect[page, layer, :, :, off] = row
     onp.testing.assert_array_equal(pool_out, expect, err_msg=what)
 
 
@@ -577,12 +577,14 @@ def _prefill_case():
 
 def _assert_pages(pool_in, pool_out, pages, what):
     """``pages``: {page: (layers, heads, n, head_dim) k/v of its first n
-    positions}. Pages not named are bit-identical to the input."""
+    positions}. Pages not named are bit-identical to the input. (A page
+    of the pool is (layers, heads, head_dim, positions).)"""
     out = pool_out.copy()
     for page, want in pages.items():
         n = want.shape[2]
-        onp.testing.assert_array_equal(out[page][:, :, :n], want,
-                                       err_msg=f"{what} page {page}")
+        onp.testing.assert_array_equal(
+            out[page][..., :n], want.transpose(0, 1, 3, 2),
+            err_msg=f"{what} page {page}")
         out[page] = pool_in[page]
     onp.testing.assert_array_equal(out, pool_in, err_msg=what)
 
@@ -623,6 +625,180 @@ def test_prefix_join_scatter_lands_at_start(net, monkeypatch):
             6: new[1][:, :, 0:5], 8: new[2][:, :, 8:16]}, "kv"[which])
 
 
+# -- decode attention read straight from the pool ----------------------------
+# Pages of 128 positions (the kernel engages on lane-aligned pages only), the
+# rest tiny. Slots: 0 ends on its first page's last cell; 1 starts its second
+# page; 2 is ragged, mid-page, with sentinel columns after its pages; 3 is
+# inactive (all sentinel); 4 holds one position.
+PA_PAGES, PA_LAYERS, PA_D, PA_P, PA_W = 7, 2, 8, 128, 3
+PA_TABLE = [[3], [5, 1], [0, 6, 2], [], [4]]
+PA_POS = [PA_P - 1, PA_P, 2 * PA_P + 37, 11, 0]
+
+
+def _paged_case(K, hq=4, hkv=4, seed=7):
+    rs = onp.random.RandomState(seed)
+    shape = (PA_PAGES, PA_LAYERS, hkv, PA_D, PA_P)
+    tab = onp.full((len(PA_TABLE), PA_W + 1), PA_PAGES, dtype="int32")
+    for r, ids in enumerate(PA_TABLE):
+        tab[r, :len(ids)] = ids
+    return (rs.standard_normal((len(PA_TABLE), K, hq, PA_D))
+            .astype("float32"),
+            rs.standard_normal(shape).astype("float32"),
+            rs.standard_normal(shape).astype("float32"),
+            tab, onp.array(PA_POS, "int32"))
+
+
+def _dense_attention(q, kp, vp, layer, tab, pos):
+    """The tick's attention as it was before the paged op: gather every
+    column of a slot's row into a view over W*P positions, mask to
+    positions <= the query's, softmax, weigh. float64 on the host. By the
+    op's contract a position in an unmapped page counts for nothing (slot
+    0's second query stands on one), and an inactive slot is zeros."""
+    S, K, hq, D = q.shape
+    g = hq // kp.shape[2]
+    out = onp.zeros((S, K, hq * D))
+    for s in range(S):
+        ids = [int(i) for i in tab[s, :PA_W]]
+        if ids[0] >= PA_PAGES:
+            continue
+        pages = [min(i, PA_PAGES - 1) for i in ids]
+        mapped = onp.repeat(onp.array(ids) < PA_PAGES, PA_P)
+        # (Hkv, D, W*P) -> heads repeated for the group
+        kv = [onp.repeat(onp.concatenate(
+            [pool[i, layer].astype("float64") for i in pages], -1), g, 0)
+            for pool in (kp, vp)]
+        for k in range(K):
+            n = int(pos[s]) + k + 1
+            logit = onp.einsum("hd,hdt->ht", q[s, k].astype("float64"),
+                               kv[0][..., :n]) / D ** 0.5
+            w = onp.exp(logit - logit.max(-1, keepdims=True)) * mapped[:n]
+            w /= w.sum(-1, keepdims=True)
+            out[s, k] = onp.einsum("ht,hdt->hd", w, kv[1][..., :n]).ravel()
+    return out
+
+
+def _paged_op(case, layer, body, monkeypatch):
+    """``npx.paged_decode_attention`` through the Pallas kernel in interpret
+    mode or through the plain body (the per-op jit forgets its traces
+    first, so neither body is served the other's)."""
+    from mxnet_tpu.ops.registry import get_op
+
+    get_op("paged_decode_attention")._fn_cache.clear()
+    if body == "kernel":
+        monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("MXTPU_PALLAS_INTERPRET", raising=False)
+    q, kp, vp, tab, pos = case
+    return mx.npx.paged_decode_attention(
+        mx.np.array(q), mx.np.array(kp), mx.np.array(vp),
+        mx.np.array(layer, dtype="int32"), mx.np.array(tab),
+        mx.np.array(pos)).asnumpy()
+
+
+@pytest.mark.parametrize("body", ["kernel", "reference"])
+@pytest.mark.parametrize("K", [1, 2])
+def test_paged_decode_attention_matches_the_dense_view(monkeypatch, K, body):
+    case = _paged_case(K)
+    for layer in range(PA_LAYERS):
+        got = _paged_op(case, layer, body, monkeypatch)
+        want = _dense_attention(*case[:3], layer, *case[3:])
+        assert got.shape == want.shape
+        onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+        assert (got[3] == 0).all(), "an inactive slot returns zeros"
+
+
+def test_paged_kernel_is_the_body_under_interpret(monkeypatch):
+    """The interpret-mode case above ran the kernel, not the plain body."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    called = []
+    real = pk._paged_decode_tpu
+    monkeypatch.setattr(pk, "_paged_decode_tpu",
+                        lambda *a: called.append(1) or real(*a))
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    q, kp, vp, tab, pos = (mx.np.array(a)._data for a in _paged_case(1))
+    pk.paged_decode_attention(q, kp, vp, 0, tab, pos)
+    assert called
+    # pages that are not lane-aligned take the plain body
+    del called[:]
+    pk.paged_decode_attention(q, kp[..., :8], vp[..., :8], 0, tab, pos // 16)
+    assert not called
+
+
+@pytest.mark.parametrize("body", ["kernel", "reference"])
+@pytest.mark.parametrize("K", [1, 2])
+def test_nothing_past_a_slots_length_is_read(monkeypatch, K, body):
+    """Every cell past a slot's last query and every page no slot holds is
+    NaN: the outputs are finite and equal to the clean run's."""
+    q, kp, vp, tab, pos = case = _paged_case(K)
+    dirty_k, dirty_v = (onp.full_like(kp, onp.nan) for _ in range(2))
+    for s, ids in enumerate(PA_TABLE):
+        end = int(pos[s]) + K          # positions 0 .. end-1 are the slot's
+        for j, page in enumerate(ids):
+            n = max(0, min(PA_P, end - j * PA_P))
+            dirty_k[page, ..., :n] = kp[page, ..., :n]
+            dirty_v[page, ..., :n] = vp[page, ..., :n]
+    assert onp.isnan(dirty_k).any()
+    for layer in range(PA_LAYERS):
+        clean = _paged_op(case, layer, body, monkeypatch)
+        got = _paged_op((q, dirty_k, dirty_v, tab, pos), layer, body,
+                        monkeypatch)
+        assert onp.isfinite(got).all()
+        onp.testing.assert_array_equal(got, clean)
+
+
+def test_paged_kernel_shares_a_page_among_grouped_heads(monkeypatch):
+    """Grouped-query attention at g = 8: eight query heads read each KV
+    head's page (the kernel indexes the page by ``h // g``)."""
+    case = _paged_case(2, hq=16, hkv=2)
+    got = _paged_op(case, 1, "kernel", monkeypatch)
+    want = _dense_attention(*case[:3], 1, *case[3:])
+    onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+def test_engine_on_the_paged_kernel_equals_uncached_greedy(monkeypatch):
+    """The tick through the Pallas kernel (interpret mode, speculation on,
+    pages of 128): the served tokens are those of the uncached loop. Only
+    this op is steered (the plain body is swapped for the kernel), so no
+    other op's per-process jit sees interpret mode."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops.registry import get_op
+
+    mx.random.seed(17)
+    model = gpt_tiny(vocab_size=VOCAB, dropout=0.0, num_layers=2, units=32,
+                     num_heads=4, max_length=256)
+    model.initialize()
+    rs = onp.random.RandomState(2)
+    prompts = [rs.randint(1, VOCAB, n).tolist() for n in (3, 11, 16)]
+    want = [model.generate(p, max_new_tokens=7, temperature=0.0,
+                           use_cache=False)[len(p):] for p in prompts]
+    runs = []
+
+    def through_the_kernel(*args):
+        runs.append(1)
+        with monkeypatch.context() as mp:
+            mp.setattr(pk, "_interpret", lambda: True)
+            return pk._paged_decode_tpu(*args)
+
+    monkeypatch.setattr(pk, "_paged_decode_reference", through_the_kernel)
+    op = get_op("paged_decode_attention")
+    op._fn_cache.clear()
+    try:
+        eng = DecodeEngine(model, num_slots=2, max_len=256,
+                           max_prompt_len=16, prefill_batch=1,
+                           page_tokens=128, speculate_k=2,
+                           prefix_cache=False, max_wait_us=0,
+                           cache_dir=False)
+        try:
+            streams = [eng.submit(p, max_new_tokens=7) for p in prompts]
+            got = [st.result(timeout=300) for st in streams]
+        finally:
+            eng.close()
+    finally:
+        op._fn_cache.clear()
+    assert runs and got == want
+
+
 def _hlo_instructions(text):
     """(result shape, opcode, line) of every instruction of an HLO text."""
     import re
@@ -644,7 +820,7 @@ def test_nothing_but_the_update_is_pool_shaped(net, family):
                            prefill_batch=2, max_prompt_len=16,
                            page_tokens=POOL_P, kv_pages=POOL_PAGES,
                            speculate_k=2, prefix_cache=True)
-    assert progs.cache_shape == (POOL_PAGES, 2, 4, POOL_P, 8)
+    assert progs.cache_shape == (POOL_PAGES, 2, 4, 8, POOL_P)
     pool = mx.np.zeros(progs.cache_shape)._data
     i32 = lambda *shape: onp.zeros(shape, "int32")  # noqa: E731
     Wt = progs.table_width
@@ -694,7 +870,7 @@ def test_decode_manifest_roundtrip(net, tmp_path):
     # slot: same bytes as the old slot-cache reservation
     assert m["page_tokens"] == MAX_LEN and m["kv_pages"] == 4
     assert m["speculate_k"] == 1 and m["prefix_cache"] is True
-    assert m["cache_shape"] == [4, 2, 4, MAX_LEN, 8]
+    assert m["cache_shape"] == [4, 2, 4, 8, MAX_LEN]
     assert m["signatures"] == manifest["signatures"]
     assert set(m["signatures"]) == {
         "decode|1", "prefill|1|8", "prefill|1|16", "prefill|2|8",
@@ -719,6 +895,19 @@ def test_decode_manifest_roundtrip(net, tmp_path):
     bad.write_text(json.dumps({"version": 99}))
     with pytest.raises(MXNetError, match="decode manifest"):
         serve.decode.load_decode_manifest(str(bad))
+
+
+def test_manifest_of_the_older_pool_layout_is_refused(tmp_path):
+    """Version 2 artefacts hold graphs and a ``cache_shape`` for the pool
+    ``[.., page_tokens, head_dim]``: refused by name, never loaded."""
+    old = tmp_path / "old-decode.manifest.json"
+    old.write_text(json.dumps({
+        "kind": "decode_engine", "version": 2, "page_tokens": 8,
+        "cache_shape": [4, 2, 4, 8, 8]}))
+    with pytest.raises(MXNetError, match="another KV pool layout"):
+        serve.decode.load_decode_manifest(str(old))
+    with pytest.raises(MXNetError, match="re-exported"):
+        DecodeEngine.from_export(str(old))
 
 
 # -- bench smoke (mirrors test_bench_serve_smoke) ---------------------------
