@@ -2,9 +2,12 @@
 
 The LLM leg of the serving story (ISSUE 7, decode engine v2 in ISSUE 18):
 a PAGED KV cache — a shared pool of fixed-size pages mapped through
-per-slot page tables (:mod:`cache`) — three AOT-compiled program
-families — bucketed ``prefill``, prefix-join ``prefill_ext`` and
-fixed-shape ``decode_tick_k`` (:mod:`programs`) — a host-side radix
+per-slot page tables, and the views of it a model's one forward pass is
+handed (``cache=view``: ``view.attend(layer, q, k, v)`` stores and
+attends, whatever the program; :mod:`cache`) — three AOT-compiled program
+families, that forward over one view each — bucketed ``prefill``,
+prefix-join ``prefill_ext`` and fixed-shape ``decode_tick_k``
+(:mod:`programs`) — a host-side radix
 prefix cache sharing prompt-prefix pages across requests (:mod:`prefix`),
 speculative multi-token verification (:mod:`spec`), and a
 continuous-batching scheduler with streaming token futures, deadlines,
@@ -21,7 +24,7 @@ Quick start::
 
 See docs/DESIGN.md "Decode engine v2".
 """
-from .cache import KVCache, PageAllocator, PagedKVCache, SlotAllocator
+from .cache import PageAllocator, PagedKVCache, SlotAllocator
 from .engine import DecodeEngine, DecodeStream, EngineDeadError, ShedError
 from .prefix import RadixPrefixCache
 from .programs import DecodePrograms, load_decode_manifest
@@ -29,7 +32,7 @@ from .spec import (LastTokenDraft, NgramDraft, accept_longest_prefix,
                    make_draft)
 
 __all__ = ["DecodeEngine", "DecodeStream", "ShedError", "EngineDeadError",
-           "KVCache", "SlotAllocator", "PageAllocator", "PagedKVCache",
+           "SlotAllocator", "PageAllocator", "PagedKVCache",
            "RadixPrefixCache", "DecodePrograms", "load_decode_manifest",
            "NgramDraft", "LastTokenDraft", "make_draft",
            "accept_longest_prefix"]

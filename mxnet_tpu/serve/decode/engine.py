@@ -163,15 +163,19 @@ class DecodeStream:
 
 
 class DecodeEngine:
-    """Continuous-batching autoregressive decoding for a GPT-style model.
+    """Continuous-batching autoregressive decoding for a causal LM.
 
     Parameters
     ----------
-    model : GPTModel-like block, optional
-        Must expose ``forward_prefill_paged`` / ``forward_prefill_join``
-        / ``forward_decode_paged`` / ``init_paged_cache``. May be omitted
-        when ``programs`` (e.g. from ``DecodeEngine.from_export``)
-        supplies traced graphs.
+    model : block, optional
+        A model with ONE forward pass, ``model(tokens, cache=view)`` ->
+        logits (B, T, V): its attention layers call
+        ``view.attend(layer, q, k, v)`` and its embedding takes
+        ``view.positions(limit)`` (the views: :mod:`cache`). Besides, it
+        states ``cache_spec()`` (what a KV cache must hold) and
+        ``max_length`` (and ``tp_partition_rules('serve')`` for tp >= 2).
+        May be omitted when ``programs`` (e.g. from
+        ``DecodeEngine.from_export``) supplies traced graphs.
     num_slots : int
         Concurrent sequences per decode tick (the fixed decode program
         shape). Default: ``MXTPU_DECODE_SLOTS`` (8).

@@ -26,10 +26,11 @@ applied to decoding — the host only feeds operands):
   program set fixed, so steady state never recompiles regardless of
   drafts, prefix hits, or which requests join or leave.
 
-The pool pair is ``[kv_pages, layers, heads, head_dim, page_tokens]``:
-positions along the last axis, the layout the chip keeps and the kernel
-reads without a re-lay (manifest version 3; an artefact exported with
-the older ``[.., page_tokens, head_dim]`` pool is refused, not loaded).
+Each family is the model's ONE forward pass over a view of the paged pool
+(``cache.PrefillView`` / ``JoinView`` / ``TickView``), followed by the
+greedy choice; the pool's layout, how a layer's K/V is written and how it
+is attended over live in :mod:`cache` alone. (Manifest version 3: an
+artefact exported with an older pool layout is refused, not loaded.)
 
 All three donate the pool pair (pool in, pool out — a single device
 residency; on backends without donation support XLA falls back to
@@ -51,6 +52,7 @@ import numpy as onp
 
 from ...base import MXNetError
 from ..bucketing import bucket_ladder
+from . import cache as kv
 
 __all__ = ["DecodePrograms", "load_decode_manifest"]
 
@@ -66,8 +68,7 @@ def load_decode_manifest(path):
             f"unsupported decode manifest in {path}: version="
             f"{m.get('version')!r} kind={m.get('kind')!r} (this build "
             f"reads version {MANIFEST_VERSION}; older manifests describe "
-            "another KV pool layout — version 2 kept [pages, layers, "
-            "heads, page_tokens, head_dim] — and must be re-exported)")
+            "another KV pool layout and must be re-exported)")
     return m
 
 
@@ -106,8 +107,8 @@ class DecodePrograms:
                 f"max_prompt_len {max_prompt_len} exceeds cache max_len "
                 f"{self.max_len}")
         self.max_prompt_len = max_prompt_len
-        # clamp to max_len: a page larger than the whole cache row would
-        # silently re-grow per-slot reservation past the slot-cache design
+        # clamp to max_len: a page larger than a slot's whole capacity
+        # would reserve more for a slot than max_len positions
         self.page_tokens = min(int(page_tokens), self.max_len)
         if self.page_tokens < 1:
             raise MXNetError(
@@ -138,7 +139,7 @@ class DecodePrograms:
         self._programs = {}     # ("decode", K) | ("prefill"[_ext], B, T)
         self._costs = {}        # program key -> (flops, bytes_accessed)
         self._signatures = {}   # str key -> trace signature
-        self.cache_shape = None  # [kv_pages, layers, heads, hd, page_tokens]
+        self.cache_shape = None  # one pool's shape (cache.POOL_AXES)
         self.cache_dtype = "float32"
         # tensor parallelism: the model's column-parallel serve layout,
         # traced at per-rank local shapes and replayed under shard_map
@@ -204,15 +205,12 @@ class DecodePrograms:
     def _trace_graphs(self, params):
         names = [name for name, _ in params]
         K = self.speculate_k
-        self._cops[f"decode:{K}"] = self._trace_decode(K, params)
-        self._graph_params[f"decode:{K}"] = names
-        for T in self.len_ladder:
-            self._cops[f"prefill:{T}"] = self._trace_prefill(T, params)
-            self._graph_params[f"prefill:{T}"] = names
-            if self.prefix_cache:
-                self._cops[f"prefill_ext:{T}"] = \
-                    self._trace_prefill_ext(T, params)
-                self._graph_params[f"prefill_ext:{T}"] = names
+        prefills = ("prefill", "prefill_ext") if self.prefix_cache \
+            else ("prefill",)
+        for family, size in [("decode", K)] + [
+                (family, T) for T in self.len_ladder for family in prefills]:
+            self._cops[f"{family}:{size}"] = self._trace(family, size, params)
+            self._graph_params[f"{family}:{size}"] = names
 
     def _trace_all_tp(self):
         """Trace every graph at per-rank LOCAL shapes: column-parallel
@@ -274,82 +272,62 @@ class DecodePrograms:
                 p._data = full
 
     def _pool_pair(self):
-        kp, vp = self._model.init_paged_cache(self.kv_pages,
-                                              self.page_tokens)
+        spec = self._model.cache_spec()
         if self.cache_shape is None:
-            shape = tuple(int(d) for d in kp.shape)
-            if self.tp > 1:
-                # the traced pool is per-rank local over heads; report the
-                # GLOBAL pool geometry the engine allocates
-                shape = shape[:2] + (shape[2] * self.tp,) + shape[3:]
-            self.cache_shape = shape
-            self.cache_dtype = str(kp.dtype)
-        return kp, vp
+            # the traced pool is per-rank local over heads; report the
+            # GLOBAL pool geometry the engine allocates
+            self.cache_shape = kv.pool_shape(
+                dict(spec, heads=spec["heads"] * self.tp), self.kv_pages,
+                self.page_tokens)
+            self.cache_dtype = str(spec["dtype"])
+        return kv.empty_pools(spec, self.kv_pages, self.page_tokens)
 
-    def _trace_decode(self, K, params):
+    # family -> (the view its forward is handed, program name, whether it
+    # forwards prompts: rows of ``valid_length`` tokens, the last one scored)
+    _FAMILIES = {
+        "decode": (kv.TickView, "serve_decode_tick_k{}", False),
+        "prefill": (kv.PrefillView, "serve_prefill_{}", True),
+        "prefill_ext": (kv.JoinView, "serve_prefill_ext_{}", True),
+    }
+
+    def _trace(self, family, size, params):
+        """Trace one graph: the model's ONE forward over ``family``'s view
+        of the operands, then the greedy choice. ``size`` is K for the
+        tick (``tokens`` (S, K), ``positions`` (S,)), the length bucket T
+        for the prefills (``tokens`` (B, T), ``valid_length`` (B,) and,
+        joining a prefix, ``start`` (B,)); the page table and the donated
+        pool pair close the operand list. The tick scores every column
+        (logits[:, i] scores the token AFTER tokens[:, i] — greedy
+        verification accepts the longest draft prefix that matches); a
+        prefill scores each row's last valid position."""
         from ... import numpy as np
         from ...cached_op import trace
 
         model = self._model
-        S = self.num_slots
-        tokens = np.zeros((S, K), dtype="int32")
-        positions = np.zeros((S,), dtype="int32")
-        table = np.full((S, self.table_width), self.kv_pages,
-                        dtype="int32")
-        kp, vp = self._pool_pair()
+        view_cls, name, prompt = self._FAMILIES[family]
+        rows = self.prefill_batch if prompt else self.num_slots
+        operands = [np.zeros((rows, size), dtype="int32")]
+        if prompt:
+            operands.append(np.ones((rows,), dtype="int32"))    # valid
+        if family != "prefill":     # the tick's positions, the join's start
+            operands.append(np.zeros((rows,), dtype="int32"))
+        operands.append(np.full((rows, self.table_width), self.kv_pages,
+                                dtype="int32"))
+        operands += self._pool_pair()
 
-        def fn(t, p, tab, k, v):
-            logits, k2, v2 = model.forward_decode_paged(t, p, tab, k, v)
-            nxt = np.argmax(logits, axis=-1).astype("int32")
-            return nxt, k2, v2
+        def fn(tokens, *rest):
+            view = view_cls(tokens, *rest)
+            logits = model(tokens, cache=view)
+            if prompt:
+                valid = rest[0]
+                onehot = np.one_hot(valid.astype("int32") - 1, size,
+                                    dtype=str(logits.dtype))      # (B, T)
+                logits = np.einsum("btv,bt->bv", logits, onehot)
+            k2, v2 = view.state()
+            return np.argmax(logits, axis=-1).astype("int32"), k2, v2
 
-        _, _, cop = trace(fn, [tokens, positions, table, kp, vp], params)
-        cop._name = f"serve_decode_tick_k{K}"
-        return cop
-
-    def _trace_prefill(self, T, params):
-        from ... import numpy as np
-        from ...cached_op import trace
-
-        model = self._model
-        B = self.prefill_batch
-        tokens = np.zeros((B, T), dtype="int32")
-        valid = np.ones((B,), dtype="int32")
-        table = np.full((B, self.table_width), self.kv_pages,
-                        dtype="int32")
-        kp, vp = self._pool_pair()
-
-        def fn(tok, vl, tab, k, v):
-            last, k2, v2 = model.forward_prefill_paged(tok, vl, tab, k, v)
-            first = np.argmax(last, axis=-1).astype("int32")
-            return first, k2, v2
-
-        _, _, cop = trace(fn, [tokens, valid, table, kp, vp], params)
-        cop._name = f"serve_prefill_{T}"
-        return cop
-
-    def _trace_prefill_ext(self, T, params):
-        from ... import numpy as np
-        from ...cached_op import trace
-
-        model = self._model
-        B = self.prefill_batch
-        tokens = np.zeros((B, T), dtype="int32")
-        valid = np.ones((B,), dtype="int32")
-        start = np.zeros((B,), dtype="int32")
-        table = np.full((B, self.table_width), self.kv_pages,
-                        dtype="int32")
-        kp, vp = self._pool_pair()
-
-        def fn(tok, vl, st, tab, k, v):
-            last, k2, v2 = model.forward_prefill_join(tok, vl, st, tab,
-                                                      k, v)
-            first = np.argmax(last, axis=-1).astype("int32")
-            return first, k2, v2
-
-        _, _, cop = trace(fn, [tokens, valid, start, table, kp, vp],
-                          params)
-        cop._name = f"serve_prefill_ext_{T}"
+        _, _, cop = trace(fn, operands, params)
+        cop._name = name.format(size)
         return cop
 
     # --------------------------------------------------------------- compile
@@ -454,7 +432,7 @@ class DecodePrograms:
         from ...parallel.mesh import shard_map_compat
 
         names = self._graph_params[self._cop_key(key)]
-        pool = P(None, None, "tp")
+        pool = P(*("tp" if a == "heads" else None for a in kv.POOL_AXES))
         data_specs = [P()] * (len(examples) - 2) + [pool, pool]
         pspecs = []
         for n in names:

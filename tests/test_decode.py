@@ -14,10 +14,11 @@ import mxnet_tpu as mx
 from mxnet_tpu import serve, telemetry as tm
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo import gpt_tiny
-from mxnet_tpu.serve.decode import (DecodeEngine, KVCache, PageAllocator,
+from mxnet_tpu.serve.decode import (DecodeEngine, PageAllocator,
                                     PagedKVCache, RadixPrefixCache,
                                     ShedError, SlotAllocator,
                                     accept_longest_prefix, make_draft)
+from mxnet_tpu.serve.decode import cache as kv
 
 VOCAB = 50
 MAX_LEN = 64
@@ -79,7 +80,7 @@ def _naive(net, prompt, max_new):
     return [int(t) for t in out[len(prompt):]]
 
 
-# -- slot allocator / KV cache ----------------------------------------------
+# -- slot allocator ----------------------------------------------------------
 def test_slot_alloc_free_reuse():
     alloc = SlotAllocator(3)
     sids = [alloc.alloc() for _ in range(3)]
@@ -93,19 +94,6 @@ def test_slot_alloc_free_reuse():
         alloc.free(7)
     with pytest.raises(MXNetError, match="at least one slot"):
         SlotAllocator(0)
-
-
-def test_kv_cache_shape_and_rebind():
-    cache = KVCache((2, 3, 4, 8, 5), "float32")
-    assert cache.num_slots == 2 and cache.max_len == 8
-    assert cache.k.shape == (2, 3, 4, 8, 5)
-    assert cache.nbytes == 2 * 3 * 4 * 8 * 5 * 4 * 2
-    assert cache.occupancy() == 0.0
-    k0 = cache.k
-    cache.rebind(cache.k + 1, cache.v)
-    assert cache.k is not k0
-    with pytest.raises(MXNetError, match="cache shape"):
-        KVCache((2, 3, 4))
 
 
 # -- page allocator / paged KV cache ----------------------------------------
@@ -498,7 +486,7 @@ POOL_W = MAX_LEN // POOL_P
 
 
 def _random_pools(net, seed=5):
-    kp, vp = net.init_paged_cache(POOL_PAGES, POOL_P)
+    kp, vp = kv.empty_pools(net.cache_spec(), POOL_PAGES, POOL_P)
     rs = onp.random.RandomState(seed)
     return (rs.standard_normal(kp.shape).astype("float32"),
             rs.standard_normal(vp.shape).astype("float32"))
@@ -513,16 +501,21 @@ def _table(rows):
     return tab
 
 
-def _record_qkv(net, monkeypatch):
-    """Every block's ``_qkv`` also appends its (k, v) to a per-layer list."""
+def _forward_recording(net, view, tokens):
+    """``net``'s one forward over ``view``; returns the updated pools and,
+    per layer in order, the (k, v) the model handed ``view.attend``."""
     seen = []
-    for blk in net.blocks:
-        def qkv(x, _orig=blk._qkv):
-            q, k, v = _orig(x)
-            seen.append((k.asnumpy(), v.asnumpy()))
-            return q, k, v
-        monkeypatch.setattr(blk, "_qkv", qkv, raising=False)
-    return seen
+    attend = view.attend
+
+    def recording(layer, q, k, v):
+        assert layer == len(seen)
+        seen.append((k.asnumpy(), v.asnumpy()))
+        return attend(layer, q, k, v)
+
+    view.attend = recording
+    net(tokens, cache=view)
+    kp, vp = view.state()
+    return seen, kp.asnumpy(), vp.asnumpy()
 
 
 def _assert_only_cells(pool_in, pool_out, cells, what):
@@ -535,7 +528,7 @@ def _assert_only_cells(pool_in, pool_out, cells, what):
 
 
 @pytest.mark.parametrize("K", [1, 2])
-def test_decode_tick_writes_only_its_rows(net, monkeypatch, K):
+def test_decode_tick_writes_only_its_rows(net, K):
     H, D = 4, 8
     kp, vp = _random_pools(net)
     # slot 0 sits at offset P-1 (K=2 spills into its next page), slot 1 is
@@ -544,13 +537,12 @@ def test_decode_tick_writes_only_its_rows(net, monkeypatch, K):
     table = _table([[3, 5], [], [0, 1, 2, 4, 6, 7, 8, 9], [1, 10]])
     positions = onp.array([POOL_P - 1, 5, MAX_LEN - 1, 10], "int32")
     tokens = onp.arange(1, 4 * K + 1, dtype="int32").reshape(4, K)
-    seen = _record_qkv(net, monkeypatch)
-    _, kp2, vp2 = net.forward_decode_paged(
-        mx.np.array(tokens), mx.np.array(positions), mx.np.array(table),
-        mx.np.array(kp), mx.np.array(vp))
+    tokens = mx.np.array(tokens)
+    seen, kp2, vp2 = _forward_recording(net, kv.TickView(
+        tokens, mx.np.array(positions), mx.np.array(table),
+        mx.np.array(kp), mx.np.array(vp)), tokens)
     assert len(seen) == 2
-    for which, (before, after) in enumerate([(kp, kp2.asnumpy()),
-                                             (vp, vp2.asnumpy())]):
+    for which, (before, after) in enumerate([(kp, kp2), (vp, vp2)]):
         cells = {}
         for layer in range(2):
             rows = seen[layer][which].reshape(4, K, H, D)
@@ -589,37 +581,40 @@ def _assert_pages(pool_in, pool_out, pages, what):
     onp.testing.assert_array_equal(out, pool_in, err_msg=what)
 
 
+def _stacked(seen, which, H=4, D=8):
+    """The recorded per-layer (B, T, units) k (0) or v (1) as
+    (B, layers, heads, T, head_dim)."""
+    return onp.stack([kv_[which].reshape(3, 16, H, D).transpose(0, 2, 1, 3)
+                      for kv_ in seen], 1)
+
+
 def test_prefill_scatter_writes_only_live_pages(net):
     kp, vp = _random_pools(net)
     tokens, valid = _prefill_case()
     table = _table([[2, 4], [6, 7], [POOL_PAGES, 8]])
-    _, k, v = net.forward_prefill(mx.np.array(tokens), mx.np.array(valid))
-    _, kp2, vp2 = net.forward_prefill_paged(
-        mx.np.array(tokens), mx.np.array(valid), mx.np.array(table),
-        mx.np.array(kp), mx.np.array(vp))
-    for what, new, before, after in (("k", k.asnumpy(), kp, kp2.asnumpy()),
-                                     ("v", v.asnumpy(), vp, vp2.asnumpy())):
+    tokens = mx.np.array(tokens)
+    seen, kp2, vp2 = _forward_recording(net, kv.PrefillView(
+        tokens, mx.np.array(valid), mx.np.array(table),
+        mx.np.array(kp), mx.np.array(vp)), tokens)
+    for which, (before, after) in enumerate([(kp, kp2), (vp, vp2)]):
+        new = _stacked(seen, which)
         _assert_pages(before, after, {
             2: new[0][:, :, 0:8], 4: new[0][:, :, 8:12],
-            6: new[1][:, :, 0:5], 8: new[2][:, :, 8:16]}, what)
+            6: new[1][:, :, 0:5], 8: new[2][:, :, 8:16]}, "kv"[which])
 
 
-def test_prefix_join_scatter_lands_at_start(net, monkeypatch):
-    H, D = 4, 8
+def test_prefix_join_scatter_lands_at_start(net):
     kp, vp = _random_pools(net)
     tokens, valid = _prefill_case()
     # suffixes from page-aligned starts: pages start//P + j of each row
     start = onp.array([8, 24, 0], "int32")
     table = _table([[0, 2, 4], [1, 3, 5, 6, 7], [POOL_PAGES, 8]])
-    seen = _record_qkv(net, monkeypatch)
-    _, kp2, vp2 = net.forward_prefill_join(
-        mx.np.array(tokens), mx.np.array(valid), mx.np.array(start),
-        mx.np.array(table), mx.np.array(kp), mx.np.array(vp))
-    for which, (before, after) in enumerate([(kp, kp2.asnumpy()),
-                                             (vp, vp2.asnumpy())]):
-        # (B, T, units) per layer -> (B, layers, heads, T, head_dim)
-        new = onp.stack([seen[layer][which].reshape(3, 16, H, D)
-                         .transpose(0, 2, 1, 3) for layer in range(2)], 1)
+    tokens = mx.np.array(tokens)
+    seen, kp2, vp2 = _forward_recording(net, kv.JoinView(
+        tokens, mx.np.array(valid), mx.np.array(start), mx.np.array(table),
+        mx.np.array(kp), mx.np.array(vp)), tokens)
+    for which, (before, after) in enumerate([(kp, kp2), (vp, vp2)]):
+        new = _stacked(seen, which)
         _assert_pages(before, after, {
             2: new[0][:, :, 0:8], 4: new[0][:, :, 8:12],
             6: new[1][:, :, 0:5], 8: new[2][:, :, 8:16]}, "kv"[which])
@@ -913,9 +908,9 @@ def test_manifest_of_the_older_pool_layout_is_refused(tmp_path):
 # -- bench smoke (mirrors test_bench_serve_smoke) ---------------------------
 def test_bench_serve_llm_smoke(monkeypatch):
     """bench.py serve_llm (small) with the full v2 stack on — speculative
-    K=4, 50% prefix-shared prompts, paged 2x-slots at equal bytes: beats
-    the naive per-request rolling-window loop, decodes with zero
-    steady-state recompiles, and surfaces the v2 counters."""
+    K=4, 50% prefix-shared prompts, paged 2x-slots at equal bytes: decodes
+    with zero steady-state recompiles and surfaces the v2 counters. Counts
+    only: a CPU run yields no time, so no ratio of two is asserted."""
     import bench
 
     monkeypatch.setenv("BENCH_SERVE_LLM_SMALL", "1")
@@ -930,8 +925,6 @@ def test_bench_serve_llm_smoke(monkeypatch):
     assert r["speculate_k"] == 4 and 1.0 <= r["spec_accept_mean"] <= 4.0
     assert r["prefix_hit_tokens"] > 0
     assert r["num_slots"] == 8 and r["paged_2x_slots"]
-    # full-size runs show ~20-25x; 2x keeps the small CI box margin wide
-    assert r["vs_baseline"] >= 2.0, r
 
 
 def test_decode_export_roundtrip(net, tmp_path):
