@@ -8,8 +8,22 @@ AOT executables and applies bookkeeping to the results:
            prefix lookup, page allocation, then the prefill or
            prefix-join program, bucketed batch x length) -> one
            decode_tick_k for ALL slots (K-1 drafted tokens verified per
-           slot when speculation is on) -> commit the accepted prefix /
-           retire finished requests
+           slot when speculation is on) -> read back and commit what was
+           dispatched BEFORE that tick / finish the requests it ended
+
+With ``speculate_k == 1`` the scheduler keeps ONE tick in flight: the
+token a slot feeds next stays on the device (tick n's token output is
+tick n+1's operand; a prefill's first tokens are placed into it by a tiny
+program), and every live slot advances by exactly one position a tick, so
+lengths, page-table growth and which requests end at tick n are known when
+tick n is dispatched. Tick n+1 goes out before tick n's tokens are read
+back; the read-back, the commit and the clients' ``on_token`` for tick n
+run while the device executes tick n+1, and a request known to end at tick
+n has no row in tick n+1. ``stats()["ticks_overlapped"]`` (counter
+``serve.ticks_overlapped``) counts the ticks dispatched while an earlier
+tick's tokens were not yet read back. With ``speculate_k > 1`` the draft
+needs the accepted tokens on the host: nothing stays in flight, every
+program is read back before the next is dispatched.
 
 KV memory is PAGED (vLLM-style): a shared pool of
 ``page_tokens``-position pages backs every slot through per-slot page
@@ -36,7 +50,8 @@ Transient program-run failures retry with capped exponential backoff
 (``MXTPU_SERVE_RETRIES`` / ``MXTPU_SERVE_RETRY_BACKOFF_MS`` /
 ``MXTPU_SERVE_RETRY_MAX_MS``; ``serve.retries`` counts them); an
 exception that survives the retries fails EVERY live, pending and queued
-stream with :class:`EngineDeadError` carrying the real cause, marks the
+stream (those whose last tokens were in flight too) with
+:class:`EngineDeadError` carrying the real cause, marks the
 engine dead (telemetry health check → ``/healthz`` 503,
 ``serve.scheduler_crashes``), and later ``submit`` raises immediately.
 ``drain()`` finishes accepted work while shedding new submissions;
@@ -114,6 +129,8 @@ class DecodeStream:
         # prefill span's ``rids`` and the trace's ``trace_id`` carry it
         self.t_submit = time.perf_counter()
         self._t_last = None           # engine: last emit time (TTFT/TPOT)
+        self._dispatched = 0          # engine: tokens the programs
+        # dispatched so far produce for this stream, read back or not
         self._on_token = on_token
         self._cond = threading.Condition()
         self._done = False
@@ -162,8 +179,42 @@ class DecodeStream:
             yield tok
 
 
+class _Flight:
+    """One dispatched program whose tokens the host has not read back.
+
+    ``out`` is the program's token output, still on the device: (S, K) of
+    a tick, (B,) of a prefill. ``rows`` is [(row of ``out``, slot id,
+    stream)] as dispatched: the slot may be another request's by the time
+    the tokens are read. ``ends`` maps a slot to why its request ends with
+    this program's token(s) ("done" | "truncated" | "starved"), for what
+    was known at dispatch. ``span`` is the dispatch span (the wait's timer
+    sample covers both). Of a tick besides: the ``drafts`` it verifies
+    (K > 1), the slots the pool ``starved``, the share of the slots it ran
+    with (``occupancy``) and whether it went out while an earlier tick's
+    tokens were unread (``overlapped``)."""
+
+    __slots__ = ("tick", "out", "rows", "ends", "span", "drafts", "starved",
+                 "occupancy", "overlapped")
+
+    def __init__(self, tick, out, rows, ends, span, drafts=None, starved=(),
+                 occupancy=0.0, overlapped=False):
+        self.tick = tick
+        self.out = out
+        self.rows = rows
+        self.ends = ends
+        self.span = span
+        self.drafts = drafts
+        self.starved = starved
+        self.occupancy = occupancy
+        self.overlapped = overlapped
+
+
 class DecodeEngine:
     """Continuous-batching autoregressive decoding for a causal LM.
+
+    With ``speculate_k == 1`` one decode tick stays in flight: ``on_token``
+    for tick n runs while the device executes tick n+1 (see the module's
+    notes; ``stats()["ticks_overlapped"]`` says how often).
 
     Parameters
     ----------
@@ -310,6 +361,14 @@ class DecodeEngine:
         self._slot_pages = {}   # sid -> owned pool page ids
         self._slot_handles = {}  # sid -> radix pin handles to release
         self._cols = onp.zeros(self.num_slots, dtype="int32")
+        # how many ticks may stay dispatched and unread: the draft of
+        # K > 1 reads the accepted tokens on the host, K == 1 needs none
+        self._depth = 1 if self.speculate_k == 1 else 0
+        self._inflight = deque()   # _Flight, oldest first
+        # each slot's next input token: K == 1 keeps it on the device (the
+        # (S, 1) output of the last tick or place program), K > 1 on the
+        # host, where the draft is appended
+        self._next_tok = None
         self._last_tok = onp.zeros(self.num_slots, dtype="int32")
 
         self._q = queue.SimpleQueue()
@@ -342,6 +401,7 @@ class DecodeEngine:
         self._n_evicted = 0
         self._n_tokens = 0
         self._n_ticks = 0
+        self._n_overlapped = 0
         self._n_prefills = 0
         self._n_starved = 0
         self._n_prefix_hit_tokens = 0
@@ -484,6 +544,11 @@ class DecodeEngine:
                 self._admit(pending)
                 if self._slot_req:
                     self._tick()
+                # the tick just dispatched stays in flight (K == 1) while
+                # a slot it left live waits for the next; all before it is
+                # read back and committed while the device runs it
+                self._settle(keep=self._depth if self._slot_req else 0)
+            self._settle()      # closing: deliver what was computed
         except BaseException as e:  # noqa: BLE001 — converted, never lost
             crash = e
         finally:
@@ -541,7 +606,7 @@ class DecodeEngine:
         """Pull new requests off the queue. Blocks when fully idle;
         otherwise drains without waiting (the decode tick itself is the
         coalescing window once slots are live). Returns True on STOP."""
-        idle = not self._slot_req and not pending
+        idle = not self._slot_req and not pending and not self._inflight
         with span("serve.wait_queue" if idle else "serve.gather") as sp:
             n0 = len(pending)
             stop = self._take(pending, idle)
@@ -593,7 +658,8 @@ class DecodeEngine:
                 "deadline expired before the request reached a slot"))
         for sid in [s for s, st in self._slot_req.items()
                     if st.deadline is not None and now > st.deadline]:
-            self._retire(sid, expired=True)
+            # a row of this slot still in flight is dropped at its commit
+            self._complete(self._vacate(sid), expired=True)
 
     # ------------------------------------------------------ page admission
     def _alloc_pages(self, n):
@@ -684,7 +750,6 @@ class DecodeEngine:
         import jax
 
         cache = self._cache
-        P = self.page_tokens
         ext = sub[0][1]["start"] > 0
         with span("serve.prefill.host") as sp:
             slots = [cache.slots.alloc() for _ in sub]
@@ -721,37 +786,41 @@ class DecodeEngine:
                     rids=" ".join(str(s.rid) for s, _ in sub),
                     queue_wait_ms_max=max(
                         t_q - s.t_submit for s, _ in sub) * 1e3)
-        tm = self._tm
-        hb_on = tm.ON
-        if hb_on:
-            self._hb_prefill.begin()
-        try:
-            with span("serve.prefill.dispatch", batch=B, length=T) as sp:
-                args = [jax.device_put(tokens), jax.device_put(valid)]
-                if ext:
-                    args.append(jax.device_put(start))
-                args += [jax.device_put(table), cache.k, cache.v]
-                outs = self._run_retry(key, args, point="decode.prefill")
-                cache.rebind(outs[1], outs[2])
-            with span("serve.wait_prefill", timer="serve.prefill.call",
-                      after=sp):
-                first = onp.asarray(outs[0])  # device sync: the TTFT tokens
-        finally:
-            if hb_on:
-                self._hb_prefill.end()
-        with span("serve.prefill.commit"):
-            self._prefill_commit(sub, slots, first)
+        with span("serve.prefill.dispatch", batch=B, length=T) as sp:
+            args = [jax.device_put(tokens), jax.device_put(valid)]
+            if ext:
+                args.append(jax.device_put(start))
+            args += [jax.device_put(table), cache.k, cache.v]
+            outs = self._run_retry(key, args, point="decode.prefill")
+            cache.rebind(outs[1], outs[2])
+            if self._depth:
+                # the first tokens go from this program's output to the
+                # next tick's operand without passing through the host
+                if self._next_tok is None:
+                    self._next_tok = self.programs.token_vector()
+                self._next_tok = self.programs.place(
+                    self._next_tok, outs[0],
+                    slots + [self.num_slots] * (B - len(slots)))
+            ends = self._prefill_plan(sub, slots)
+        self._inflight.append(_Flight(
+            False, outs[0],
+            [(i, sid, s) for i, ((s, _), sid) in enumerate(zip(sub, slots))],
+            ends, sp))
+        if not self._depth:
+            self._settle()      # the draft reads the first token
 
-    def _prefill_commit(self, sub, slots, first):
+    def _prefill_plan(self, sub, slots):
+        """The slots' bookkeeping for a prefill just dispatched: nothing
+        of it needs the first tokens' values. Returns the ``ends`` of the
+        program's record (a request that asked for one token)."""
         cache = self._cache
         P = self.page_tokens
         tm = self._tm
-        if tm.ON:
-            tm.record_dispatch()
+        ends = {}
         with self._stats_lock:
             self._n_prefills += 1
             self._pending_count -= len(sub)
-        for i, ((stream, meta), sid) in enumerate(zip(sub, slots)):
+        for (stream, meta), sid in zip(sub, slots):
             plen = len(stream.prompt)
             cache.lengths[sid] = plen
             self._slot_req[sid] = stream
@@ -765,7 +834,9 @@ class DecodeEngine:
                     stream.trace.extra["prefix_hit_tokens"] = meta["start"]
             if self._prefix is not None:
                 # publish this prompt's full pages for future sharers;
-                # adopted pages change owner (tree frees them, not us)
+                # adopted pages change owner (tree frees them, not us).
+                # A sharer's join is dispatched after this prefill, so it
+                # reads the pages written
                 a0 = meta["start"] // P
                 full = plen // P - a0
                 offered = {a0 + t: meta["own"][t] for t in range(full)}
@@ -777,12 +848,12 @@ class DecodeEngine:
                     keep = [pid for t, pid in enumerate(meta["own"])
                             if (a0 + t) not in adopted]
                     self._slot_pages[sid] = keep
-            tok = int(first[i])
-            self._last_tok[sid] = tok
-            self._emit_tokens(stream, [tok])
-            if len(stream.tokens) >= stream.max_new_tokens:
-                self._retire(sid)
+            stream._dispatched = 1
+            if stream.max_new_tokens <= 1:
+                ends[sid] = "done"
+                self._vacate(sid)
         self._set_slot_gauge()
+        return ends
 
     def _tick(self):
         import jax
@@ -809,80 +880,136 @@ class DecodeEngine:
                         cache.table[sid, c:c + len(got)] = got
                         self._cols[sid] = c + len(got)
                         self._slot_pages[sid].extend(got)
-            tokens = onp.zeros((self.num_slots, K), dtype="int32")
-            tokens[:, 0] = self._last_tok
             self.programs.ensure("decode")
             sp.note(starved=len(starved))
         drafts = {}
-        if K > 1:
+        if self._depth:
+            tokens = self._next_tok
+        else:
             with span("serve.tick.draft"):
+                tokens = onp.zeros((self.num_slots, K), dtype="int32")
+                tokens[:, 0] = self._last_tok
                 for sid in live:
                     stream = self._slot_req[sid]
                     d = self._draft.propose(stream.prompt + stream.tokens,
                                             K - 1)
                     drafts[sid] = d
                     tokens[sid, 1:] = d
-        key = ("decode", K)
-        tm = self._tm
-        hb_on = tm.ON
-        if hb_on:
-            self._hb_tick.begin()
-        try:
-            with span("serve.tick.dispatch", live=len(live)) as sp:
-                outs = self._run_retry(key, [
-                    jax.device_put(tokens), jax.device_put(cache.lengths),
-                    jax.device_put(cache.table), cache.k, cache.v],
-                    point="decode.tick")
-                cache.rebind(outs[1], outs[2])
-            with span("serve.wait_tick", timer="serve.decode_tick.call",
-                      after=sp):
-                rows = onp.asarray(outs[0])   # device sync: the tokens
-        finally:
-            if hb_on:
-                self._hb_tick.end()
-        with span("serve.tick.commit") as sp:
-            n0 = self._n_tokens
-            self._tick_commit(live, starved, drafts, rows)
-            sp.note(tokens=self._n_tokens - n0)
+                tokens = jax.device_put(tokens)
+        with span("serve.tick.dispatch", live=len(live)) as sp:
+            # lengths and table are COPIED: the host's arrays move on
+            # before the device has taken these
+            outs = self._run_retry(("decode", K), [
+                tokens, jax.device_put(cache.lengths.copy()),
+                jax.device_put(cache.table.copy()), cache.k, cache.v],
+                point="decode.tick")
+            cache.rebind(outs[1], outs[2])
+            rec = _Flight(True, outs[0],
+                          [(sid, sid, self._slot_req[sid]) for sid in live],
+                          {}, sp, drafts, starved,
+                          occupancy=len(live) / self.num_slots,
+                          overlapped=any(f.tick for f in self._inflight))
+            if self._depth:
+                # every row yields exactly one token: account for it now,
+                # so that the next tick can be dispatched without it
+                self._next_tok = outs[0]
+                for _, sid, stream in rec.rows:
+                    self._advance(rec, sid, stream, 1)
+        self._inflight.append(rec)
 
-    def _tick_commit(self, live, starved, drafts, rows):
+    def _advance(self, rec, sid, stream, m):
+        """Account ``m`` more tokens of tick ``rec`` for the slot's
+        request. A request that ends with them is noted in ``rec.ends``
+        and gives its slot back."""
         cache = self._cache
-        K = self.speculate_k
+        cache.lengths[sid] += m
+        stream._dispatched += m
+        if stream._dispatched >= stream.max_new_tokens:
+            end = "done"
+        elif sid in rec.starved:
+            end = "starved"
+        elif cache.lengths[sid] >= cache.max_len:
+            end = "truncated"
+        else:
+            return
+        rec.ends[sid] = end
+        self._vacate(sid)
+
+    def _settle(self, keep=0):
+        """Read back and commit the programs in flight, oldest first, all
+        but the newest ``keep``. A failed program raises HERE, at its
+        read-back; its record stays in ``_inflight`` until committed, so
+        the crash path finds every stream it carries."""
         tm = self._tm
+        while len(self._inflight) > keep:
+            rec = self._inflight[0]
+            wait, call, commit, hb = (
+                ("serve.wait_tick", "serve.decode_tick.call",
+                 "serve.tick.commit", self._hb_tick) if rec.tick else
+                ("serve.wait_prefill", "serve.prefill.call",
+                 "serve.prefill.commit", self._hb_prefill))
+            hb_on = tm.ON
+            if hb_on:
+                hb.begin()
+            try:
+                with span(wait, timer=call, after=rec.span):
+                    rows = onp.asarray(rec.out)   # device sync: the tokens
+            finally:
+                if hb_on:
+                    hb.end()
+            with span(commit) as sp:
+                n0 = self._n_tokens
+                self._commit(rec, rows)
+                sp.note(tokens=self._n_tokens - n0)
+            self._inflight.popleft()
+
+    def _commit(self, rec, rows):
+        """Deliver a read-back program's tokens: emit (the clients'
+        ``on_token`` runs here) and finish the requests that end with
+        them. A row whose request finished meanwhile (evicted by its
+        deadline) is dropped."""
+        cache = self._cache
+        tm = self._tm
+        rows = rows.reshape(len(rows), -1)      # a prefill's are (B,)
         if tm.ON:
             tm.record_dispatch()
-        occ = cache.occupancy()
-        with self._stats_lock:
-            self._n_ticks += 1
-            self._occupancy_sum += occ
-        for sid in live:
-            stream = self._slot_req[sid]
-            m = accept_longest_prefix(drafts[sid], rows[sid]) if K > 1 \
-                else 1
-            if K > 1:
+        if rec.tick:
+            with self._stats_lock:
+                self._n_ticks += 1
+                self._n_overlapped += rec.overlapped
+                self._occupancy_sum += rec.occupancy
+            if rec.overlapped and tm.ON:
+                tm.REGISTRY.counter("serve.ticks_overlapped").inc()
+        for i, sid, stream in rec.rows:
+            if stream.done:
+                continue
+            if not rec.tick or self._depth:
+                toks = [int(rows[i, 0])]
+            else:
+                m = accept_longest_prefix(rec.drafts[sid], rows[i])
                 self._spec_accept.record(m)
                 if tm.ON:
                     tm.REGISTRY.histogram("serve.spec_accept_len").record(m)
-            ln = int(cache.lengths[sid])
-            m = min(m, stream.max_new_tokens - len(stream.tokens),
-                    cache.max_len - ln)
-            if sid in starved:
-                m = min(m, 1)
-            toks = [int(t) for t in rows[sid][:m]]
-            cache.lengths[sid] = ln + m
-            self._last_tok[sid] = toks[-1]
+                m = min(m, stream.max_new_tokens - len(stream.tokens),
+                        cache.max_len - int(cache.lengths[sid]))
+                if sid in rec.starved:
+                    m = min(m, 1)
+                toks = [int(t) for t in rows[i][:m]]
+                self._advance(rec, sid, stream, m)
+            if not self._depth:
+                self._last_tok[sid] = toks[-1]
             self._emit_tokens(stream, toks)
-            if len(stream.tokens) >= stream.max_new_tokens:
-                self._retire(sid)
-            elif cache.lengths[sid] >= cache.max_len or sid in starved:
-                stream.truncated = True
-                if sid in starved:
+            end = rec.ends.get(sid)
+            if end:
+                if end != "done":
+                    stream.truncated = True
+                if end == "starved":
                     with self._stats_lock:
                         self._n_starved += 1
                     if tm.ON:
                         tm.REGISTRY.counter("serve.kv_page_starved").inc()
-                self._retire(sid)
-        if tm.ON:
+                self._complete(stream)
+        if rec.tick and tm.ON:
             # tokens/s/chip over a ~0.5 s window (single-device engine:
             # chips == 1, so per-chip is the engine rate)
             nowt = time.perf_counter()
@@ -931,7 +1058,12 @@ class DecodeEngine:
         for tok in toks:
             stream._emit(tok)
 
-    def _retire(self, sid, expired=False):
+    def _vacate(self, sid):
+        """Give the slot, its pages and its prefix pins back; returns the
+        request that held it. Safe while programs that name them are in
+        flight: every program takes the pool pair from the program
+        dispatched before it, so whoever is handed a page next writes it
+        only after every earlier program is done with it."""
         cache = self._cache
         stream = self._slot_req.pop(sid)
         cache.slots.free(sid)
@@ -942,7 +1074,12 @@ class DecodeEngine:
             cache.pages.free(owned)
         for handle in self._slot_handles.pop(sid, []):
             self._prefix.release(handle)
-        self._last_tok[sid] = 0
+        self._set_slot_gauge()
+        return stream
+
+    def _complete(self, stream, expired=False):
+        """Finish a request whose slot is vacated: its last token is
+        emitted (or it is evicted with what it has)."""
         stream.expired = expired
         if stream.trace is not None:
             stream.trace.mark("decode")  # first token -> generation done
@@ -951,14 +1088,13 @@ class DecodeEngine:
                 stream.trace.extra["truncated"] = True
         self._tm.finish_trace(stream.trace,
                               status="evicted" if expired else "completed")
-        stream._finish()
         with self._stats_lock:
             self._n_completed += 1
             if expired:
                 self._n_evicted += 1
         if expired and self._tm.ON:
             self._tm.REGISTRY.counter("serve.evict_total").inc()
-        self._set_slot_gauge()
+        stream._finish()
 
     def _shed_one(self, admitted=False):
         with self._stats_lock:
@@ -988,6 +1124,13 @@ class DecodeEngine:
             self._cache.slots.free(sid)
             self._tm.finish_trace(stream.trace, status=status)
             stream._finish(err)
+        # requests whose last tokens were dispatched and never read back
+        # hold no slot any more: only these records know them
+        while self._inflight:
+            for _, _, stream in self._inflight.popleft().rows:
+                if not stream.done:
+                    self._tm.finish_trace(stream.trace, status=status)
+                    stream._finish(err)
         for stream in pending:
             self._shed_one(admitted=True)
             self._tm.finish_trace(stream.trace, status=status)
@@ -1004,7 +1147,11 @@ class DecodeEngine:
 
     # ----------------------------------------------------------- reporting
     def stats(self):
-        """Engine accounting independent of the global telemetry gate."""
+        """Engine accounting independent of the global telemetry gate.
+        ``ticks``, ``ticks_overlapped``, ``mean_slot_occupancy`` and
+        ``tokens`` count what has been read back; an idle engine has
+        nothing in flight (the scheduler settles it before it waits for
+        work), so after the last ``result()`` they count everything."""
         with self._stats_lock:
             ticks = self._n_ticks
             occ = self._occupancy_sum / ticks if ticks else 0.0
@@ -1015,6 +1162,7 @@ class DecodeEngine:
                 "evicted": self._n_evicted,
                 "tokens": self._n_tokens,
                 "ticks": ticks,
+                "ticks_overlapped": self._n_overlapped,
                 "prefills": self._n_prefills,
                 "pending": self._pending_count,
                 "prefix_hit_tokens": self._n_prefix_hit_tokens,
@@ -1059,19 +1207,21 @@ class DecodeEngine:
     # ---------------------------------------------------------- drain/resume
     def drain(self, timeout=None):
         """Shed new submissions (``ShedError``) while already-accepted
-        work — live slots AND queued-but-unslotted requests — runs to
-        completion. Blocks until idle (or ``timeout`` seconds); returns
-        True when fully drained. ``resume()`` reopens the gate."""
+        work — live slots, tokens in flight AND queued-but-unslotted
+        requests — runs to completion. Blocks until idle (or ``timeout``
+        seconds); returns True when fully drained. ``resume()`` reopens
+        the gate."""
         self._draining = True
         deadline = None if timeout is None \
             else time.perf_counter() + float(timeout)
         while True:
             with self._stats_lock:
                 pending = self._pending_count
-            if not self._slot_req and pending <= 0:
+            idle = not self._slot_req and not self._inflight
+            if idle and pending <= 0:
                 return True
             if self._dead is not None or self._closed:
-                return not self._slot_req
+                return idle
             if deadline is not None and time.perf_counter() > deadline:
                 return False
             time.sleep(0.002)

@@ -32,6 +32,13 @@ greedy choice; the pool's layout, how a layer's K/V is written and how it
 is attended over live in :mod:`cache` alone. (Manifest version 3: an
 artefact exported with an older pool layout is refused, not loaded.)
 
+Beside the three, with K = 1, one tiny program a batch bucket
+(``place``, compiled in ``warmup`` from the geometry alone, so an export
+needs no file for it) puts a prefill's first tokens into the (S, 1) token
+vector the next tick reads: the engine keeps that vector on the device
+(a tick's token output IS the next tick's operand) and never waits for a
+prefill before it dispatches the tick that follows.
+
 All three donate the pool pair (pool in, pool out — a single device
 residency; on backends without donation support XLA falls back to
 copying), and every write into it is an indexed update of whole pages,
@@ -137,6 +144,7 @@ class DecodePrograms:
         self._graph_params = {}  # graph key -> ordered param names
         self._params = {}       # name -> raw device array
         self._programs = {}     # ("decode", K) | ("prefill"[_ext], B, T)
+        self._places = {}       # batch bucket -> the place program
         self._costs = {}        # program key -> (flops, bytes_accessed)
         self._signatures = {}   # str key -> trace signature
         self.cache_shape = None  # one pool's shape (cache.POOL_AXES)
@@ -494,12 +502,54 @@ class DecodePrograms:
         outs = prog(*args)
         return outs if isinstance(outs, (tuple, list)) else (outs,)
 
+    def _token_sharding(self):
+        """Where the tick wants its token operand (None: the one device)."""
+        if self.tp == 1:
+            return None
+        self.ensure("decode")
+        return self._in_shardings[("decode", self.speculate_k)][0]
+
+    def token_vector(self):
+        """The (S, 1) device-side token vector a K = 1 engine starts from."""
+        import jax
+
+        return jax.device_put(onp.zeros((self.num_slots, 1), "int32"),
+                              self._token_sharding())
+
+    def _place_program(self, batch):
+        """Compile (memoized) the place program of one batch bucket."""
+        prog = self._places.get(batch)
+        if prog is None:
+            import jax
+
+            def fn(tokens, first, slots):
+                return tokens.at[slots, 0].set(first, mode="drop")
+
+            fn.__name__ = f"mxtpu_serve_place_b{batch}"
+            sharding = self._token_sharding()
+            examples = [jax.device_put(onp.zeros(shape, "int32"), sharding)
+                        for shape in ((self.num_slots, 1), (batch,),
+                                      (batch,))]
+            prog = self._places[batch] = jax.jit(fn).lower(
+                *examples).compile()
+        return prog
+
+    def place(self, tokens, first, slots):
+        """``tokens`` (S, 1) with ``tokens[slots[i], 0] = first[i]``: a
+        prefill's first tokens (B,), still on the device, go where the
+        next tick reads them. A row of the bucket that holds no request
+        names slot ``num_slots``, past the vector: dropped. No operand
+        is donated."""
+        return self._place_program(int(first.shape[0]))(
+            tokens, first, onp.asarray(slots, "int32"))
+
     def warmup(self):
         """Compile the whole table: decode_tick_k + every (batch, len)
-        prefill (and prefix-join) bucket. After this, serving compiles
-        nothing. Tuned kernel configs (``MXTPU_TUNE=1``) preload first so
-        each trace resolves its blocks from the persisted winners — the
-        engine never tunes online."""
+        prefill (and prefix-join) bucket, and with K = 1 the place program
+        of every batch bucket. After this, serving compiles nothing. Tuned
+        kernel configs (``MXTPU_TUNE=1``) preload first so each trace
+        resolves its blocks from the persisted winners — the engine never
+        tunes online."""
         from ...tune import preload as _tune_preload
 
         _tune_preload()
@@ -509,6 +559,9 @@ class DecodePrograms:
                 self.ensure("prefill", batch=B, length=T)
                 if self.prefix_cache:
                     self.ensure("prefill_ext", batch=B, length=T)
+        if self.speculate_k == 1:
+            for B in self.batch_ladder:
+                self._place_program(B)
 
     # ------------------------------------------------------------- manifests
     def manifest_dict(self, cache_dir=None, graphs=None):

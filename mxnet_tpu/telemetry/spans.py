@@ -44,19 +44,36 @@ SPANS = {
     "serve.prefill.host": "building tokens/valid/start/table and the "
                           "slots' page-table rows (batch, length, rids, "
                           "queue_wait_ms_max)",
-    "serve.prefill.dispatch": "device_put of the prefill operands and the "
-                              "program call (batch, length)",
-    "serve.wait_prefill": "reading the first tokens back from the device",
-    "serve.prefill.commit": "radix insert, first-token emit (the clients' "
-                            "on_token runs here) and retire",
+    "serve.prefill.dispatch": "device_put of the prefill operands, the "
+                              "program call, with speculate_k == 1 the "
+                              "place program that hands the first tokens "
+                              "to the next tick on the device, and the "
+                              "slots' bookkeeping: lengths, radix insert "
+                              "(batch, length)",
+    "serve.wait_prefill": "reading a prefill's first tokens back from the "
+                          "device: with speculate_k == 1 AFTER the tick "
+                          "that follows it was dispatched",
+    "serve.prefill.commit": "first-token emit (the clients' on_token runs "
+                            "here) and finishing a one-token request "
+                            "(tokens)",
     "serve.tick.grow": "page-table growth over the live slots (live, "
                        "starved)",
-    "serve.tick.draft": "draft proposal, only when speculate_k > 1",
-    "serve.tick.dispatch": "three device_puts and the tick program call "
+    "serve.tick.draft": "draft proposal and the token operand, only "
+                        "when speculate_k > 1",
+    "serve.tick.dispatch": "two device_puts and the tick program call; "
+                           "with speculate_k == 1 the token operand is "
+                           "the tick before's output, still on the "
+                           "device, and every row's one token is "
+                           "accounted here (lengths, which requests end, "
+                           "their slots and pages given back). One a tick "
                            "(live)",
-    "serve.wait_tick": "reading this tick's tokens back from the device",
-    "serve.tick.commit": "accept, emit (the clients' on_token runs here) "
-                         "and retire (tokens)",
+    "serve.wait_tick": "reading a tick's tokens back from the device: "
+                       "with speculate_k == 1 those of the tick BEFORE "
+                       "the one just dispatched, which runs meanwhile",
+    "serve.tick.commit": "emit (the clients' on_token runs here) and "
+                         "finish the requests the tick ended, for the "
+                         "tick just read back; with speculate_k > 1 "
+                         "also accept (tokens)",
     # the caller's thread inside CompiledTrainStep (train_step.py)
     "train.assemble": "gathering the parameter, state and frozen arrays",
     "train.key": "drawing the step's PRNG key(s)",

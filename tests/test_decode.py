@@ -954,3 +954,248 @@ def test_decode_export_roundtrip(net, tmp_path):
             "re-imported decode engine compiled at steady state"
     finally:
         eng2.close()
+
+
+# -- one tick in flight (ISSUE 33): speculate_k == 1 ----------------------------
+def _plain_engine(net, **kw):
+    kw.setdefault("num_slots", 4)
+    kw.setdefault("max_len", MAX_LEN)
+    kw.setdefault("max_prompt_len", 16)
+    kw.setdefault("prefill_batch", 2)
+    kw.setdefault("page_tokens", 8)
+    kw.setdefault("speculate_k", 1)
+    kw.setdefault("prefix_cache", False)
+    kw.setdefault("cache_dir", False)
+    eng = DecodeEngine(net, **kw)
+    eng.warmup()
+    return eng
+
+
+@pytest.fixture(scope="module")
+def plain_engine(net):
+    eng = _plain_engine(net)
+    yield eng
+    eng.close()
+
+
+class _Compiles:
+    """Counts what jax builds, as the benchmark's compile log does."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.n = 0
+        monitoring.register_event_duration_secs_listener(self._duration)
+
+    def _duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.n += 1
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    return _Compiles()
+
+
+def _ticks_in_flight(eng):
+    return sum(f.tick for f in eng._inflight)
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_tokens_with_a_tick_in_flight_are_the_models_greedy_tokens(
+        net, prefix_cache, compiles):
+    """More requests than slots, budgets from 1 to 9, a shared page-long
+    prefix among half of them, arrivals spread over the run: requests are
+    admitted, finish and are replaced while ticks are in flight, and each
+    gets the model's own greedy continuation token for token. Nothing is
+    compiled after warmup()."""
+    import time
+
+    eng = _plain_engine(net, prefix_cache=prefix_cache)
+    try:
+        built = compiles.n
+        prompts = _prompts(14, lo=1, hi=16, seed=33)
+        shared = _prompts(1, lo=9, hi=10, seed=34)[0]
+        for i in range(0, 14, 2):
+            prompts[i] = shared + prompts[i][:6]
+        budgets = [1 + (5 * i) % 9 for i in range(14)]
+        seen = [[] for _ in prompts]
+        streams = []
+        for i, (p, b) in enumerate(zip(prompts, budgets)):
+            streams.append(eng.submit(p, max_new_tokens=b,
+                                      on_token=seen[i].append))
+            if i % 3 == 2:
+                time.sleep(0.01)
+        results = [s.result(timeout=120) for s in streams]
+        # (the model's own loop below builds programs of its own)
+        assert compiles.n == built, "compiled after warmup()"
+        for p, b, s, got, out in zip(prompts, budgets, streams, seen,
+                                     results):
+            assert out == _naive(net, p, b) == got
+            assert not s.truncated and not s.expired
+        st = eng.stats()
+        assert st["completed"] == 14 and st["tokens"] == sum(budgets)
+        assert st["slots_live"] == 0 and st["kv_pages_live"] == (
+            st["prefix_cache"]["pages"] if prefix_cache else 0)
+        assert 0 < st["ticks_overlapped"] <= st["ticks"]
+        assert (st["prefix_hit_tokens"] > 0) == prefix_cache
+    finally:
+        eng.close()
+
+
+def test_a_long_saturated_run_overlaps_nearly_every_tick(net, plain_engine):
+    """Slots full for hundreds of ticks: all but the first tick after an
+    idle moment go out while the tick before is unread; stats() after the
+    last result and drain() have nothing in flight left to count."""
+    eng = plain_engine
+    st0 = eng.stats()
+    prompts = _prompts(8, lo=2, hi=8, seed=35)
+    streams = [eng.submit(p, max_new_tokens=50) for p in prompts]
+    assert eng.drain(timeout=300) is True
+    assert not eng._inflight and all(s.done for s in streams)
+    eng.resume()
+    for p, s in zip(prompts, streams):
+        assert s.result(timeout=1) == _naive(net, p, 50)
+    st = eng.stats()
+    ticks = st["ticks"] - st0["ticks"]
+    # two waves of four requests: 2 x 49 ticks
+    assert ticks >= 98
+    assert st["tokens"] - st0["tokens"] == 8 * 50
+    assert (st["ticks_overlapped"] - st0["ticks_overlapped"]) / ticks > 0.9
+    # per tick, for the slots the tick ran with (as the benchmark reads it)
+    occupancy = (st["mean_slot_occupancy"] * st["ticks"]
+                 - st0["mean_slot_occupancy"] * st0["ticks"]) / ticks
+    assert 0.9 < occupancy <= 1.0
+
+
+def test_speculation_keeps_nothing_in_flight(net, warm_engine):
+    """speculate_k > 1 drafts from the accepted tokens on the host: every
+    program is read back before the next, the tokens are what they were."""
+    prompts = _prompts(6, seed=36)
+    seen = []
+    streams = [warm_engine.submit(
+        p, max_new_tokens=9,
+        on_token=lambda _t: seen.append(len(warm_engine._inflight)))
+        for p in prompts]
+    for p, s in zip(prompts, streams):
+        assert s.result(timeout=120) == _naive(net, p, 9)
+    # on_token runs in the commit of the one record there is
+    assert set(seen) == {1}
+    st = warm_engine.stats()
+    assert st["ticks"] > 0 and st["ticks_overlapped"] == 0
+
+
+def test_a_slot_evicted_with_its_row_in_flight_emits_no_more(net,
+                                                             plain_engine):
+    """The victim's deadline passes while the commit of tick n runs, that
+    is with its row of tick n+1 already dispatched: it finishes with the
+    tokens committed so far, the row in flight is dropped, its pages go
+    back once, and its neighbour's tokens are untouched."""
+    import time
+
+    eng = plain_engine
+    st0 = eng.stats()
+    a, b = [7, 3, 9, 2], [4, 4, 8, 1, 6]
+    seen = []
+
+    def on_token(_tok):
+        seen.append(_ticks_in_flight(eng))
+        if len(seen) == 3:
+            victim.deadline = time.perf_counter() - 1.0
+
+    # the callback runs on the scheduler's thread after submit() returned
+    other = eng.submit(b, max_new_tokens=20)
+    victim = eng.submit(a, max_new_tokens=40, deadline_ms=600_000,
+                        on_token=on_token)
+    got = victim.result(timeout=120)
+    assert victim.expired and got == _naive(net, a, 40)[:3]
+    # token 3 came with tick 2, and tick 3 was out before it was read
+    assert seen == [seen[0], 2, 2]
+    assert other.result(timeout=120) == _naive(net, b, 20)
+    assert eng.drain(timeout=60) and eng.healthy
+    eng.resume()
+    st = eng.stats()
+    assert st["evicted"] - st0["evicted"] == 1
+    assert st["tokens"] - st0["tokens"] == 3 + 20
+    assert st["kv_pages_live"] == 0 and st["slots_live"] == 0
+    # the slot and the pages serve the next request
+    assert eng.submit(a, max_new_tokens=6).result(timeout=120) == \
+        _naive(net, a, 6)
+
+
+def test_a_starved_slot_with_a_tick_in_flight_emits_one_more_token(net):
+    """Two requests grow in step until the pool has no page for either:
+    each commits one more token (as the synchronous engine did), retires
+    truncated with no row in the tick after, and frees its pages once."""
+    eng = _plain_engine(net, num_slots=2, kv_pages=4)
+    try:
+        prompts = [[5, 1, 7, 2, 9, 3], [8, 2, 6, 4, 1, 7]]
+        streams = [eng.submit(p, max_new_tokens=30) for p in prompts]
+        for p, s in zip(prompts, streams):
+            got = s.result(timeout=120)
+            # first token, positions 6..15 with a page under them, and the
+            # one token of the tick that found the pool dry
+            assert len(got) == 1 + 10 + 1 and s.truncated
+            assert got[:-1] == _naive(net, p, 11)
+        assert eng.drain(timeout=60) and eng.healthy
+        st = eng.stats()
+        assert st["page_starved"] == 2 and st["completed"] == 2
+        assert st["kv_pages_live"] == 0 and st["tokens"] == 24
+        assert st["ticks"] == 11
+    finally:
+        eng.close()
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("times", [2, 50])
+def test_a_fault_at_the_tick_with_a_tick_in_flight(net, times):
+    """The fourth tick's dispatch fails while the third is unread. Within
+    the retry budget (2) the dispatch is made again and no client sees it;
+    past it every stream, the one whose last token was in flight too, ends
+    in EngineDeadError with the cause: none hangs."""
+    from mxnet_tpu.serve.decode import EngineDeadError
+    from mxnet_tpu.testing import chaos
+
+    eng = _plain_engine(net, num_slots=2)
+    try:
+        chaos.inject("decode.tick", "raise", countdown=3, times=times)
+        p, q = [3, 1, 4, 1], [2, 7, 1, 8, 2]
+        short = eng.submit(p, max_new_tokens=4)     # ends with tick 3
+        long = eng.submit(q, max_new_tokens=12)
+        if times <= 2:
+            assert short.result(timeout=120) == _naive(net, p, 4)
+            assert long.result(timeout=120) == _naive(net, q, 12)
+            assert tm.REGISTRY.counter("serve.retries").value == times
+            assert eng.healthy and eng.stats()["ticks_overlapped"] >= 9
+        else:
+            for s in (short, long):
+                with pytest.raises(EngineDeadError) as err:
+                    s.result(timeout=120)
+                assert isinstance(err.value.__cause__, chaos.FaultError)
+            assert short.tokens == _naive(net, p, 4)[:3]
+            assert not eng.healthy and not eng._inflight
+    finally:
+        chaos.clear()
+        eng.close()
+
+
+def test_an_engine_from_an_export_overlaps_too(net, tmp_path, compiles):
+    prefix = str(tmp_path / "gpt")
+    eng = _plain_engine(net)
+    try:
+        eng.export(prefix)
+    finally:
+        eng.close()
+    eng2 = DecodeEngine.from_export(prefix, cache_dir=False)
+    try:
+        built = compiles.n
+        prompts = _prompts(6, seed=37)
+        streams = [eng2.submit(p, max_new_tokens=12) for p in prompts]
+        results = [s.result(timeout=120) for s in streams]
+        assert compiles.n == built, "compiled after from_export()"
+        for p, out in zip(prompts, results):
+            assert out == _naive(net, p, 12)
+        st = eng2.stats()
+        assert st["ticks_overlapped"] / st["ticks"] > 0.8
+    finally:
+        eng2.close()
