@@ -21,24 +21,48 @@ that ``autograd``, ``hybridize`` and ``compile_step`` see them like any other:
 
 Dropless with static shapes. The token-expert pairs whose expert is held are
 sorted by expert (a stable argsort: inside a group the tokens stay in order)
-and laid out in tiles of ``tile`` rows, each group padded to whole tiles. The
-worst case (every pair here) fixes the SHAPES: ``N * min(k, held)`` rows plus
-one tile of padding an expert. The WORK follows the routing: a loop over the
-experts held, and inside it a ``lax.fori_loop`` over that expert's tiles in
-use (none where it received nothing), which gathers a tile's rows from
-``x``, multiplies them with the expert (two grouped matrix products, ragged
-groups, no capacity factor) and adds the weighted rows back into ``y``. The
-expert's weights are read once an expert, however many tiles it has. No
-token is dropped however skewed the router is.
+and laid out in tiles of ``tile`` rows, each group padded to whole tiles
+(``_plan``; worked out a tile at a time, so planning is a few dozen small
+operations whatever the row count). The worst case (every pair here) fixes
+the SHAPES: ``N * min(k, held)`` rows plus one tile of padding an expert. The
+WORK follows the routing, and no token is dropped however skewed the router
+is. Two forms of the forward pass compute the same sum:
+
+*The kernel* (``pallas_kernels.grouped_experts``, ``mxtpu_experts_swiglu``;
+on the TPU wherever ``experts_kernel_serves`` says its shapes fit: the Granite
+serving tick and prefills). The layout's rows are gathered ONCE (a padding
+slot takes any row), and one kernel walks the tiles: ``gate_up`` and ``down``
+go in whole, the tile -> expert table, each tile's rows in use and each
+slot's token and routing weight are scalar-prefetched, and the weight
+blocks' index maps pick the tile's expert IN PLACE (a block is one expert's
+matrix, whole). An expert with several tiles keeps its block index and is
+fetched once; an expert no token chose has no tile and costs no bytes; the
+grid steps past the last tile in use repeat its indices and do nothing.
+Both products accumulate in float32 on the matrix unit (float32 rows and
+weights are rounded to bfloat16 there, as the chip's default precision
+rounds them), SwiGLU runs on the float32 product and is rounded once, and
+each row in use is weighted and added to its token's float32 sum, which
+stays in fast memory from the first tile to the last: no buffer of the
+layout's results exists.
+
+*The loop* (everywhere else: the CPU, tiny tiles, and shapes whose sums or
+experts do not fit the kernel's fast memory, such as 4096 float32 rows of
+2048): a ``lax.fori_loop`` over the experts held, and inside it one over that
+expert's tiles in use (none where it received nothing), which gathers a
+tile's rows from ``x``, multiplies them with the expert and adds the weighted
+rows back into ``y``. It is the reference the kernel is held to
+(``tests/test_moe_kernel.py``).
 
 The backward pass is written out (``jax.custom_vjp``): reverse-mode autodiff
 cannot run a loop of unknown length backwards, and through a scan it would
-keep a copy of the expert's weights for every tile. It walks the same tiles,
-recomputes a tile's activations, and accumulates the expert's weight
-gradients in the inner loop's carry, written back once an expert.
+keep a copy of the expert's weights for every tile. It is the loop's form
+whichever way the forward ran: it walks the same tiles, recomputes a tile's
+activations, and accumulates the expert's weight gradients in the inner
+loop's carry, written back once an expert.
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -47,6 +71,7 @@ import numpy as onp
 from jax import lax
 
 from ..base import MXNetError
+from . import pallas_kernels as pk
 from .registry import register
 
 
@@ -74,39 +99,54 @@ def _tile_rows(n_tokens, tile):
     return int(min(tile, -(-n_tokens // 8) * 8))
 
 
+_Plan = collections.namedtuple(
+    "_Plan", "tok pair w_slot tile_lo tile_hi tile_expert tile_rows")
+
+
 def _plan(weights, experts, lo, hi, tm):
     """The tiled layout of the pairs held here.
 
     Returns ``tok`` (slots,) token id of each slot (out of range, ascending
     and distinct, where the slot is padding), ``pair`` (slots,) the flat
     pair id (N * k where padding), ``w_slot`` (slots,) the pair's routing
-    weight (0 where padding), and ``tile_lo``, ``tile_hi`` (held,): expert
-    g's tiles are ``tile_lo[g] .. tile_hi[g] - 1`` (none where it received
-    nothing). ``slots = tiles * tm`` is the static worst case."""
+    weight (0 where padding), ``tile_lo``, ``tile_hi`` (held,): expert g's
+    tiles are ``tile_lo[g] .. tile_hi[g] - 1`` (none where it received
+    nothing), and by tile ``tile_expert`` (tiles,), its expert, and
+    ``tile_rows`` (tiles,), its rows in use (0 past the last tile in use,
+    ``tile_hi[-1]``). ``slots = tiles * tm`` is the static worst case."""
     N, k = experts.shape
     G, P = hi - lo, N * k
     max_tiles = -(-N * min(k, G) // tm) + G
+    i32 = jnp.int32
     here = (experts >= lo) & (experts < hi)
     eid = jnp.where(here, experts - lo, G).reshape(P)
-    order = jnp.argsort(eid, stable=True).astype(jnp.int32)
-    sizes = jnp.sum(eid[:, None] == jnp.arange(G, dtype=jnp.int32), axis=0,
-                    dtype=jnp.int32)
+    order = jnp.argsort(eid, stable=True).astype(i32)
+    held = jnp.arange(G, dtype=i32)
+    sizes = jnp.sum(eid[:, None] == held, axis=0, dtype=i32)
     starts = jnp.cumsum(sizes) - sizes
     tiles = (sizes + tm - 1) // tm
-    tile_end = jnp.cumsum(tiles)
-    n_tiles = tile_end[-1]
-    pstart = (tile_end - tiles) * tm
-    tile_expert = jnp.minimum(jnp.searchsorted(
-        tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
-        G - 1).astype(jnp.int32)
-    slot = jnp.arange(max_tiles * tm, dtype=jnp.int32)
-    g = tile_expert[slot // tm]
-    j = slot - pstart[g]
-    valid = (j < sizes[g]) & (slot // tm < n_tiles)
-    pair = order[jnp.clip(starts[g] + j, 0, P - 1)]
-    tok = jnp.where(valid, pair // k, N + slot % tm)
+    tile_hi = jnp.cumsum(tiles)
+    tile_lo = tile_hi - tiles
+    # everything below is worked out a TILE (a few dozen), not a slot: a
+    # tile finds its group's numbers through a one-hot row over the experts
+    t = jnp.arange(max_tiles, dtype=i32)
+    tile_expert = jnp.minimum(jnp.sum(tile_hi[None, :] <= t[:, None], axis=1,
+                                      dtype=i32), G - 1)
+    mine = tile_expert[:, None] == held
+
+    def of_tile(v):
+        return jnp.sum(jnp.where(mine, v, 0), axis=1)
+
+    before = (t - of_tile(tile_lo)) * tm     # the group's rows in earlier tiles
+    tile_rows = jnp.clip(of_tile(sizes) - before, 0, tm)
+    j = jnp.arange(tm, dtype=i32)
+    valid = (j < tile_rows[:, None]).reshape(-1)
+    pair = order[jnp.clip((of_tile(starts) + before)[:, None] + j, 0, P - 1)
+                 .reshape(-1)]
+    tok = jnp.where(valid, pair // k, jnp.tile(N + j, max_tiles))
     w_slot = jnp.where(valid, weights.reshape(P)[pair], 0)
-    return tok, jnp.where(valid, pair, P), w_slot, tile_end - tiles, tile_end
+    return _Plan(tok, jnp.where(valid, pair, P), w_slot, tile_lo, tile_hi,
+                 tile_expert, tile_rows)
 
 
 def _swiglu(h, F):
@@ -136,7 +176,20 @@ def _routed_experts(x, weights, experts, gate_up, down, lo, hi, tm):
 
 def _routed_fwd(x, weights, experts, gate_up, down, lo, hi, tm):
     F = down.shape[1]
-    tok, _, w_slot, tile_lo, tile_hi = _plan(weights, experts, lo, hi, tm)
+    plan = _plan(weights, experts, lo, hi, tm)
+    res = (x, weights, experts, gate_up, down)
+
+    if pk.experts_kernel_serves(x.shape[0], tm, x.shape[1], F, x.dtype,
+                                gate_up.dtype):
+        # one gather of the layout's rows, then one kernel over its tiles;
+        # a padding slot takes the last token's row, which the kernel may
+        # multiply and never adds to a sum
+        tok = jnp.minimum(plan.tok, x.shape[0] - 1)
+        y = pk.grouped_experts(
+            x.at[tok].get(mode="promise_in_bounds"), gate_up, down,
+            plan.tile_expert, plan.tile_rows, plan.tile_hi[-1], tok,
+            plan.w_slot, x.shape[0])
+        return y.astype(x.dtype), res
 
     # a token's sum over its experts is kept in float32 whatever x is (as
     # are the routing weights): in bfloat16 every one of up to top_k adds
@@ -147,25 +200,26 @@ def _routed_fwd(x, weights, experts, gate_up, down, lo, hi, tm):
         w_gu, w_dn = gate_up[g], down[g]     # read once an expert
 
         def tile(t, y):
-            tk = _tile(tok, t, tm)
+            tk = _tile(plan.tok, t, tm)
             a = _swiglu(_rows(x, tk) @ w_gu, F)[3]
             return _add_rows(
-                y, tk, _tile(w_slot, t, tm)[:, None]
+                y, tk, _tile(plan.w_slot, t, tm)[:, None]
                 * jnp.matmul(a, w_dn, preferred_element_type=acc))
 
-        return lax.fori_loop(tile_lo[g], tile_hi[g], tile, y)
+        return lax.fori_loop(plan.tile_lo[g], plan.tile_hi[g], tile, y)
 
     y = lax.fori_loop(0, hi - lo, expert, jnp.zeros(x.shape, acc))
-    return y.astype(x.dtype), (x, weights, experts, gate_up, down)
+    return y.astype(x.dtype), res
 
 
 def _routed_bwd(lo, hi, tm, res, dy):
     x, weights, experts, gate_up, down = res
     N, k = experts.shape
     F = down.shape[1]
-    tok, pair, w_slot, tile_lo, tile_hi = _plan(weights, experts, lo, hi,
-                                                tm)
-    w_slot = w_slot.astype(x.dtype)     # the backward runs in x's dtype
+    plan = _plan(weights, experts, lo, hi, tm)
+    tok, pair, tile_lo, tile_hi = (plan.tok, plan.pair, plan.tile_lo,
+                                   plan.tile_hi)
+    w_slot = plan.w_slot.astype(x.dtype)   # the backward runs in x's dtype
 
     def expert(g, carry):
         dx, dgu, ddn, dw_slot = carry

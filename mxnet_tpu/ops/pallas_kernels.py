@@ -1,5 +1,5 @@
-"""Pallas TPU kernels: flash attention, paged decode attention, fused layer
-norm, fused softmax.
+"""Pallas TPU kernels: flash attention, paged decode attention, the routed
+experts' grouped products, fused layer norm, fused softmax.
 
 TPU-native replacement for the reference's hand-fused CUDA ops
 (src/operator/contrib/transformer.cc fused attention projections,
@@ -1256,3 +1256,125 @@ def _paged_decode_tpu(q, k_pool, v_pool, layer, page_table, positions, scale):
     )(page_table, positions, layer.reshape(1),
       (q * scale).transpose(0, 1, 3, 2), k_pool, v_pool)
     return out.transpose(0, 1, 3, 2)
+
+
+# ---------------------------------------------------------------------------
+# routed experts, forward: the two grouped products of a routed layer over
+# the tiled layout of ``ops/moe.py::_plan``, each expert's matrices read
+# where they lie in the stacked arrays, each token's weighted sum kept in
+# fast memory
+# ---------------------------------------------------------------------------
+def _experts_resident(n, tm, d, f, x_dtype, w_dtype):
+    """Bytes the kernel keeps in fast memory: an expert's two matrices and a
+    tile of rows, each double-buffered, the tokens' sums (the result's
+    block, two buffers), a tile's results and the float32 products."""
+    w = jnp.dtype(w_dtype).itemsize
+    return (2 * 3 * d * f * w + 2 * tm * d * jnp.dtype(x_dtype).itemsize
+            + 2 * n * d * 4 + tm * d * 4 + 4 * tm * (3 * f + d))
+
+
+def experts_kernel_serves(n, tm, d, f, x_dtype, w_dtype):
+    """Whether ``grouped_experts`` takes these shapes: the lanes whole, the
+    tile whole sublanes of the rows' type, and an expert resident twice
+    (the one in use, the next one on its way) beside the tokens' sums
+    inside what ``_vmem_params`` may ask for. Anything else is the loop's."""
+    sublanes = 32 // jnp.dtype(x_dtype).itemsize
+    return (_use_pallas() and d % 128 == 0 and f % 128 == 0
+            and tm % sublanes == 0
+            and _experts_resident(n, tm, d, f, x_dtype, w_dtype)
+            <= 84 * 2**20)
+
+
+def _experts_kernel(te_ref, nt_ref, rows_ref, tok_ref, w_ref, x_ref, gu_ref,
+                    dn_ref, y_ref, o_ref):
+    """Grid (tile,). ``x_ref`` (tm, D): the rows of one tile, all of one
+    expert; ``gu_ref`` (D, 2F) and ``dn_ref`` (F, D): that expert's
+    matrices, whole; ``y_ref`` (N, D) float32: every token's sum, resident
+    from the first tile to the last; ``o_ref`` (tm, D) float32 scratch: the
+    tile's results. Both products accumulate in float32 on the matrix unit,
+    which takes bfloat16: float32 rows and weights are rounded to it here,
+    as the chip's default precision rounds them, and SwiGLU runs on the
+    float32 product and is rounded once. A tile with at most 32 rows in use
+    multiplies its first 32 only. Then each row in use is weighted and added
+    to its token's sum, in float32."""
+    del te_ref, nt_ref                # the index maps read them
+    t = pl.program_id(0)
+    tm = x_ref.shape[0]
+    f = dn_ref.shape[0]
+    rows = rows_ref[t]
+
+    @pl.when(t == 0)
+    def _start():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    def products(m):
+        h = jnp.dot(x_ref[:m].astype(jnp.bfloat16),
+                    gu_ref[...].astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+        g, u = h[:, :f], h[:, f:]
+        a = g * jax.nn.sigmoid(g) * u
+        o_ref[:m] = jnp.dot(a.astype(jnp.bfloat16),
+                            dn_ref[...].astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
+
+    few = min(tm, 32)
+    pl.when((rows > 0) & (rows <= few))(lambda: products(few))
+    if few < tm:
+        pl.when(rows > few)(lambda: products(tm))
+
+    def add(r, carry):
+        s = t * tm + r
+        tok = pl.ds(tok_ref[s], 1)
+        y_ref[tok, :] = y_ref[tok, :] + w_ref[s] * o_ref[pl.ds(r, 1), :]
+        return carry
+
+    lax.fori_loop(0, rows, add, 0)
+
+
+def grouped_experts(rows, gate_up, down, tile_expert, tile_rows, n_tiles, tok,
+                    w_slot, n):
+    """``y[tok[s]] += w_slot[s] * (silu(r_s W_g) * (r_s W_u)) W_d`` over the
+    slots in use, with the matrices of the slot's tile's expert: ``rows``
+    (tiles * tm, D), ``gate_up`` (held, D, 2F), ``down`` (held, F, D); by
+    tile ``tile_expert`` (ascending) and ``tile_rows`` (its rows in use, the
+    tile's first), int32; ``n_tiles`` int32 scalar, the tiles in use; by
+    slot ``tok`` int32 and ``w_slot`` float32. Returns (n, D) float32.
+
+    The stacked arrays go in whole. The tables are prefetched and the weight
+    blocks' index maps pick the expert's matrices in place: an expert with
+    several tiles keeps its block index and is fetched once, one with no
+    tile is never fetched, and the steps past the last tile in use repeat
+    its indices (no bytes) and do nothing."""
+    tiles = tile_expert.shape[0]
+    tm = rows.shape[0] // tiles
+    _, f, d = down.shape
+
+    def tile(t, te, nt, *_):
+        return (jnp.minimum(t, jnp.maximum(nt[0] - 1, 0)), 0)
+
+    def expert(t, te, nt, *_):
+        return (te[tile(t, te, nt)[0]], 0, 0)
+
+    w = gate_up.dtype.itemsize
+    return pl.pallas_call(
+        _experts_kernel,
+        name="mxtpu_experts_swiglu",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(tiles,),
+            in_specs=[pl.BlockSpec((tm, d), tile),
+                      pl.BlockSpec((None, d, 2 * f), expert),
+                      pl.BlockSpec((None, f, d), expert)],
+            out_specs=pl.BlockSpec((n, d), lambda t, *_: (0, 0)),
+            scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * tiles * tm * d * f,
+            bytes_accessed=3 * gate_up.shape[0] * d * f * w
+            + tiles * tm * d * rows.dtype.itemsize + n * d * 4,
+            transcendentals=tiles * tm * f),
+        interpret=_interpret(),
+        **_vmem_params(_experts_resident(n, tm, d, f, rows.dtype,
+                                         gate_up.dtype)),
+    )(tile_expert, n_tiles.reshape(1), tile_rows, tok, w_slot, rows, gate_up,
+      down)

@@ -306,22 +306,90 @@ def test_chunked_delta_rule_compiles_at_the_cells_shapes(chip_kernels):
     assert ma.temp_size_in_bytes < 3 * 2**30
 
 
+def _routed_args(chip, rows, units, held, width, dtype):
+    """Operands of ``routed_experts``: ``rows`` tokens of ``units``, top-10,
+    ``held`` experts of ``width``."""
+    sds = lambda shape, dt=dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    return (sds((rows, units)), sds((rows, 10), jnp.float32),
+            sds((rows, 10), jnp.int32), sds((held, units, 2 * width)),
+            sds((held, width, units)))
+
+
+def _experts_kernels(text):
+    import re
+
+    # under value_and_grad the instruction is ``jvp_mxtpu_experts_swiglu_``
+    return len(re.findall(r"%(jvp_)?mxtpu_experts_swiglu[_.\d]* = ", text))
+
+
+def _loops(text):
+    return [line for line in text.splitlines() if " while(" in line]
+
+
 def test_routed_experts_compile_at_the_cells_shapes(chip_kernels):
-    """4096 tokens, top-10 of 512, experts 0-31 of width 512 held: the
-    dropless grouped products, forward and backward, as loops over the tiles
-    in use (a ``while`` each), and no buffer of tokens x top-k rows."""
+    """4096 tokens, top-10 of 512, experts 0-31 of width 512 held, float32,
+    forward and backward: the dropless grouped products as loops over the
+    tiles in use (a ``while`` each), and no buffer of tokens x top-k rows.
+    The forward kernel does not take this shape (4096 float32 sums of 2048
+    beside float32 experts are more than it may keep in fast memory, and
+    the layout's 45056 slots cost XLA's gather more than the experts'
+    bytes: PERF.md section 6, PR 35), so the forward is the loop too."""
     from mxnet_tpu.ops.registry import get_op
 
     op = get_op("routed_experts")._make_fn(experts_held=(0, 32))
-    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, dt, sharding=chip_kernels)
-    args = (sds((QT, 2048)), sds((QT, 10)), sds((QT, 10), jnp.int32),
-            sds((32, 2048, 1024)), sds((32, 512, 2048)))
     text = jax.jit(jax.value_and_grad(
         lambda x, w, i, gu, dn: op(x, w, i, gu, dn).sum(),
-        argnums=(0, 1, 3, 4))).lower(*args).compile().as_text()
-    assert text.count(" while(") >= 2
+        argnums=(0, 1, 3, 4))).lower(*_routed_args(
+            chip_kernels, QT, 2048, 32, 512, jnp.float32)).compile().as_text()
+    assert _experts_kernels(text) == 0
+    assert len(_loops(text)) == 4
+    assert sum("transpose(" in w for w in _loops(text)) == 2   # backward
     assert "f32[40960,2048]" not in text and "f32[45056,2048]" not in text
+
+
+def test_experts_kernel_compiles_in_float32_under_value_and_grad(
+        chip_kernels):
+    """Qwen3-Next's widths (hidden 2048, 32 experts of 512, float32 rows and
+    weights, rounded at the matrix unit inside the kernel) at the 1024 rows
+    the kernel does take: one kernel in the forward, the backward's two
+    loops (experts, and tiles inside) as they were, and no loop forward."""
+    from mxnet_tpu.ops.registry import get_op
+
+    op = get_op("routed_experts")._make_fn(experts_held=(0, 32))
+    text = jax.jit(jax.value_and_grad(
+        lambda x, w, i, gu, dn: op(x, w, i, gu, dn).sum(),
+        argnums=(0, 1, 3, 4))).lower(*_routed_args(
+            chip_kernels, 1024, 2048, 32, 512, jnp.float32)) \
+        .compile().as_text()
+    assert _experts_kernels(text) == 1
+    assert len(_loops(text)) == 2
+    assert all("transpose(" in w for w in _loops(text))
+
+
+@pytest.mark.parametrize("rows", [32, 1024], ids=["tick", "prefill_1024"])
+def test_experts_kernel_compiles_at_the_granite_cells_shapes(chip_kernels,
+                                                             rows):
+    """32 slots' rows (tiles of 32) and a 1024-token prefill's (tiles of 128)
+    on 36 experts of 768 at hidden 4096 in bfloat16: one kernel with an
+    expert's two matrices as its blocks (18.9 MB, double-buffered: a limit
+    above Mosaic's default), no loop, no copy of any matrix, and no buffer
+    of the layout's results (the tokens' sums stay in the kernel)."""
+    import re
+
+    from mxnet_tpu.ops.registry import get_op
+
+    op = get_op("routed_experts")._make_fn(experts_held=(0, 36))
+    text = jax.jit(op).lower(*_routed_args(
+        chip_kernels, rows, 4096, 36, 768, jnp.bfloat16)).compile().as_text()
+    assert _experts_kernels(text) == 1
+    assert " while(" not in text
+    assert not re.search(r"bf16\[(\d+,)?4096,1536\]\S* (copy|fusion)\(",
+                         text)
+    assert not re.search(r"f32\[\d{4,},4096\]", text.replace(
+        f"f32[{rows},4096]", ""))
+    assert pk._vmem_params(pk._experts_resident(
+        rows, min(rows, 128), 4096, 768, jnp.bfloat16, jnp.bfloat16))
 
 
 # -- the Granite-4.0-H serving cell's shapes: bfloat16, 32 slots -------------
@@ -366,7 +434,10 @@ def test_granite_serving_programs_compile_at_the_cells_widths(
     are a few MB beside 135 MB of state a layer); the tick holds the paged
     kernel at 32 query heads on 8 KV heads of 128 over a bfloat16 pool, the
     prefill the flash kernel; neither holds a float32 copy of an expert
-    matrix (the grouped products take the bfloat16 weights as they lie)."""
+    matrix (the grouped products take the bfloat16 weights as they lie).
+    The routed layers' products are one ``mxtpu_experts_swiglu`` kernel a
+    layer, named under the scope ``experts``: no loop is left in that scope
+    and nothing slices an expert's matrix out of the stacked arrays."""
     import re
 
     progs = granite_programs
@@ -400,6 +471,15 @@ def test_granite_serving_programs_compile_at_the_cells_widths(
     assert ma.alias_size_in_bytes >= donated
     assert not re.search(r"f32\[(\d+,)?4096,1536\]|f32\[(\d+,)?768,4096\]",
                          text)
+    assert not re.search(r"dynamic[-_]slice[.\d]* = "
+                         r"bf16\[(1,)?(4096,1536|768,4096)\]", text)
+    kernels = [line for line in text.splitlines()
+               if re.search(r"%mxtpu_experts_swiglu[.\d]* = ", line)]
+    assert len(kernels) == 2
+    assert all(re.search(r'op_name="[^"]*/experts/[^"]*"', k)
+               for k in kernels)
+    assert not [line for line in text.splitlines() if " while(" in line
+                and re.search(r'op_name="[^"]*/experts/', line)]
     if family == "decode":
         assert len(re.findall(r"%mxtpu_paged_decode[.\d]* = ", text)) == 1
         assert ma.temp_size_in_bytes < 64 * 2**20
