@@ -12,9 +12,12 @@ from .qwen3_next import Qwen3NextModel, qwen3_next, qwen3_next_tiny
 from . import granite_hybrid as _granite_hybrid
 from .granite_hybrid import (GraniteHybridModel, granite_hybrid,
                              granite_hybrid_tiny)
+from . import axk1 as _axk1
+from .axk1 import AXK1Model, axk1, axk1_tiny
 
 __all__ = ["vision", "get_model", "bert", "bert_base", "bert_large",
            "gpt", "GPTModel", "gpt2_small", "gpt2_medium", "gpt_tiny",
            "BERTModel", "BERTForPretraining", "rnn_lm", "RNNModel",
            "Qwen3NextModel", "qwen3_next", "qwen3_next_tiny",
-           "GraniteHybridModel", "granite_hybrid", "granite_hybrid_tiny"]
+           "GraniteHybridModel", "granite_hybrid", "granite_hybrid_tiny",
+           "AXK1Model", "axk1", "axk1_tiny"]

@@ -283,9 +283,10 @@ class RoutedPlusShared(RoutedExperts):
     and, in a tick, the held experts that got one."""
 
     def __init__(self, units, expert_units, num_experts, top_k, shared_units,
-                 experts_held=None, dtype="float32", **kwargs):
+                 experts_held=None, dtype="float32", norm_topk=True,
+                 **kwargs):
         super().__init__(units, expert_units, num_experts, top_k,
-                         experts_held=experts_held, norm_topk=True,
+                         experts_held=experts_held, norm_topk=norm_topk,
                          dtype=dtype, weight_initializer=_NormalAs(0.02),
                          **kwargs)
         self._shared_units = shared_units

@@ -270,11 +270,34 @@ def paged_decode_attention(query, k_pool, v_pool, layer, page_table,
                scale=scale)
 
 
-def rope(data, rotary_dim=None, theta=10000.0, offset=0):
+def mla_decode_attention(query, pool, layer, page_table, positions,
+                         value_dim, scale):
+    """Absorbed multi-head latent attention read straight from a latent
+    pool: ``query`` (S, K, H, R), each head's ``[W_UK q_nope | q_rope]`` —
+    query k of slot s at ``positions[s] + k`` — against the ONE row a
+    position ``[c (value_dim) | k_rope]`` that the pages ``page_table`` (S,
+    W+1) maps in layer ``layer`` of the pool [pages, layers, 1, R,
+    page_tokens] hold; the values are the row's first ``value_dim`` columns.
+    Returns (S, K, H*value_dim), still latent: the caller applies ``W_UV``.
+    TPU-native extension; see ops/pallas_kernels.py."""
+    return _op("mla_decode_attention", _nd(query), _nd(pool), _nd(layer),
+               _nd(page_table), _nd(positions), value_dim=int(value_dim),
+               scale=float(scale))
+
+
+def rope(data, rotary_dim=None, theta=10000.0, offset=0, positions=None,
+         inv_freq=None):
     """Rotary position embedding (rotate-half) on the first ``rotary_dim``
-    entries of the last axis of ``data`` (B, T, H, D); TPU-native extension."""
-    return _op("rope", _nd(data), rotary_dim=rotary_dim, theta=theta,
-               offset=offset)
+    entries of the last axis of ``data`` (B, T, H, D). Position t of every
+    row is ``offset + t``; ``positions`` (B or 1, T) int32 gives them A ROW
+    instead (a decode tick's slots stand at different positions).
+    ``inv_freq``: the ``rotary_dim / 2`` inverse frequencies as a sequence
+    of floats, for schemes that are not ``theta ** (-i / half)`` (YaRN).
+    Angles, cos, sin and the rotation are float32. TPU-native extension."""
+    args = [_nd(data)] + ([] if positions is None else [_nd(positions)])
+    return _op("rope", *args, rotary_dim=rotary_dim, theta=theta,
+               offset=offset, inv_freq=None if inv_freq is None
+               else tuple(float(v) for v in inv_freq))
 
 
 def causal_conv1d(data, weight, activation=None):
@@ -342,12 +365,15 @@ def gated_delta_rule(query, key, value, g, beta, chunk=64, scale=None,
                _nd(beta), chunk=chunk, scale=scale, l2norm=l2norm)
 
 
-def moe_router(data, weight, top_k=1, norm_topk=True):
+def moe_router(data, weight, top_k=1, norm_topk=True, score="softmax",
+               scaling=1.0):
     """``softmax(data weight^T)`` in float32 over the router's full width,
     its ``top_k`` largest renormalised: ``(weights (N, k), experts (N, k)
-    int32, counts (E,))``. TPU-native extension; see ops/moe.py."""
+    int32, counts (E,))``. ``score="sigmoid"``: ``sigmoid`` an expert in
+    place of the softmax, the chosen ones over their sum + 1e-20, times
+    ``scaling``. TPU-native extension; see ops/moe.py."""
     return _op("moe_router", _nd(data), _nd(weight), top_k=top_k,
-               norm_topk=norm_topk)
+               norm_topk=norm_topk, score=score, scaling=float(scaling))
 
 
 def routed_experts(data, weights, experts, gate_up, down, experts_held=None,

@@ -4,10 +4,13 @@ of a layer that is TOLD WHICH EXPERTS IT HOLDS.
 Beyond the reference (SURVEY §2.2: no expert layer). Two registered ops, so
 that ``autograd``, ``hybridize`` and ``compile_step`` see them like any other:
 
-``moe_router(x, w, top_k, norm_topk)``
+``moe_router(x, w, top_k, norm_topk, score, scaling)``
     ``p = softmax(x w^T)`` over the router's FULL width in float32 at
     ``highest`` matmul precision (a top-k choice is discontinuous: a bf16
     pass flips near-ties), the ``top_k`` largest, renormalised to sum 1.
+    With ``score="sigmoid"`` (DeepSeek-V3's router) ``p = sigmoid(x w^T)``,
+    an expert at a time, the ``top_k`` largest divided by their sum + 1e-20
+    and multiplied by ``scaling``.
     Returns ``(weights (N, k), experts (N, k) int32, counts (E,))`` where
     ``counts[e]`` is how many tokens chose expert ``e`` (no gradient).
 
@@ -45,9 +48,19 @@ each row in use is weighted and added to its token's float32 sum, which
 stays in fast memory from the first tile to the last: no buffer of the
 layout's results exists.
 
-*The loop* (everywhere else: the CPU, tiny tiles, and shapes whose sums or
-experts do not fit the kernel's fast memory, such as 4096 float32 rows of
-2048): a ``lax.fori_loop`` over the experts held, and inside it one over that
+*The blocked kernel* (``pallas_kernels.grouped_experts_blocked``,
+``mxtpu_experts_swiglu_blocked``; wherever an expert is too large to be
+resident whole twice, such as A.X-K1's 88 MB, or the tokens' float32 sums do
+not fit beside it): the same layout and tables, grid (tile, block of the
+expert's inner width); a step fetches one block of the gate, up and down
+matrices in place, the tile's float32 result stays resident over the blocks,
+and what comes back is every slot's result, weighted and added to its token's
+sum by one scatter-add outside the kernel. WHICH of the two kernels, or
+neither, is one rule from what the op sees:
+``pallas_kernels.experts_kernel_blocks``.
+
+*The loop* (everywhere else: the CPU, tiny tiles, widths that are no
+multiple of 128): a ``lax.fori_loop`` over the experts held, and inside it one over that
 expert's tiles in use (none where it received nothing), which gathers a
 tile's rows from ``x``, multiplies them with the expert and adds the weighted
 rows back into ``y``. It is the reference the kernel is held to
@@ -76,15 +89,25 @@ from .registry import register
 
 
 @register("moe_router", nout=3)
-def _moe_router(top_k=1, norm_topk=True):
+def _moe_router(top_k=1, norm_topk=True, score="softmax", scaling=1.0):
+    if score not in ("softmax", "sigmoid"):
+        raise MXNetError(f"moe_router: score {score!r} is neither 'softmax' "
+                         "nor 'sigmoid'")
+
     def f(x, w):
         f32 = jnp.float32
         logits = jnp.matmul(x.astype(f32), w.astype(f32).T,
                             precision=lax.Precision.HIGHEST)
-        p = jax.nn.softmax(logits, axis=-1)
-        vals, idx = lax.top_k(p, int(top_k))
-        if norm_topk:
-            vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+        if score == "sigmoid":
+            vals, idx = lax.top_k(jax.nn.sigmoid(logits), int(top_k))
+            if norm_topk:
+                vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+            vals = vals * f32(scaling)
+        else:
+            p = jax.nn.softmax(logits, axis=-1)
+            vals, idx = lax.top_k(p, int(top_k))
+            if norm_topk:
+                vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
         idx = idx.astype(jnp.int32)
         counts = jnp.sum(idx[..., None] == jnp.arange(
             w.shape[0], dtype=jnp.int32), axis=(0, 1), dtype=f32)
@@ -179,16 +202,27 @@ def _routed_fwd(x, weights, experts, gate_up, down, lo, hi, tm):
     plan = _plan(weights, experts, lo, hi, tm)
     res = (x, weights, experts, gate_up, down)
 
-    if pk.experts_kernel_serves(x.shape[0], tm, x.shape[1], F, x.dtype,
-                                gate_up.dtype):
+    blocks = pk.experts_kernel_blocks(x.shape[0], tm, x.shape[1], F, x.dtype,
+                                      gate_up.dtype)
+    if blocks is not None:
         # one gather of the layout's rows, then one kernel over its tiles;
         # a padding slot takes the last token's row, which the kernel may
         # multiply and never adds to a sum
         tok = jnp.minimum(plan.tok, x.shape[0] - 1)
-        y = pk.grouped_experts(
-            x.at[tok].get(mode="promise_in_bounds"), gate_up, down,
-            plan.tile_expert, plan.tile_rows, plan.tile_hi[-1], tok,
-            plan.w_slot, x.shape[0])
+        rows = x.at[tok].get(mode="promise_in_bounds")
+        if blocks == 0:     # an expert resident whole, the sums with it
+            y = pk.grouped_experts(
+                rows, gate_up, down, plan.tile_expert, plan.tile_rows,
+                plan.tile_hi[-1], tok, plan.w_slot, x.shape[0])
+        else:
+            # an expert in blocks of its inner width: the kernel hands back
+            # every slot's result, weighted and summed a token here (a
+            # padding slot's token id is out of range: dropped)
+            out = pk.grouped_experts_blocked(
+                rows, gate_up, down, plan.tile_expert, plan.tile_rows,
+                plan.tile_hi[-1], blocks)
+            y = jnp.zeros(x.shape, jnp.float32).at[plan.tok].add(
+                plan.w_slot[:, None] * out, mode="drop")
         return y.astype(x.dtype), res
 
     # a token's sum over its experts is kept in float32 whatever x is (as

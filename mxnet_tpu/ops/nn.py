@@ -617,25 +617,72 @@ def _paged_decode_attention(scale=None):
     return f
 
 
+# absorbed latent (MLA) decode attention against the latent pool: one row a
+# position shared by every head, its leading columns the values.
+@register("mla_decode_attention", differentiable=False)
+def _mla_decode_attention(value_dim=None, scale=1.0):
+    """``q`` (S, K, H, R) absorbed queries, the latent pool [pages, layers,
+    1, R, page_tokens], ``layer`` (int32 scalar operand), ``page_table`` (S,
+    W+1), ``positions`` (S,) -> (S, K, H*value_dim). See
+    ``pallas_kernels.mla_decode_attention``."""
+    def f(q, pool, layer, page_table, positions):
+        from .pallas_kernels import mla_decode_attention
+
+        out = mla_decode_attention(q, pool, layer, page_table, positions,
+                                   int(value_dim), float(scale))
+        return out.reshape(out.shape[:2] + (-1,))
+
+    return f
+
+
 @register("rope")
-def _rope(rotary_dim=None, theta=10000.0, offset=0):
+def _rope(rotary_dim=None, theta=10000.0, offset=0, inv_freq=None):
     """Rotary position embedding, rotate-half convention, on the first
     ``rotary_dim`` entries of the last axis (default: all of it); the rest
-    passes through. ``x``: (B, T, H, D); position t is ``offset + t``."""
-    def f(x):
+    passes through. ``x``: (B, T, H, D); position t is ``offset + t``, or,
+    with a second operand ``positions`` (B or 1, T) int32, whatever that
+    says A ROW (a decode tick's slots stand at different positions).
+    ``inv_freq``: the ``rotary_dim / 2`` inverse frequencies, given (YaRN's
+    blend is not a power of ``theta``); default ``theta ** (-i / half)``.
+    Angles, cos and sin and the rotation itself are float32 whatever ``x``
+    is; the result is ``x``'s type."""
+    def f(x, *positions):
         D, T = x.shape[-1], x.shape[1]
         rot = D if rotary_dim is None else int(rotary_dim)
         if rot % 2 or not 0 < rot <= D:
             raise MXNetError(f"rope: rotary_dim {rot} is not an even number "
                              f"in (0, {D}]")
         half = rot // 2
-        inv = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
-        ang = (jnp.arange(T, dtype=jnp.float32) + offset)[:, None] * inv
-        cos = jnp.cos(ang)[None, :, None].astype(x.dtype)
-        sin = jnp.sin(ang)[None, :, None].astype(x.dtype)
-        x1, x2 = x[..., :half], x[..., half:rot]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
-                                x[..., rot:]], axis=-1)
+        if inv_freq is None:
+            inv = float(theta) ** (-jnp.arange(half, dtype=jnp.float32)
+                                   / half)
+        else:
+            if len(inv_freq) != half:
+                raise MXNetError(f"rope: {len(inv_freq)} inverse frequencies "
+                                 f"for rotary_dim {rot}")
+            inv = jnp.asarray(inv_freq, jnp.float32)
+        f32 = jnp.float32
+        if positions:
+            # whole-width form: x * [cos | cos] + [-x2 | x1] * [sin | sin],
+            # one convert at the end. (The halves converted and
+            # concatenated, as below, abort the TPU compiler at a tick's
+            # shapes in bfloat16: ``IsFusibleUnalignedDUS``.)
+            ang = positions[0].astype(f32)[:, :, None] * inv
+            cos = jnp.tile(jnp.cos(ang), 2)[:, :, None]
+            sin = jnp.tile(jnp.sin(ang), 2)[:, :, None] * jnp.asarray(
+                [-1.0] * half + [1.0] * half, f32)
+            xr = x[..., :rot].astype(f32)
+            out = (xr * cos + jnp.roll(xr, half, axis=-1) * sin) \
+                .astype(x.dtype)
+            return out if rot == D else jnp.concatenate(
+                [out, x[..., rot:]], axis=-1)
+        ang = (jnp.arange(T, dtype=f32) + offset)[:, None] * inv
+        cos = jnp.cos(ang)[None, :, None]
+        sin = jnp.sin(ang)[None, :, None]
+        x1, x2 = x[..., :half].astype(f32), x[..., half:rot].astype(f32)
+        return jnp.concatenate(
+            [(x1 * cos - x2 * sin).astype(x.dtype),
+             (x2 * cos + x1 * sin).astype(x.dtype), x[..., rot:]], axis=-1)
 
     return f
 

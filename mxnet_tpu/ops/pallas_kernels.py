@@ -1378,3 +1378,311 @@ def grouped_experts(rows, gate_up, down, tile_expert, tile_rows, n_tiles, tok,
                                          gate_up.dtype)),
     )(tile_expert, n_tiles.reshape(1), tile_rows, tok, w_slot, rows, gate_up,
       down)
+
+
+# ---------------------------------------------------------------------------
+# routed experts too large to be resident whole: the same two grouped
+# products, an expert taken in blocks along its inner width
+# ---------------------------------------------------------------------------
+_EXPERTS_VMEM = 84 * 2**20     # what either experts kernel may keep resident
+
+
+def _experts_block_resident(tm, d, fb, x_dtype, w_dtype):
+    """Bytes ``grouped_experts_blocked`` keeps in fast memory: a block of
+    the expert's three matrices and a tile of rows, each double-buffered,
+    the tile's float32 results (the output's block, two buffers) and the
+    float32 products."""
+    w = jnp.dtype(w_dtype).itemsize
+    return (2 * 3 * d * fb * w + 2 * tm * d * jnp.dtype(x_dtype).itemsize
+            + 2 * tm * d * 4 + 4 * tm * (3 * fb + d))
+
+
+def experts_kernel_blocks(n, tm, d, f, x_dtype, w_dtype):
+    """The routed layer's ONE rule, from what the op sees: ``0`` where
+    ``grouped_experts`` takes the shapes with an expert whole
+    (``experts_kernel_serves``); where an EXPERT is too large to be resident
+    whole twice, the width ``fb`` of the blocks in which
+    ``grouped_experts_blocked`` takes it along its inner width (the widest
+    of 512, 256, 128 that divides it and fits); else None, the loop's: the
+    CPU, odd widths, and experts that would fit but for the tokens' sums
+    (4096 float32 rows of 2048), which stay where they were."""
+    if experts_kernel_serves(n, tm, d, f, x_dtype, w_dtype):
+        return 0
+    sublanes = 32 // jnp.dtype(x_dtype).itemsize
+    if not (_use_pallas() and d % 128 == 0 and tm % sublanes == 0) \
+            or _experts_resident(0, tm, d, f, x_dtype, w_dtype) \
+            <= _EXPERTS_VMEM:
+        return None
+    for fb in (512, 256, 128):
+        if f % fb == 0 and _experts_block_resident(
+                tm, d, fb, x_dtype, w_dtype) <= _EXPERTS_VMEM:
+            return fb
+    return None
+
+
+def _experts_blocked_kernel(te_ref, nt_ref, rows_ref, x_ref, g_ref, u_ref,
+                            dn_ref, o_ref):
+    """Grid (tile, block of the inner width). ``x_ref`` (tm, D): the rows of
+    one tile, all of one expert; ``g_ref``, ``u_ref`` (D, fb) and ``dn_ref``
+    (fb, D): block j of that expert's gate, up and down matrices; ``o_ref``
+    (tm, D) float32: the tile's results, resident over j, the sum of the
+    blocks' products. As ``_experts_kernel``: both products on the matrix
+    unit in float32 from bfloat16 operands, SwiGLU on the float32 product,
+    rounded once. A tile past the last in use, or with no row in use, does
+    nothing."""
+    del te_ref                        # the index maps read it
+    t, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((t < nt_ref[0]) & (rows_ref[t] > 0))
+    def _products():
+        x = x_ref[...].astype(jnp.bfloat16)
+        g = jnp.dot(x, g_ref[...].astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+        u = jnp.dot(x, u_ref[...].astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+        a = (g * jax.nn.sigmoid(g) * u).astype(jnp.bfloat16)
+        part = jnp.dot(a, dn_ref[...].astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+
+        @pl.when(j == 0)
+        def _first():
+            o_ref[...] = part
+
+        @pl.when(j > 0)
+        def _more():
+            o_ref[...] = o_ref[...] + part
+
+
+def grouped_experts_blocked(rows, gate_up, down, tile_expert, tile_rows,
+                            n_tiles, fb):
+    """``(silu(r_s W_g) * (r_s W_u)) W_d`` for every slot of the tiles in
+    use, with the matrices of the slot's tile's expert taken ``fb`` columns
+    of the inner width at a time: ``rows`` (tiles * tm, D), ``gate_up``
+    (held, D, 2F), ``down`` (held, F, D); by tile ``tile_expert``
+    (ascending) and ``tile_rows`` (its rows in use), int32; ``n_tiles``
+    int32 scalar, the tiles in use. Returns (tiles * tm, D) float32, NOT
+    yet weighted or summed a token; the rows of tiles not in use hold
+    whatever (the caller drops them).
+
+    For experts that ``grouped_experts`` cannot hold whole (7168 x 4096 +
+    2048 x 7168 in bfloat16 is 88 MB, twice resident 176): per grid step
+    one block of each matrix is fetched, in place in the stacked arrays
+    (``gate_up`` goes in twice, its index maps picking block j of the gate
+    half and block j of the up half). Every block of a touched expert is
+    read once a TILE of that expert, so an expert with several tiles (a long
+    prefill) is read once a tile; an expert no token chose has no tile and
+    costs no bytes; the steps past the last tile in use repeat the last
+    indices (no bytes) and do nothing."""
+    tiles = tile_expert.shape[0]
+    tm = rows.shape[0] // tiles
+    _, f, d = down.shape
+    nf = f // fb
+
+    def tile(t, j, te, nt, *_):
+        return (jnp.minimum(t, jnp.maximum(nt[0] - 1, 0)), 0)
+
+    def block(t, j, nt):
+        return jnp.where(t < nt[0], j, nf - 1)
+
+    def gate(t, j, te, nt, *_):
+        return (te[tile(t, j, te, nt)[0]], 0, block(t, j, nt))
+
+    def up(t, j, te, nt, *_):
+        return (te[tile(t, j, te, nt)[0]], 0, nf + block(t, j, nt))
+
+    def dn(t, j, te, nt, *_):
+        return (te[tile(t, j, te, nt)[0]], block(t, j, nt), 0)
+
+    w = gate_up.dtype.itemsize
+    return pl.pallas_call(
+        _experts_blocked_kernel,
+        name="mxtpu_experts_swiglu_blocked",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(tiles, nf),
+            in_specs=[pl.BlockSpec((tm, d), tile),
+                      pl.BlockSpec((None, d, fb), gate),
+                      pl.BlockSpec((None, d, fb), up),
+                      pl.BlockSpec((None, fb, d), dn)],
+            out_specs=pl.BlockSpec((tm, d), tile)),
+        out_shape=jax.ShapeDtypeStruct((tiles * tm, d), jnp.float32),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * tiles * tm * d * f,
+            bytes_accessed=3 * gate_up.shape[0] * d * f * w
+            + tiles * tm * d * (rows.dtype.itemsize + 4),
+            transcendentals=tiles * tm * f),
+        interpret=_interpret(),
+        **_vmem_params(_experts_block_resident(tm, d, fb, rows.dtype,
+                                               gate_up.dtype)),
+    )(tile_expert, n_tiles.reshape(1), tile_rows, rows, gate_up, gate_up,
+      down)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) decode attention, absorbed: every query head of a slot against
+# the ONE latent row a position its pages hold, values the row's leading
+# columns, so a page is read once for keys and values
+# ---------------------------------------------------------------------------
+def mla_decode_attention(q, pool, layer, page_table, positions, value_dim,
+                         scale):
+    """Absorbed multi-head latent attention straight from a latent pool.
+
+    q : (S, K, H, R) — the ABSORBED queries ``[W_UK,h q_h^nope | q_h^rope]``;
+        query k of slot s stands at ``positions[s] + k``.
+    pool : [num_pages, layers, 1, R, page_tokens] — a position's row is
+        ``[c (value_dim) | k_rope]``, shared by all H heads; a page of one
+        layer is one contiguous block, positions along the lanes.
+    layer, page_table (S, W+1), positions (S,): as ``paged_decode_attention``.
+
+    ``score = scale * q_h . row``, softmax over the positions ``<=
+    positions[s] + k`` in mapped pages, ``out_h = sum_t p_t row_t[:value_dim]``
+    (the caller expands it with ``W_UV``). Returns (S, K, H, value_dim) in
+    q's dtype; scores, softmax and sums in float32. A slot with no mapped
+    page returns zeros.
+
+    Pallas kernel ``mxtpu_mla_decode`` on TPU (both products on the matrix
+    unit: H queries a row make it a matrix problem); the gather + mask +
+    softmax of the same numbers elsewhere."""
+    r, p = pool.shape[-2:]
+    layer = jnp.asarray(layer, jnp.int32)
+    page_table = page_table.astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
+    if _use_pallas() and p % 128 == 0 and r % 8 == 0 and value_dim % 128 == 0:
+        return _mla_decode_tpu(q, pool, layer, page_table, positions,
+                               int(value_dim), float(scale))
+    return _mla_decode_reference(q, pool, layer, page_table, positions,
+                                 int(value_dim), float(scale))
+
+
+def _mla_decode_reference(q, pool, layer, page_table, positions, value_dim,
+                          scale):
+    """The plain body: gather every column of every slot's table row into a
+    (S, W*P, R) view, mask, softmax, weigh."""
+    num_pages, _, _, r, p = pool.shape
+    s, kq, h, _ = q.shape
+    w = page_table.shape[1] - 1
+    ids = page_table[:, :w]
+    kpos = jnp.arange(w * p, dtype=jnp.int32)
+    qpos = positions[:, None] + jnp.arange(kq, dtype=jnp.int32)   # (S, K)
+    ok = (kpos[None, None, :] <= qpos[:, :, None]) \
+        & jnp.repeat(ids < num_pages, p, axis=1)[:, None, :]      # (S,K,WP)
+    rows = pool[ids.reshape(-1), layer, 0]          # (S*W, R, P); clamps
+    rows = rows.reshape(s, w, r, p).transpose(0, 1, 3, 2).reshape(s, w * p, r)
+    # the last query sees the most: what none may see reads as zero
+    rows = jnp.where(ok[:, -1][:, :, None], rows, 0)
+    logits = jnp.einsum("skhr,str->skht", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    mask = ok[:, :, None, :]
+    logits = jnp.where(mask, logits, _NEG_INF)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    e = jnp.where(mask, jnp.exp(logits - m), 0.0)
+    probs = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("skht,stv->skhv", probs.astype(rows.dtype),
+                     rows[..., :value_dim],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def _mla_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, *rest, num_pages,
+                       heads, value_dim, scale, pages_a_step):
+    """Grid (slot, group of ``pages_a_step`` logical pages). ``q_ref``
+    (K*H, R): the slot's absorbed queries, query k's heads in rows k*H ..;
+    ``page_refs``: that many (R, P) pages of one layer; ``o_ref`` (K*H,
+    value_dim). Online softmax in float32; ``m_ref``, ``l_ref`` (K*H, 1),
+    ``acc_ref`` (K*H, value_dim). Scores ``q @ page`` and sums ``p @
+    page[:value_dim]^T`` run on the matrix unit in the pool's type."""
+    del lay_ref                       # the index maps read it
+    page_refs = rest[:pages_a_step]
+    o_ref, acc_ref, m_ref, l_ref = rest[pages_a_step:]
+    si, jg = pl.program_id(0), pl.program_id(1)
+    kh = q_ref.shape[0]
+    p = page_refs[0].shape[-1]
+    pos = pos_ref[si]
+    last = pos + kh // heads - 1      # the newest position any query sees
+    w = tab_ref.shape[1] - 1
+
+    @pl.when(jg == 0)
+    def _start():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for i, page_ref in enumerate(page_refs):
+        j = jg * pages_a_step + i
+
+        @pl.when((j < w) & (j * p <= last)
+                 & (tab_ref[si, jnp.minimum(j, w - 1)] < num_pages))
+        def _page(j=j, page_ref=page_ref):
+            kpos = j * p + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+            # past the slot's length a page may hold anything: 0 x NaN
+            page = jnp.where(kpos <= last, page_ref[...], 0)      # (R, P)
+            s = jnp.dot(q_ref[...], page,
+                        preferred_element_type=jnp.float32) * scale
+            qk = jax.lax.broadcasted_iota(jnp.int32, (kh, 1), 0) // heads
+            ok = kpos <= pos + qk                                 # (KH, P)
+            s = jnp.where(ok, s, _NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            pr = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                pr.astype(page.dtype), page[:value_dim],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+
+    @pl.when(jg == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)) \
+            .astype(o_ref.dtype)
+
+
+_MLA_PAGES_A_STEP = 4   # a grid step costs about what a page's bytes cost
+
+
+def _mla_decode_tpu(q, pool, layer, page_table, positions, value_dim, scale):
+    num_pages, _, _, r, p = pool.shape
+    s, kq, h, _ = q.shape
+    w = page_table.shape[1] - 1
+    n = min(_MLA_PAGES_A_STEP, w)
+
+    def page(i):
+        def index(si, jg, tab, pos, lay):
+            # past the slot's last live page the block index repeats, and a
+            # repeated index moves no bytes; the sentinel id (one past the
+            # pool) clamps into it
+            last = jnp.clip((pos[si] + kq - 1) // p, 0, w - 1)
+            return (jnp.minimum(tab[si, jnp.minimum(jg * n + i, last)],
+                                num_pages - 1), lay[0], 0, 0, 0)
+        return index
+
+    def slot(si, jg, tab, pos, lay):
+        return (si, 0, 0)
+
+    itemsize = pool.dtype.itemsize
+    out = pl.pallas_call(
+        functools.partial(_mla_decode_kernel, num_pages=num_pages, heads=h,
+                          value_dim=value_dim, scale=scale, pages_a_step=n),
+        name="mxtpu_mla_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s, -(-w // n)),
+            in_specs=[pl.BlockSpec((None, kq * h, r), slot)]
+            + [pl.BlockSpec((None, None, None, r, p), page(i))
+               for i in range(n)],
+            out_specs=pl.BlockSpec((None, kq * h, value_dim), slot),
+            scratch_shapes=[
+                pltpu.VMEM((kq * h, value_dim), jnp.float32),    # acc
+                pltpu.VMEM((kq * h, 1), jnp.float32),            # m
+                pltpu.VMEM((kq * h, 1), jnp.float32),            # l
+            ]),
+        out_shape=jax.ShapeDtypeStruct((s, kq * h, value_dim), q.dtype),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * s * kq * h * (r + value_dim) * w * p,
+            bytes_accessed=s * w * r * p * itemsize,
+            transcendentals=s * kq * h * w * p),
+        interpret=_interpret(),
+    )(page_table, positions, layer.reshape(1),
+      q.reshape(s, kq * h, r).astype(pool.dtype), pool, *([pool] * (n - 1)))
+    return out.reshape(s, kq, h, value_dim)

@@ -115,7 +115,9 @@ class TopKRouter(HybridBlock):
     """``forward(x)`` on (N, units) tokens: ``(weights (N, k), experts (N, k)
     int32)``, the ``top_k`` largest of ``softmax(x weight^T)`` over ALL
     ``num_experts`` in float32, renormalised to sum 1 when ``norm_topk``
-    (``npx.moe_router``). A block of its own so that a forward hook sees the
+    (``npx.moe_router``; ``score="sigmoid"`` and ``scaling``: a sigmoid an
+    expert in the softmax's place, the renormalised weights times
+    ``scaling``). A block of its own so that a forward hook sees the
     choices.
 
     ``expert_tokens`` (num_experts,), not trained: how many tokens chose each
@@ -125,12 +127,14 @@ class TopKRouter(HybridBlock):
     ``telemetry.moe_report()`` reads it when asked."""
 
     def __init__(self, units, num_experts, top_k, norm_topk=True,
-                 dtype="float32", weight_initializer=None, **kwargs):
+                 dtype="float32", weight_initializer=None, score="softmax",
+                 scaling=1.0, **kwargs):
         super().__init__(**kwargs)
         if not 1 <= top_k <= num_experts:
             raise MXNetError(f"top_k {top_k} is not in 1..{num_experts}")
         self.num_experts, self.top_k = num_experts, top_k
         self._norm_topk = norm_topk
+        self._score, self._scaling = score, scaling
         self.weight = Parameter(
             shape=(num_experts, units), dtype=dtype,
             init=weight_initializer or init_mod.Normal(0.02))
@@ -141,7 +145,8 @@ class TopKRouter(HybridBlock):
     def forward(self, x):
         weights, experts, counts = npx.moe_router(
             x, self.weight.data(), top_k=self.top_k,
-            norm_topk=self._norm_topk)
+            norm_topk=self._norm_topk, score=self._score,
+            scaling=self._scaling)
         if dc.is_tracing():
             dc.register_aux_update(self.expert_tokens.data(), counts)
         else:
@@ -155,7 +160,8 @@ class RoutedExperts(HybridBlock):
     ``num_experts``: the router's (published) width; ``experts_held``:
     ``(lo, hi)``, the experts whose weights live here (default: all);
     ``top_k`` experts a token, their weights renormalised to sum 1 when
-    ``norm_topk``. ``forward(x)`` takes (N, units) tokens and returns
+    ``norm_topk`` (``score``, ``scaling``: the router's, see
+    ``TopKRouter``). ``forward(x)`` takes (N, units) tokens and returns
     the weighted sum over each token's chosen experts THAT ARE HELD; the
     weights are those of the full top-k, so the ``num_experts / held``
     shares of a layer add up to the whole layer. Dropless: however
@@ -168,7 +174,8 @@ class RoutedExperts(HybridBlock):
 
     def __init__(self, units, expert_units, num_experts, top_k,
                  experts_held=None, norm_topk=True, dtype="float32",
-                 weight_initializer=None, **kwargs):
+                 weight_initializer=None, score="softmax", scaling=1.0,
+                 **kwargs):
         super().__init__(**kwargs)
         lo, hi = (0, num_experts) if experts_held is None \
             else map(int, experts_held)
@@ -179,7 +186,7 @@ class RoutedExperts(HybridBlock):
         self.num_experts, self.top_k = num_experts, top_k
         init = weight_initializer or init_mod.Normal(0.02)
         self.router = TopKRouter(units, num_experts, top_k, norm_topk,
-                                 dtype, init)
+                                 dtype, init, score, scaling)
         self.gate_up = Parameter(
             shape=(hi - lo, units, 2 * expert_units), dtype=dtype,
             init=init)

@@ -1,14 +1,16 @@
 """The paged KV cache: the pool's layout, the host's page tables and the
 views a model's forward is handed.
 
-This is the one module that knows how a layer's K/V is stored, written
-and attended over. A servable model has ONE forward pass: handed a view
-(``cache=``), its attention layers call ``view.attend(layer, q, k, v)``
-where they would call the attention op, and its embedding takes
+This is the one module that knows how a layer's K/V (or its ONE latent
+row) is stored, written and attended over. A servable model has ONE
+forward pass: handed a view (``cache=``), its attention layers call
+``view.attend(layer, q, k, v)`` where they would call the attention op
+(``view.attend_latent`` for a latent row), and its embedding takes
 ``view.positions(limit)``. What the model states in return is what a
 cache must hold: ``model.cache_spec()`` -> ``{"layers", "heads",
 "head_dim", "dtype"}`` (layers: those that keep K/V pages, numbered in
-the pool by the model; heads: the KV heads THIS rank holds).
+the pool by the model; heads: the KV heads THIS rank holds), or, for a
+latent cache, ``{"layers", "latent", "dtype"}`` (below).
 
 **Two kinds of state.** A model whose layers are not all attention
 states besides ``"state"``: ``((shape, dtype), ...)``, the arrays ONE
@@ -24,13 +26,33 @@ where an attention layer calls ``attend``: ``step(*arrays,
 valid_length)`` gets its rows' state (zeros in a prefill, the slots' in a
 tick) and returns ``(output, *new arrays)``; a prefill keeps the state AS
 OF ``valid_length``, so ``step`` must leave it alone past that. The
-programs' donated operands are ``(k_pool, v_pool, *state)``, in this
-order, then the counters (not donated: ``stats()`` reads them from
+programs' donated operands are what the cache names, ``(*pools, *state)``
+(``PagedKVCache.operands()``: the K and V pair, or the one latent pool), in
+this order, then the counters (not donated: ``stats()`` reads them from
 another thread). What recurrent state cannot do yet is refused by name
 where the programs are built: the radix prefix cache (the join needs a
 snapshot of the state at the prefix's end), ``speculate_k > 1`` (a
 rejected draft needs the state rolled back), ``tp > 1`` (state heads are
 not sharded), ``export``.
+
+**A third kind: the latent row.** A model with multi-head latent attention
+(DeepSeek-V3's, A.X-K1's) states ``"latent": (row width, value width)``
+in place of ``heads`` and ``head_dim``: what a position keeps a layer is
+ONE headless row ``[c | k_rope]`` (576 numbers where per-head K and V would
+be 16,384), and the values are a projection of the row's leading ``value
+width`` columns, so there is ONE pool, ``[pages, layers, 1, row width,
+page_tokens]``, and no V pool: the operands are ``(latent_pool, *state)``
+(``pool_count``, ``PagedKVCache.pools``). Such a layer calls
+``view.attend_latent(layer, row, q, k, v, scale=, heads=)``: a prefill
+attends EXPANDED (the model's own per-head ``q, k, v`` through the flash
+path; ``v`` padded to ``k``'s width) and stores the row; a tick attends
+ABSORBED (``q`` already multiplied through ``W_UK``: every head against the
+shared row, ``npx.mla_decode_attention``, kernel ``mxtpu_mla_decode``: each
+live page read once for keys and values) and gets the still-latent sums
+back. What latent rows cannot do yet is refused by name where the programs
+are built: the prefix join (``JoinView`` gathers per-head pages), ``tp >
+1`` (one headless row has no head axis to shard), ``speculate_k > 1`` and
+``export`` (not tried).
 
 **Layout.** The pool pair has shape ``POOL_AXES`` = ``[pages, layers,
 heads, head_dim, page_tokens]``: a shared pool of fixed-size pages, each
@@ -83,7 +105,8 @@ from ... import numpy as np
 from ... import numpy_extension as npx
 from ...base import MXNetError, dtype_name
 
-__all__ = ["POOL_AXES", "pool_shape", "empty_pools", "state_shapes",
+__all__ = ["POOL_AXES", "pool_shape", "pool_count", "empty_pools",
+           "state_shapes",
            "empty_state", "counter_names", "view_layout", "PrefillView",
            "JoinView", "TickView", "generate", "SlotAllocator",
            "PageAllocator", "PagedKVCache"]
@@ -91,17 +114,26 @@ __all__ = ["POOL_AXES", "pool_shape", "empty_pools", "state_shapes",
 POOL_AXES = ("pages", "layers", "heads", "head_dim", "page_tokens")
 
 
+def pool_count(spec):
+    """How many pools a cache for ``spec`` holds: the K and V pair, or ONE
+    latent pool (``spec["latent"]``: values are columns of the same row)."""
+    return 1 if "latent" in spec else 2
+
+
 def pool_shape(spec, num_pages, page_tokens):
-    """The shape of one pool (``POOL_AXES``) for a model's ``cache_spec()``."""
+    """The shape of one pool (``POOL_AXES``) for a model's ``cache_spec()``;
+    a latent pool is headless: one row of ``latent[0]`` numbers a position."""
     size = dict(spec, pages=num_pages, page_tokens=page_tokens)
+    if "latent" in spec:
+        size.update(heads=1, head_dim=spec["latent"][0])
     return tuple(int(size[a]) for a in POOL_AXES)
 
 
 def empty_pools(spec, num_pages, page_tokens):
-    """Preallocated (k_pool, v_pool) of zeros."""
+    """Preallocated pools of zeros: (k_pool, v_pool), or (latent_pool,)."""
     shape = pool_shape(spec, num_pages, page_tokens)
-    return (np.zeros(shape, dtype=spec["dtype"]),
-            np.zeros(shape, dtype=spec["dtype"]))
+    return tuple(np.zeros(shape, dtype=spec["dtype"])
+                 for _ in range(pool_count(spec)))
 
 
 def state_shapes(spec, num_slots):
@@ -229,7 +261,7 @@ class PagedKVCache:
     """
 
     def __init__(self, shape, dtype="float32", *, num_slots, max_len,
-                 state=(), counters=()):
+                 state=(), counters=(), pools=2):
         import jax.numpy as jnp
 
         shape = tuple(int(d) for d in shape)
@@ -242,8 +274,8 @@ class PagedKVCache:
         self.max_len = int(max_len)
         self.pages_per_slot = -(-self.max_len // self.page_tokens)  # W
         self.trash = self.num_pages
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        # the K and V pair, or one latent pool (``pool_count``)
+        self.pools = tuple(jnp.zeros(shape, dtype) for _ in range(pools))
         # recurrent state: one (num_slots, ...) array a layer and entry;
         # a slot's rows belong to whoever holds the slot
         self.state = tuple(jnp.zeros(sh, dt) for sh, dt in state)
@@ -261,21 +293,30 @@ class PagedKVCache:
     def recurrent(self):
         return bool(self.state)
 
+    @property
+    def k(self):
+        return self.pools[0]
+
+    @property
+    def v(self):
+        return self.pools[1]
+
     def operands(self):
-        """What every program takes last and gives back: the pool pair and
-        the recurrent state (donated), then the counters (not donated)."""
+        """What every program takes last and gives back: the pools and the
+        recurrent state (donated), then the counters (not donated)."""
         tail = () if self.counters is None else (self.counters,)
-        return (self.k, self.v) + self.state + tail
+        return self.pools + self.state + tail
 
     def rebind(self, outs):
         """Take a program's outputs after its tokens: the new operands, in
         ``operands()``'s order (whatever follows them, a model's auxiliary
         outputs, is not the cache's)."""
-        n = len(self.state)
-        self.k, self.v = outs[0], outs[1]
-        self.state = tuple(outs[2:2 + n])
+        p = len(self.pools)
+        n = p + len(self.state)
+        self.pools = tuple(outs[:p])
+        self.state = tuple(outs[p:n])
         if self.counters is not None:
-            self.counters = outs[2 + n]
+            self.counters = outs[n]
 
     def reset_row(self, sid):
         self.table[sid, :] = self.trash
@@ -283,7 +324,7 @@ class PagedKVCache:
 
     @property
     def nbytes(self):
-        return int(self.k.size * self.k.dtype.itemsize * 2)
+        return int(sum(a.size * a.dtype.itemsize for a in self.pools))
 
     @property
     def state_nbytes(self):
@@ -350,20 +391,19 @@ def _page_chunks(x, J, P):
     return np.reshape(np.swapaxes(x, -1, -2), (-1,) + inner + (D, P))
 
 
-def _scatter_pages(k, v, valid_length, start, page_table, k_pool, v_pool,
-                   layer=None):
-    """Write prompt k/v into the pool, whole pages at a time, with one
-    indexed update per pool: (B, layers, heads, T, head_dim) of every
-    layer, or (B, heads, T, head_dim) of layer ``layer`` (a
-    ``_layer_id``).
+def _scatter_pages(xs, valid_length, start, page_table, pools, layer=None):
+    """Write prompt k/v (or latent rows) into the pools, whole pages at a
+    time, with one indexed update per pool: ``xs[i]`` goes into
+    ``pools[i]``, (B, layers, heads, T, head_dim) of every layer, or (B,
+    heads, T, head_dim) of layer ``layer`` (a ``_layer_id``).
 
     Chunk j of a row lands in the page its ``page_table`` row maps
     for logical page ``start//P + j``. A chunk past ``valid_length``
     is routed at the sentinel id, like one whose table column holds
     it, and the update drops both. The engine never maps one page to
     two rows of a batch, so no two chunks share a page."""
-    NP_, P = k_pool.shape[0], k_pool.shape[4]
-    T = k.shape[-2]
+    NP_, P = pools[0].shape[0], pools[0].shape[4]
+    T = xs[0].shape[-2]
     W = page_table.shape[1] - 1
     J = -(-T // P)
     j_idx = np.arange(J, dtype="int32").reshape(1, J)
@@ -376,8 +416,8 @@ def _scatter_pages(k, v, valid_length, start, page_table, k_pool, v_pool,
         page_table, np.minimum(base + j_idx, W), axis=1)     # (B, J)
     page_id = np.reshape(np.where(j_idx * P < valid, page_id, NP_), (-1,))
     key = (page_id,) if layer is None else (page_id, layer)
-    return (_update_pool(k_pool, key, _page_chunks(k, J, P)),
-            _update_pool(v_pool, key, _page_chunks(v, J, P)))
+    return tuple(_update_pool(pool, key, _page_chunks(x, J, P))
+                 for x, pool in zip(xs, pools))
 
 
 def _gather_page_view(pool, layer, flat_ids, W):
@@ -420,6 +460,14 @@ class _PagedView:
     - ``attend(layer, q, k, v)``: layer ``layer``'s attention output
       (B, T, heads*head_dim) for the flat ``q, k, v`` (B, T,
       heads*head_dim) of this call's tokens, having stored ``k, v``;
+    - ``attend_latent(layer, row, q, k=None, v=None, scale=, heads=)``: the same
+      for a model whose cache holds ONE latent row a position (``row`` (B,
+      T, R), stored in place of k, v). A prefill attends EXPANDED: ``q, k``
+      (B, T, heads*d_k) and ``v`` (B, T, heads*d_k too: padded) as the
+      model expanded them from the row, through the flash path, (B, T,
+      heads*d_k) back. A tick (``decoding``) attends ABSORBED: ``q`` (S, K,
+      heads*R) against the rows the pages hold, (S, K, heads*value_dim)
+      back, still latent;
     - ``recur(layer, step)``: recurrent layer ``layer``'s output, from
       ``step(*arrays, valid_length) -> (output, *new arrays)``: ``arrays``
       are the layer's state for this call's rows, ``valid_length`` (B,) or
@@ -430,27 +478,41 @@ class _PagedView:
       an int32 scalar to the counter ``name`` of the model's
       ``cache_spec()``; ``decoding`` tells a tick from a prefill;
     - ``state()``: the updated operands, once, at the end: (k_pool,
-      v_pool), then the recurrent state and the counters where the model
-      has them.
+      v_pool) or the one latent pool, then the recurrent state and the
+      counters where the model has them.
 
-    ``extra`` is what follows the pool pair in the operand list
+    ``operands`` is the pools and what follows them in the operand list
     (``empty_state``); ``per_layer`` says how many of its arrays one
-    recurrent layer keeps, ``counters`` names the last one's entries."""
+    recurrent layer keeps, ``counters`` names the last one's entries,
+    ``latent`` is the spec's (``view_layout``)."""
 
     decoding = False
 
-    def __init__(self, page_table, k_pool, v_pool, extra=(), per_layer=0,
-                 counters=()):
+    def __init__(self, page_table, operands, per_layer=0, counters=(),
+                 latent=None):
         self.page_table = page_table
-        self.k_pool, self.v_pool = k_pool, v_pool
-        self.heads, self.head_dim, self.page_tokens = k_pool.shape[2:]
+        # a latent cache has ONE pool: ``latent`` = (row width, how many of
+        # the row's leading columns are also the values)
+        self.latent = None if latent is None else tuple(latent)
+        operands = list(operands)
+        self.k_pool = operands.pop(0)
+        self.v_pool = operands.pop(0) if latent is None else None
+        self.heads, self.head_dim, self.page_tokens = self.k_pool.shape[2:]
         self.W = page_table.shape[1] - 1
         self._per_layer = int(per_layer)
         self._counter_names = tuple(counters)
-        extra = list(extra)
+        extra = operands
         self._counters = extra.pop() if self._counter_names else None
         self._recurrent = extra
         self._counts = {}
+
+    def _pools(self):
+        return [self.k_pool] if self.latent else [self.k_pool, self.v_pool]
+
+    def _no_latent(self, what):
+        return MXNetError(
+            f"{type(self).__name__} cannot serve {what} a latent cache "
+            "(cache_spec()['latent'])")
 
     def positions(self, limit):
         return np.minimum(self._pos, limit - 1)
@@ -474,7 +536,7 @@ class _PagedView:
         return value
 
     def state(self):
-        out = [self.k_pool, self.v_pool] + list(self._recurrent)
+        out = self._pools() + list(self._recurrent)
         if self._counters is not None:
             zero = self._counters[0] * 0
             out.append(self._counters + np.stack(
@@ -492,9 +554,10 @@ class PrefillView(_PagedView):
     update per pool. K/V past ``valid_length`` inside a live page hold
     garbage no later mask admits."""
 
-    def __init__(self, tokens, valid_length, page_table, k_pool, v_pool,
-                 *extra, slots=None, **layout):
-        super().__init__(page_table, k_pool, v_pool, extra, **layout)
+    def __init__(self, tokens, valid_length, page_table, *operands,
+                 slots=None, **layout):
+        super().__init__(page_table, operands, **layout)
+        k_pool = self.k_pool
         T = tokens.shape[1]
         self.valid_length = valid_length
         self.slots = slots     # (B,) the slot of each row: its state's row
@@ -518,6 +581,13 @@ class PrefillView(_PagedView):
         return _attention(q, k, v, self._mask, self.heads, self.head_dim,
                           True, scale)
 
+    def attend_latent(self, layer, row, q, k, v, scale=None, heads=1):
+        """Expanded: the flash path on the model's own ``q, k, v`` of
+        ``heads`` heads; what is stored is ``row``."""
+        self._k[layer] = _split_heads(row, self.head_dim)
+        return _attention(q, k, v, self._mask, heads, q.shape[-1] // heads,
+                          True, scale)
+
     def recur(self, layer, step):
         """From zeros (a prompt starts a sequence); the state as of
         ``valid_length`` goes to the rows' slots, a row that holds no
@@ -537,10 +607,14 @@ class PrefillView(_PagedView):
         return out
 
     def state(self):
-        self.k_pool, self.v_pool = _scatter_pages(
-            np.stack(self._k, axis=1), np.stack(self._v, axis=1),
-            self.valid_length, None, self.page_table,
-            self.k_pool, self.v_pool)
+        new = [np.stack(self._k, axis=1)]
+        if not self.latent:
+            new.append(np.stack(self._v, axis=1))
+        pools = _scatter_pages(new, self.valid_length, None, self.page_table,
+                               self._pools())
+        self.k_pool = pools[0]
+        if not self.latent:
+            self.v_pool = pools[1]
         return super().state()
 
 
@@ -558,9 +632,11 @@ class JoinView(_PagedView):
     view stays, where the tick's one to K queries a slot read the
     pages in place.)"""
 
-    def __init__(self, tokens, valid_length, start, page_table, k_pool,
-                 v_pool, *extra, **layout):
-        super().__init__(page_table, k_pool, v_pool, extra, **layout)
+    def __init__(self, tokens, valid_length, start, page_table, *operands,
+                 **layout):
+        super().__init__(page_table, operands, **layout)
+        if self.latent:
+            raise self._no_latent("a prefix join over")
         T = tokens.shape[1]
         WP = self.W * self.page_tokens
         self.valid_length = valid_length
@@ -581,9 +657,9 @@ class JoinView(_PagedView):
     def attend(self, layer, q, k, v, scale=None):
         lay = _layer_id(layer)
         self.k_pool, self.v_pool = _scatter_pages(
-            _split_heads(k, self.head_dim), _split_heads(v, self.head_dim),
+            [_split_heads(k, self.head_dim), _split_heads(v, self.head_dim)],
             self.valid_length, self.start, self.page_table,
-            self.k_pool, self.v_pool, layer=lay)
+            [self.k_pool, self.v_pool], layer=lay)
         viewk = _gather_page_view(self.k_pool, lay, self._flat_ids, self.W)
         viewv = _gather_page_view(self.v_pool, lay, self._flat_ids, self.W)
         return _attention(q, viewk, viewv, self._mask, self.heads,
@@ -612,9 +688,8 @@ class TickView(_PagedView):
 
     decoding = True
 
-    def __init__(self, tokens, positions, page_table, k_pool, v_pool,
-                 *extra, **layout):
-        super().__init__(page_table, k_pool, v_pool, extra, **layout)
+    def __init__(self, tokens, positions, page_table, *operands, **layout):
+        super().__init__(page_table, operands, **layout)
         S, K = tokens.shape
         P, W = self.page_tokens, self.W
         self.S, self.K = S, K
@@ -650,6 +725,20 @@ class TickView(_PagedView):
             self.v_pool, lay, self.page_table, self.slot_positions,
             scale=scale)
 
+    def attend_latent(self, layer, row, q, k=None, v=None, scale=None,
+                      heads=1):
+        """Absorbed: the S*K new rows go into the pool first, then every
+        head's query reads the pages in place (``npx.mla_decode_attention``,
+        each live page once for keys and values)."""
+        lay = _layer_id(layer)
+        width, value_dim = self.latent[:2]
+        self.k_pool = _write_rows(
+            self.k_pool, lay, self._page_id, self._hits,
+            np.reshape(row, (self.S, self.K, 1, width)))
+        return npx.mla_decode_attention(
+            np.reshape(q, (self.S, self.K, -1, width)), self.k_pool, lay,
+            self.page_table, self.slot_positions, value_dim, scale)
+
     def recur(self, layer, step):
         """Every slot's state in, every slot's state out (an idle slot's
         rows hold whatever: its next prefill overwrites them). One
@@ -671,7 +760,7 @@ def view_layout(spec):
     """The keywords every view takes besides its operands, from a model's
     ``cache_spec()``."""
     return {"per_layer": len(spec.get("state", ())),
-            "counters": counter_names(spec)}
+            "counters": counter_names(spec), "latent": spec.get("latent")}
 
 
 def generate(model, tokens, max_new_tokens, pick):

@@ -91,7 +91,7 @@ def stats_log():
     process, oldest first: ``engine`` (a number an engine), ``t``
     (``perf_counter()``), ``ticks``, ``prefills``, ``tokens``,
     ``prompt_tokens``, ``slots_live``, ``num_slots``, ``kv_pages_live``,
-    ``page_tokens`` and the model's counters. One small dict a ``stats()``
+    ``page_tokens``, ``kv_pages``, ``cache_bytes`` and the model's counters. One small dict a ``stats()``
     call, 1024 kept.
 
     NOT part of the engine's surface, and a debt to delete (ROADMAP, "Named
@@ -375,7 +375,8 @@ class DecodeEngine:
                                    num_slots=self.num_slots,
                                    max_len=self.max_len,
                                    state=self.programs.state_shapes,
-                                   counters=self.programs.counter_names)
+                                   counters=self.programs.counter_names,
+                                   pools=self.programs.pools)
         # the model's device-side counters, as ``stats()`` last read them:
         # int32 sums that wrap; the differences (modulo 2**32) go into
         # exact host totals, so they hold as long as ``stats()`` is asked
@@ -1245,7 +1246,8 @@ class DecodeEngine:
             counters, engine=self._engine_id, t=time.perf_counter(),
             **{k: out[k] for k in (
                 "ticks", "prefills", "tokens", "prompt_tokens", "slots_live",
-                "num_slots", "kv_pages_live", "page_tokens")}))
+                "num_slots", "kv_pages_live", "page_tokens", "kv_pages",
+                "cache_bytes")}))
         return out
 
     def _read_counters(self):

@@ -40,8 +40,9 @@ vector the next tick reads: the engine keeps that vector on the device
 prefill before it dispatches the tick that follows.
 
 All three donate what the cache names (``PagedKVCache.operands()``): the
-pool pair and, for a model with recurrent layers, one state array a
-layer and entry (in, out — a single device residency; on backends
+pool pair (or the ONE latent pool of a model with latent attention, whose
+``cache_spec()`` says so) and, for a model with recurrent layers, one state
+array a layer and entry (in, out — a single device residency; on backends
 without donation support XLA falls back to copying); the model's
 counters, where it keeps any, follow undonated. A prefill of such a model
 takes one operand more, each row's slot, to write that slot's state.
@@ -173,6 +174,7 @@ class DecodePrograms:
         # from cache_spec() alone (empty for a model of attention layers)
         self.state_shapes = []   # [(shape, dtype)] a layer and entry
         self.counter_names = ()
+        self.pools = 2           # the K and V pair, or 1 latent pool
         self._layout = kv.view_layout({})
         # tensor parallelism: the model's column-parallel serve layout,
         # traced at per-rank local shapes and replayed under shard_map
@@ -184,7 +186,9 @@ class DecodePrograms:
             self.state_shapes = kv.state_shapes(spec, self.num_slots)
             self.counter_names = kv.counter_names(spec)
             self._layout = kv.view_layout(spec)
+            self.pools = kv.pool_count(spec)
             self._refuse_for_recurrent_state()
+            self._refuse_for_latent_rows()
         self._mesh = None
         self._tp_places = {}     # param name -> (sharded dim, segments)
         self._in_shardings = {}  # program key -> per-arg NamedShardings
@@ -228,23 +232,40 @@ class DecodePrograms:
     def recurrent(self):
         return bool(self.state_shapes)
 
+    def _refuse(self, model, cases):
+        """Raise, by name, for the first of ``cases`` (on, what, why) that
+        is on: never served wrong."""
+        for on, what, why in cases:
+            if on:
+                raise MXNetError(
+                    f"a model with {model} cannot be served with {what}: "
+                    f"{why}")
+
     def _refuse_for_recurrent_state(self):
         """What a per-slot recurrent state cannot do yet, refused by name
-        where the programs are built: never served wrong."""
-        if not self.recurrent:
-            return
-        for on, what, why in (
+        where the programs are built."""
+        if self.recurrent:
+            self._refuse("recurrent state (cache_spec()['state'])", (
                 (self.prefix_cache, "prefix_cache=True",
                  "a join at a cached prefix needs a snapshot of the state "
                  "at the prefix's end"),
                 (self.speculate_k > 1, f"speculate_k={self.speculate_k}",
                  "a rejected draft needs the state rolled back"),
                 (self.tp > 1, f"tp={self.tp}",
-                 "the state's heads are not sharded over tp")):
-            if on:
-                raise MXNetError(
-                    f"a model with recurrent state (cache_spec()['state']) "
-                    f"cannot be served with {what}: {why}")
+                 "the state's heads are not sharded over tp")))
+
+    def _refuse_for_latent_rows(self):
+        """What a latent cache (one headless row a position) cannot do yet,
+        refused by name where the programs are built."""
+        if self._layout["latent"] is not None:
+            self._refuse("a latent cache (cache_spec()['latent'])", (
+                (self.prefix_cache, "prefix_cache=True",
+                 "the prefix join gathers per-head K and V pages"),
+                (self.speculate_k > 1, f"speculate_k={self.speculate_k}",
+                 "a draft of K > 1 through the absorbed attention is not "
+                 "tried"),
+                (self.tp > 1, f"tp={self.tp}",
+                 "one headless row has no head axis to shard over tp")))
 
     def _lead(self, family):
         """How many operands stand in front of the pool pair."""
@@ -253,7 +274,8 @@ class DecodePrograms:
 
     def _donate(self, family):
         first = self._lead(family)
-        return tuple(range(first, first + 2 + len(self.state_shapes)))
+        return tuple(range(first, first + self.pools
+                           + len(self.state_shapes)))
 
     # ----------------------------------------------------------------- trace
     def _collect_params(self):
@@ -347,8 +369,8 @@ class DecodePrograms:
             # the traced pool is per-rank local over heads; report the
             # GLOBAL pool geometry the engine allocates
             self.cache_shape = kv.pool_shape(
-                dict(spec, heads=spec["heads"] * self.tp), self.kv_pages,
-                self.page_tokens)
+                dict(spec, heads=spec.get("heads", 1) * self.tp),
+                self.kv_pages, self.page_tokens)
             self.cache_dtype = str(spec["dtype"])
         return list(kv.empty_pools(spec, self.kv_pages, self.page_tokens)) \
             + kv.empty_state(spec, self.num_slots)
@@ -445,7 +467,7 @@ class DecodePrograms:
         # the cache's operands: the pool pair, the recurrent state, the
         # counters
         held = [self._zeros(self.cache_shape, self.cache_dtype)
-                for _ in range(2)] \
+                for _ in range(self.pools)] \
             + [self._zeros(sh, dt) for sh, dt in self.state_shapes]
         if self.counter_names:
             held.append(self._zeros((len(self.counter_names),), "int32"))
@@ -681,10 +703,11 @@ class DecodePrograms:
         from these files alone (``from_export``) — no model class needed,
         and with the persistent compile cache on, no XLA compiles either.
         """
-        if self.recurrent:
+        if self.recurrent or self.pools != 2:
             raise MXNetError(
                 "export of a decode engine for a model with recurrent state "
-                "is not supported: the manifest describes the page pool only")
+                "or a latent cache is not supported: the manifest describes "
+                "the K and V page pools only")
         if self.tp > 1:
             raise MXNetError(
                 "export of a tensor-parallel decode engine is not "

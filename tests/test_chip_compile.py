@@ -502,3 +502,160 @@ def test_paged_decode_compiles_at_32_query_on_8_kv_heads_bfloat16(
             sds((32,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "f32[512,1,8,128,128]" not in text
+
+
+# -- the A.X-K1 serving cell's shapes: bfloat16, 64 slots ---------------------
+def test_mla_decode_compiles_at_64_heads_on_one_latent_row(chip_kernels):
+    """``mxtpu_mla_decode`` at the cell's shapes: 64 slots, 64 heads of 576
+    against ONE pool of 576-wide rows (1280 pages of 128, six layers),
+    values the first 512 columns: one kernel, the pool taken as it lies (no
+    copy, no float32 widening, no gathered view)."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip_kernels)
+    text = jax.jit(lambda q, pool, lay, tab, pos: pk.mla_decode_attention(
+        q, pool, lay, tab, pos, 512, 0.130861)).lower(
+            sds((64, 1, 64, 576), jnp.bfloat16),
+            sds((1280, 6, 1, 576, 128), jnp.bfloat16), sds((), jnp.int32),
+            sds((64, 21), jnp.int32), sds((64,), jnp.int32)) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "%mxtpu_mla_decode" in text
+    assert "[1280,6,1,576,128]{" in text
+    assert "f32[1280,6,1,576,128]" not in text and " gather(" not in text
+
+
+@pytest.mark.parametrize("rows", [64, 2048], ids=["tick", "prefill_2048"])
+def test_blocked_experts_kernel_compiles_at_the_axk1_cells_shapes(
+        chip_kernels, rows):
+    """64 slots' rows (tiles of 64) and a 2048-token prefill's (tiles of
+    128) on 12 experts of 2048 at hidden 7168 in bfloat16, 88 MB an expert:
+    ``experts_kernel_blocks`` says blocks of 512, ONE kernel takes each
+    expert in four of them (22 MB a step, double-buffered: a limit above
+    Mosaic's default), no loop and no copy of any expert's matrix."""
+    import re
+
+    from mxnet_tpu.ops.registry import get_op
+
+    tm = min(rows, 128)
+    assert not pk.experts_kernel_serves(rows, tm, 7168, 2048, jnp.bfloat16,
+                                        jnp.bfloat16)
+    assert pk.experts_kernel_blocks(rows, tm, 7168, 2048, jnp.bfloat16,
+                                    jnp.bfloat16) == 512
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip_kernels)
+    op = get_op("routed_experts")._make_fn(experts_held=(0, 12))
+    compiled = jax.jit(op).lower(
+        sds((rows, 7168)), sds((rows, 8), jnp.float32),
+        sds((rows, 8), jnp.int32), sds((12, 7168, 4096)),
+        sds((12, 2048, 7168))).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%mxtpu_experts_swiglu_blocked[_.\d]* = ",
+                          text)) == 1
+    assert " while(" not in text
+    assert not re.search(r"bf16\[(\d+,)?7168,4096\]\S* (copy|fusion)\(",
+                         text)
+    assert not re.search(r"bf16\[(\d+,)?2048,7168\]\S* (copy|fusion)\(",
+                         text)
+    assert pk._vmem_params(pk._experts_block_resident(
+        tm, 7168, 512, jnp.bfloat16, jnp.bfloat16))
+    # the layout's float32 results: tiles x tm x 7168, the static worst case
+    tiles = -(-rows * 8 // tm) + 12
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2.2 * tiles * tm * 7168 * 4 + 2**26
+
+
+@pytest.fixture(scope="module")
+def axk1_programs():
+    """The serving programs of A.X-K1's leading dense layer and one routed
+    layer at the cell's widths (hidden 7168, 64 heads of 128 + 64 / 128,
+    latents 1536 and 512, dense MLP 18432, a 192-way sigmoid top-8 router,
+    experts of 2048) in bfloat16, 64 slots x 2560, one 256-token prefill
+    bucket. Depth, the experts held (2 of 12) and the vocabulary (2048 rows)
+    are what a CPU can trace in a minute; no width is. Traced with the
+    kernels steered off, compiled with them on (``serve_programs`` says
+    why)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon import model_zoo
+    from mxnet_tpu.serve.decode import DecodePrograms
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pk, "_use_pallas", lambda: False)
+    try:
+        mx.random.seed(3)
+        net = model_zoo.axk1(num_hidden_layers=2, vocab_size=2048,
+                             dtype="bfloat16", experts_held=(0, 2))
+        for p in net.collect_params().values():
+            p.grad_req = "null"
+        net.initialize()
+        return DecodePrograms(net, num_slots=64, max_len=2560,
+                              prefill_batch=1, max_prompt_len=256,
+                              min_prompt_bucket=256, page_tokens=128,
+                              speculate_k=1, prefix_cache=False)
+    finally:
+        mp.undo()
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("family", ["decode", "prefill"])
+def test_axk1_serving_programs_compile_at_the_cells_widths(
+        chip_kernels, axk1_programs, family):
+    """The tick at 64 slots and a 256-token prefill compile for the
+    described v5e over ONE latent pool (no V pool among the operands),
+    updated in place (what is donated comes back aliased). The tick holds
+    the absorbed decode kernel a layer under the scope ``attn`` and no
+    gathered view of the pool; the prefill holds the flash kernel (expanded
+    attention, ``v`` padded to the key's 192) and writes each layer's rows
+    as whole pages; the routed layer's products are one blocked kernel under
+    the scope ``experts``, with no loop and no copy of an expert."""
+    import re
+
+    progs = axk1_programs
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        tuple(shape), dt, sharding=chip_kernels)
+    S, Wt = progs.num_slots, progs.table_width
+    assert progs.cache_shape == (S * 20, 2, 1, 576, 128)
+    assert progs.cache_dtype == "bfloat16" and progs.pools == 1
+    assert progs.state_shapes == []
+    held = [sds(progs.cache_shape, jnp.bfloat16),
+            sds((len(progs.counter_names),), jnp.int32)]
+    if family == "decode":
+        gkey = "decode:1"
+        data = [sds((S, 1), jnp.int32), sds((S,), jnp.int32),
+                sds((S, Wt), jnp.int32)]
+        assert progs._donate(family) == (3,)
+    else:
+        gkey = "prefill:256"
+        data = [sds((1, 256), jnp.int32), sds((1,), jnp.int32),
+                sds((1, Wt), jnp.int32)]
+        assert progs._donate(family) == (3,)
+    args = data + held + [
+        sds(progs._params[n].shape, progs._params[n].dtype)
+        for n in progs._graph_params[gkey]]
+    compiled = progs._cops[gkey].lower(
+        *args, donate=progs._donate(family)).compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    pool_bytes = math.prod(progs.cache_shape) * 2
+    assert ma.alias_size_in_bytes >= pool_bytes
+    assert ma.temp_size_in_bytes < pool_bytes          # no second pool
+    assert "f32[1280,2,1,576,128]" not in text
+    blocked = [line for line in text.splitlines() if re.search(
+        r"%mxtpu_experts_swiglu_blocked[.\d]* = ", line)]
+    assert len(blocked) == 1
+    assert re.search(r'op_name="[^"]*/experts/[^"]*"', blocked[0])
+    assert not [line for line in text.splitlines() if " while(" in line]
+    assert not re.search(r"bf16\[(1,)?(7168,4096|2048,7168)\]\S* "
+                         r"(copy|dynamic-slice)\(", text)
+    decode = [line for line in text.splitlines() if re.search(
+        r"%mxtpu_mla_decode[.\d]* = ", line)]
+    if family == "decode":
+        assert len(decode) == 2
+        assert all(re.search(r'op_name="[^"]*/attn/[^"]*"', k)
+                   for k in decode)
+        assert "mxtpu_flash_fwd" not in text
+        # no gathered view: nothing of slots x pages x a page's rows
+        assert not re.search(r"bf16\[64,20,576,128\]|bf16\[1280,576,128\]",
+                             text)
+    else:
+        assert not decode
+        assert len(re.findall(r"%mxtpu_flash_fwd[.\d]* = ", text)) == 2

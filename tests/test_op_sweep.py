@@ -1085,6 +1085,7 @@ COVERED_ELSEWHERE = {
     "multihead_attention": "test_attention_models.py",
     "flash_attention": "test_attention_models.py",
     "paged_decode_attention": "test_decode.py (kernel and plain body)",
+    "mla_decode_attention": "test_axk1.py (kernel and plain body)",
     # the Qwen3-Next operators: values and gradients against the plain
     # reference (recurrence, dense experts)
     "rope": "test_qwen3_next.py",
