@@ -257,17 +257,20 @@ def multihead_attention(query, key, value, mask=None, num_heads=1,
 
 
 def paged_decode_attention(query, k_pool, v_pool, layer, page_table,
-                           positions, scale=None):
+                           positions, scale=None, k=None, v=None):
     """Decode attention read straight from a paged KV pool: ``query``
     (S, K, Hq, D) — query k of slot s at ``positions[s] + k`` — against
     the pages ``page_table`` (S, W+1) maps in layer ``layer`` (an int32
     scalar array) of the pools [pages, layers, Hkv, D, page_tokens].
     Returns (S, K, Hq*D); an unmapped page contributes nothing, a slot
-    with none returns zeros. TPU-native extension; see
-    ops/pallas_kernels.py."""
+    with none returns zeros. Given ``k``, ``v`` (S, 1, Hkv, D), the new
+    rows of a K = 1 tick, it stores each at its slot's position first (a
+    slot whose page there is unmapped stores nothing) and returns ``(out,
+    k_pool, v_pool)``. TPU-native extension; see ops/pallas_kernels.py."""
+    rows = [] if k is None else [_nd(k), _nd(v)]
     return _op("paged_decode_attention", _nd(query), _nd(k_pool),
                _nd(v_pool), _nd(layer), _nd(page_table), _nd(positions),
-               scale=scale)
+               *rows, scale=scale)
 
 
 def mla_decode_attention(query, pool, layer, page_table, positions,

@@ -606,13 +606,17 @@ def _paged_decode_attention(scale=None):
     """``q`` (S, K, Hq, D), the pools [pages, layers, Hkv, D, page_tokens],
     ``layer`` (int32 scalar operand), ``page_table`` (S, W+1), ``positions``
     (S,) -> (S, K, Hq*D). Grouped heads follow from the shapes (Hq a
-    multiple of Hkv). See ``pallas_kernels.paged_decode_attention``."""
-    def f(q, k_pool, v_pool, layer, page_table, positions):
+    multiple of Hkv). Two more operands, ``k``, ``v`` (S, 1, Hkv, D): a
+    K = 1 tick's new rows, stored before the query attends -> (out, k_pool,
+    v_pool). See ``pallas_kernels.paged_decode_attention``."""
+    def f(q, k_pool, v_pool, layer, page_table, positions, *rows):
         from .pallas_kernels import paged_decode_attention
 
         out = paged_decode_attention(q, k_pool, v_pool, layer, page_table,
-                                     positions, scale)
-        return out.reshape(out.shape[:2] + (-1,))
+                                     positions, scale, *rows)
+        if not rows:
+            return out.reshape(out.shape[:2] + (-1,))
+        return (out[0].reshape(out[0].shape[:2] + (-1,)),) + out[1:]
 
     return f
 

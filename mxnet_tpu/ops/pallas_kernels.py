@@ -20,6 +20,8 @@ Design:
   maps, read where they lie in the pool (page table, positions and layer id
   as scalar-prefetch operands; grid (slot, logical page); block = one page of
   one layer; online softmax in float32 on the vector unit). Forward only.
+  Handed a K = 1 tick's new rows it also stores them (the page a slot's row
+  falls in is the last it reads: merged there, copied back as one block).
 - kernels engage only on the TPU backend with aligned shapes; everywhere else
   the mathematically identical XLA reference path runs, so the CPU test mesh
   exercises the same API.
@@ -1073,7 +1075,7 @@ _fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # table maps, read where they lie in the pool
 # ---------------------------------------------------------------------------
 def paged_decode_attention(q, k_pool, v_pool, layer, page_table, positions,
-                           scale=None):
+                           scale=None, k=None, v=None):
     """Decode attention straight from a paged KV pool.
 
     q : (S, K, Hq, D) — query k of slot s stands at ``positions[s] + k``.
@@ -1085,25 +1087,61 @@ def paged_decode_attention(q, k_pool, v_pool, layer, page_table, positions,
     layer : int32 scalar (an operand, not an attribute: one program serves
         every layer). page_table : (S, W+1) int32, logical page -> pool page,
         ``num_pages`` marking an unmapped column. positions : (S,) int32.
+    k / v : (S, 1, Hkv, D) in the pools' dtype, or None — the slots' NEW
+        rows (K = 1 only: K rows can straddle two pages). Slot s's row goes
+        into cell ``positions[s] % page_tokens`` of the page its table maps
+        for ``positions[s]`` before the query attends, so the query sees its
+        own position; a slot whose page there is unmapped, or whose position
+        lies past the table, writes nothing. Every other byte of the pools
+        stays as it is.
 
     Query k attends the positions ``<= positions[s] + k`` that lie in mapped
     pages: nothing past a slot's length, and nothing of an unmapped page, is
     read into the result (such cells may hold anything, NaN included). A
     slot with no mapped page — an inactive one — returns ZEROS.
-    Returns (S, K, Hq, D) in q's dtype; scores and sums in float32.
+    Returns (S, K, Hq, D) in q's dtype, scores and sums in float32; given
+    rows, ``(out, k_pool, v_pool)`` with the pools as written.
 
-    Pallas kernel on TPU (block = one page, no tuning knob); the gather +
-    mask + softmax of the same numbers elsewhere."""
+    Pallas kernel on TPU (block = one page, no tuning knob; given rows it
+    merges each into the slot's last page, which it reads anyway, and writes
+    that one page back into the aliased pool); the page-wise write and the
+    gather + mask + softmax of the same numbers elsewhere."""
     d, p = k_pool.shape[-2:]
     s = float(scale) if scale is not None else 1.0 / d ** 0.5
     layer = jnp.asarray(layer, jnp.int32)
     page_table = page_table.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
+    if k is not None and q.shape[1] != 1:
+        from ..base import MXNetError
+
+        raise MXNetError(
+            f"paged_decode_attention stores the rows of a K = 1 tick only "
+            f"(got K = {q.shape[1]}): write the rows first")
     if _use_pallas() and p % 128 == 0 and d % 8 == 0:
         return _paged_decode_tpu(q, k_pool, v_pool, layer, page_table,
-                                 positions, s)
+                                 positions, s, k, v)
+    if k is None:
+        return _paged_decode_reference(q, k_pool, v_pool, layer, page_table,
+                                       positions, s)
+    k_pool = _write_row_reference(k_pool, layer, page_table, positions, k)
+    v_pool = _write_row_reference(v_pool, layer, page_table, positions, v)
     return _paged_decode_reference(q, k_pool, v_pool, layer, page_table,
-                                   positions, s)
+                                   positions, s), k_pool, v_pool
+
+
+def _write_row_reference(pool, layer, page_table, positions, rows):
+    """The plain write of a K = 1 tick: read the page each slot's position
+    falls in, set the one cell, write the page back; the sentinel id (an
+    unmapped column, or column W for a position past the table) reads a
+    clamped page and its update is dropped."""
+    p = pool.shape[-1]
+    w = page_table.shape[1] - 1
+    ids = jnp.take_along_axis(
+        page_table, jnp.minimum(positions // p, w)[:, None], axis=1)[:, 0]
+    hit = jnp.arange(p, dtype=jnp.int32) == (positions % p)[:, None]  # (S,P)
+    new = jnp.where(hit[:, None, None, :], rows[:, 0, :, :, None],
+                    pool[ids, layer])                  # (S, Hkv, D, P)
+    return pool.at[ids, layer].set(new, mode="drop")
 
 
 def _paged_decode_reference(q, k_pool, v_pool, layer, page_table, positions,
@@ -1141,8 +1179,7 @@ def _paged_decode_reference(q, k_pool, v_pool, layer, page_table, positions,
 
 
 def _paged_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref,
-                         o_ref, qb_ref, acc_ref, m_ref, l_ref, s_ref, *,
-                         num_pages, group):
+                         *refs, num_pages, group, write):
     """Grid (slot, logical page). ``q_ref``/``o_ref``: (K, D, Hq) of the
     slot, heads along the lanes; ``k_ref``/``v_ref``: (Hkv, D, P), one page
     of one layer. Everything runs on the vector unit in float32: one query
@@ -1154,8 +1191,21 @@ def _paged_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref,
     running maximum and sum, the same in every lane. The heads are unrolled
     and the softmax bookkeeping runs on all of them at once: a loop over
     heads with a (1, P) row each took 2.4 times as long on the chip, and
-    its unrolled form 1.2 times."""
-    del lay_ref                       # the index maps read it
+    its unrolled form 1.2 times.
+
+    ``write`` (K = 1): ``kn_ref``/``vn_ref`` (D, Hkv) hold the slot's new
+    row, heads along the lanes; ``ko_ref``/``vo_ref`` are the pools again,
+    whole, where they lie (aliased to the inputs). At the step of the page
+    the slot's position falls in, the row goes into its lane of the page
+    just read and the merged page is copied back over it from a scratch
+    (``kw_ref``/``vw_ref``), one whole aligned block a pool; the copies are
+    waited for at the slot's last step, before the next slot merges. A slot
+    with no page there starts no copy."""
+    if write:
+        kn_ref, vn_ref, o_ref, ko_ref, vo_ref, *refs = refs
+        qb_ref, acc_ref, m_ref, l_ref, s_ref, kw_ref, vw_ref, sem = refs
+    else:
+        o_ref, qb_ref, acc_ref, m_ref, l_ref, s_ref = refs
     si, j = pl.program_id(0), pl.program_id(1)
     kq, d, hq = q_ref.shape
     p = k_ref.shape[-1]
@@ -1174,9 +1224,48 @@ def _paged_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref,
                               keepdims=True)                     # (D, 1)
                 qb_ref[k, h] = jnp.broadcast_to(col, (d, p))
 
+    kpos = j * p + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+
+    if write:
+        w = pl.num_programs(1)
+        at = pos // p                 # the column the row's page stands in
+        page = tab_ref[si, jnp.minimum(at, w - 1)]
+        writes = (at < w) & (page < num_pages)
+        copies = [pltpu.make_async_copy(src, dst.at[page, lay_ref[0]],
+                                        sem.at[i])
+                  for i, (src, dst) in enumerate([(kw_ref, ko_ref),
+                                                  (vw_ref, vo_ref)])]
+        kv_head = jax.lax.broadcasted_iota(jnp.int32, kn_ref.shape, 1)
+
+        @pl.when(writes & (j == at))
+        def _merge():
+            # the merged page twice: over the block just read, where the
+            # ONE page body below reads it (a second body for the scratch
+            # cost seconds of tracing a program; a block is refetched only
+            # when its index changes, and no later step of this slot reads
+            # it), and in the scratch the copies leave from (theirs until
+            # the wait)
+            for new_ref, page_ref, merged_ref in [(kn_ref, k_ref, kw_ref),
+                                                  (vn_ref, v_ref, vw_ref)]:
+                new = new_ref[...].astype(jnp.float32)           # (D, Hkv)
+                for h in range(new.shape[1]):
+                    col = jnp.sum(jnp.where(kv_head == h, new, 0.0), axis=1,
+                                  keepdims=True)                 # (D, 1)
+                    merged = jnp.where(
+                        kpos == pos, col, page_ref[h].astype(jnp.float32)
+                    ).astype(merged_ref.dtype)
+                    page_ref[h] = merged
+                    merged_ref[h] = merged
+            for copy in copies:
+                copy.start()
+
+        @pl.when(writes & (j == w - 1))
+        def _written():
+            for copy in copies:
+                copy.wait()
+
     @pl.when((j * p <= pos + kq - 1) & (tab_ref[si, j] < num_pages))
     def _page():
-        kpos = j * p + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
         for k in range(kq):
             ok = kpos <= pos + k                                 # (1, P)
             for h in range(hq):
@@ -1210,10 +1299,12 @@ def _paged_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref,
             o_ref[k] = out.astype(o_ref.dtype)
 
 
-def _paged_decode_tpu(q, k_pool, v_pool, layer, page_table, positions, scale):
+def _paged_decode_tpu(q, k_pool, v_pool, layer, page_table, positions, scale,
+                      k_new=None, v_new=None):
     num_pages, _, hkv, d, p = k_pool.shape
     s, kq, hq, _ = q.shape
     w = page_table.shape[1] - 1
+    write = k_new is not None
 
     def page(si, j, tab, pos, lay):
         # past the slot's last live page the block index repeats, and a
@@ -1230,31 +1321,56 @@ def _paged_decode_tpu(q, k_pool, v_pool, layer, page_table, positions, scale):
     q_spec = pl.BlockSpec((None, kq, d, hq), slot)
     itemsize = k_pool.dtype.itemsize
     scratch = 4 * (2 * kq * hq * d * p + (2 * kq + 1) * hq * p)
+    operands = [(q * scale).transpose(0, 1, 3, 2), k_pool, v_pool]
+    in_specs = [q_spec, pool_spec, pool_spec]
+    out_specs = q_spec
+    out_shape = jax.ShapeDtypeStruct((s, kq, d, hq), q.dtype)
+    scratch_shapes = [
+        pltpu.VMEM((kq, hq, d, p), jnp.float32),     # qb
+        pltpu.VMEM((kq, hq, d, p), jnp.float32),     # acc
+        pltpu.VMEM((kq, hq, p), jnp.float32),        # m
+        pltpu.VMEM((kq, hq, p), jnp.float32),        # l
+        pltpu.VMEM((hq, p), jnp.float32),            # scores
+    ]
+    aliases = {}
+    if write:
+        # the new rows as the queries are laid, heads along the lanes; the
+        # pools come back whole and where they lie: operand 4 (after the
+        # three prefetched scalars) is output 1, operand 5 output 2
+        row_spec = pl.BlockSpec((None, None, d, hkv), slot)
+        operands += [x.astype(k_pool.dtype).transpose(0, 1, 3, 2)
+                     for x in (k_new, v_new)]
+        in_specs += [row_spec, row_spec]
+        whole = pl.BlockSpec(memory_space=pl.ANY)
+        out_specs = [out_specs, whole, whole]
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (k_pool, v_pool)]
+        scratch_shapes += [pltpu.VMEM((hkv, d, p), k_pool.dtype),   # merged
+                           pltpu.VMEM((hkv, d, p), v_pool.dtype),
+                           pltpu.SemaphoreType.DMA((2,))]
+        aliases = {4: 1, 5: 2}
+    pages = 2 * hkv * d * p * itemsize               # a K and a V page
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, num_pages=num_pages,
-                          group=hq // hkv),
+                          group=hq // hkv, write=write),
         name="mxtpu_paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(s, w),
-            in_specs=[q_spec, pool_spec, pool_spec],
-            out_specs=q_spec,
-            scratch_shapes=[
-                pltpu.VMEM((kq, hq, d, p), jnp.float32),     # qb
-                pltpu.VMEM((kq, hq, d, p), jnp.float32),     # acc
-                pltpu.VMEM((kq, hq, p), jnp.float32),        # m
-                pltpu.VMEM((kq, hq, p), jnp.float32),        # l
-                pltpu.VMEM((hq, p), jnp.float32),            # scores
-            ]),
-        out_shape=jax.ShapeDtypeStruct((s, kq, d, hq), q.dtype),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         cost_estimate=pl.CostEstimate(
             flops=4 * s * kq * hq * d * w * p,
-            bytes_accessed=2 * s * w * hkv * d * p * itemsize,
+            bytes_accessed=s * (w + write) * pages,
             transcendentals=2 * s * kq * hq * w * p),
         interpret=_interpret(),
-        **_vmem_params(scratch + 4 * hkv * d * p * itemsize),
-    )(page_table, positions, layer.reshape(1),
-      (q * scale).transpose(0, 1, 3, 2), k_pool, v_pool)
+        **_vmem_params(scratch + (2 + write) * pages),
+    )(page_table, positions, layer.reshape(1), *operands)
+    if write:
+        return (out[0].transpose(0, 1, 3, 2),) + tuple(out[1:])
     return out.transpose(0, 1, 3, 2)
 
 

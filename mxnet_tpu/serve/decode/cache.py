@@ -79,15 +79,21 @@ the mask always excludes).
 **Writes** are indexed updates of the (donated) pool, in place and a
 WHOLE PAGE of one or all layers at a time: ``np.index_update`` at the
 page ids the table maps. (An update of a single position makes XLA lay
-the whole pool out anew and back, an update of whole pages does not.) The
-tick therefore reads the pages its rows land in, puts the rows in and
-writes the pages back. A write routed at the sentinel id (an unmapped
-column, an inactive slot, a chunk past ``valid_length``) is out of range
-— one past the end, never negative — and jax's ``.at[].set`` drops
-out-of-range updates, so it vanishes exactly instead of corrupting a live
-page. The tick and the prefix join write each layer's k/v BEFORE that
-layer's attention, so the pool already holds the new positions. Nothing
-but the updates has the pool's shape, and all three views keep fully
+the whole pool out anew and back, an update of whole pages does not.) A
+draft's tick (K > 1) and a latent tick therefore read the pages their rows
+land in, put the rows in and write the pages back (``_write_rows``). A
+write routed at the sentinel id (an unmapped column, an inactive slot, a
+chunk past ``valid_length``) is out of range — one past the end, never
+negative — and jax's ``.at[].set`` drops out-of-range updates, so it
+vanishes exactly instead of corrupting a live page. They and the prefix
+join write each layer's k/v BEFORE that layer's attention, so the pool
+already holds the new positions. The PLAIN tick (K = 1) makes no update
+of its own: ``npx.paged_decode_attention`` is handed the slots' new rows
+and stores them, on the chip inside the kernel, which merges a slot's
+row into the page it reads last for that slot and copies that one page
+back into the pool it was given (aliased to its output); a slot whose
+page there is the sentinel starts no copy. Nothing but the updates and
+that op's results has the pool's shape, and all three views keep fully
 static shapes, preserving the zero-recompile serving contract.
 
 **Host side.** ``PagedKVCache`` holds the device pool pair, the page
@@ -676,15 +682,20 @@ class TickView(_PagedView):
         length); column i lands at positions + i.
     page_table : (S, W+1) int32 row per slot (sentinel = num_pages).
 
-    Each layer writes its S*K new k/v rows into the pool first (a
-    read-modify-write of the pages they land in, ``_write_rows``)
-    and then attends the pool itself, which already holds them:
-    ``npx.paged_decode_attention`` walks the pages each slot's row
-    maps, up to its length, and query i reads positions <=
-    positions + i. A row whose page id is the sentinel (an inactive
-    slot, a position past the table) writes nothing, and a slot with
-    no mapped page attends nothing (its logits are those of a zero
-    attention output; the engine never reads them)."""
+    Each layer stores its S*K new k/v rows and attends the pool that
+    holds them: query i reads positions <= positions + i of the pages
+    its slot's row maps. In the plain tick (K = 1)
+    ``npx.paged_decode_attention`` does both: handed the rows, it puts
+    each into the page it reads last for that slot and hands the pools
+    back (on the chip the kernel writes that one page of a pool in
+    place; no page is gathered or scattered around it). A draft's K > 1
+    rows can straddle two pages: the view writes them first (a
+    read-modify-write of the pages they land in, ``_write_rows``, as
+    ``attend_latent`` does for latent rows) and the op only reads. A
+    row whose page id is the sentinel (an inactive slot, a position
+    past the table) is written by neither, and a slot with no mapped
+    page attends nothing (its logits are those of a zero attention
+    output; the engine never reads them)."""
 
     decoding = True
 
@@ -716,14 +727,24 @@ class TickView(_PagedView):
     def attend(self, layer, q, k, v, scale=None):
         lay = _layer_id(layer)
         rows = (self.S, self.K, self.heads, self.head_dim)
+        q = np.reshape(q, (self.S, self.K, -1, self.head_dim))
+        k, v = np.reshape(k, rows), np.reshape(v, rows)
+        if self.K == 1:
+            out, k_pool, v_pool = npx.paged_decode_attention(
+                q, self.k_pool, self.v_pool, lay, self.page_table,
+                self.slot_positions, scale=scale, k=k, v=v)
+            # waited for, as ``_update_pool`` waits: in the eager trace
+            # each pool the op returns is a copy
+            self.k_pool = k_pool.wait_to_read()
+            self.v_pool = v_pool.wait_to_read()
+            return out
         self.k_pool = _write_rows(self.k_pool, lay, self._page_id,
-                                  self._hits, np.reshape(k, rows))
+                                  self._hits, k)
         self.v_pool = _write_rows(self.v_pool, lay, self._page_id,
-                                  self._hits, np.reshape(v, rows))
+                                  self._hits, v)
         return npx.paged_decode_attention(
-            np.reshape(q, (self.S, self.K, -1, self.head_dim)), self.k_pool,
-            self.v_pool, lay, self.page_table, self.slot_positions,
-            scale=scale)
+            q, self.k_pool, self.v_pool, lay, self.page_table,
+            self.slot_positions, scale=scale)
 
     def attend_latent(self, layer, row, q, k=None, v=None, scale=None,
                       heads=1):

@@ -20,8 +20,9 @@ applied to decoding — the host only feeds operands):
   puts its new rows into the pages they land in and attends the pool
   where it lies: one paged kernel a layer (``mxtpu_paged_decode``, via
   ``npx.paged_decode_attention``) walks the pages a slot's table row
-  maps and stops at the slot's length — fixed shape, traced and compiled
-  exactly once. K = 1 is the plain tick; K > 1 verifies a K-1-token
+  maps and stops at the slot's length (at K = 1 the same kernel stores
+  the slot's row in the last page it reads) — fixed shape, traced and
+  compiled exactly once. K = 1 is the plain tick; K > 1 verifies a K-1-token
   draft in one batched pass (speculative decoding). Static K keeps the
   program set fixed, so steady state never recompiles regardless of
   drafts, prefix hits, or which requests join or leave.

@@ -52,6 +52,35 @@ def module_scope_waitall():
     mx.waitall()
 
 
+@pytest.fixture
+def paged_kernel_interpreted(monkeypatch):
+    """While the test runs, ``npx.paged_decode_attention`` takes its Pallas
+    kernel, interpreted, wherever it is traced (pages of 128 positions
+    permitting). Only this op is steered (its body imports
+    ``pallas_kernels.paged_decode_attention`` at call time), so no other
+    op's per-process jit sees interpret mode; the op's own jits are
+    forgotten before and after. The value is a list that gets one entry a
+    trace: whether that call stored rows."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops.registry import get_op
+
+    stored = []
+    real = pk.paged_decode_attention
+
+    def through_the_kernel(*args):
+        stored.append(len(args) > 7)
+        with monkeypatch.context() as mp:
+            mp.setattr(pk, "_use_pallas", lambda: True)
+            mp.setattr(pk, "_interpret", lambda: True)
+            return real(*args)
+
+    op = get_op("paged_decode_attention")
+    op._fn_cache.clear()
+    monkeypatch.setattr(pk, "paged_decode_attention", through_the_kernel)
+    yield stored
+    op._fn_cache.clear()
+
+
 def pytest_configure(config):
     config.addinivalue_line("markers", "seed(n): fix the RNG seed for a test")
     config.addinivalue_line("markers", "serial: run in isolation")

@@ -161,17 +161,25 @@ def serve_programs(chip_kernels):
         net = GPTModel(vocab_size=512, num_layers=2, units=1024,
                        num_heads=16, max_length=512, dropout=0.0)
         net.initialize()
-        return DecodePrograms(net, num_slots=5, max_len=512,
-                              prefill_batch=2, max_prompt_len=128,
-                              min_prompt_bucket=128, page_tokens=128,
-                              kv_pages=128, speculate_k=2,
-                              prefix_cache=True)
+        progs = DecodePrograms(net, num_slots=5, max_len=512,
+                               prefill_batch=2, max_prompt_len=128,
+                               min_prompt_bucket=128, page_tokens=128,
+                               kv_pages=128, speculate_k=2,
+                               prefix_cache=True)
+        # beside the draft's tick (K = 2) the plain one, as an engine
+        # without speculation traces it
+        with mx.autograd.pause():
+            progs._cops["decode:1"] = progs._trace(
+                "decode", 1, progs._collect_params())
+        progs._graph_params["decode:1"] = progs._graph_params["decode:2"]
+        return progs
     finally:
         mp.undo()
         jax.clear_caches()
 
 
-@pytest.mark.parametrize("family", ["decode", "prefill", "prefill_ext"])
+@pytest.mark.parametrize("family", ["decode", "decode_k2", "prefill",
+                                    "prefill_ext"])
 def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
     """The pool is ``f32[pages, layers, 16, 64, 128]``, a page's positions
     as its fastest axis: the layout the chip chose for the older
@@ -182,7 +190,9 @@ def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
     whole pages compile in place. Guard: nothing of the pool's shape but
     the updates. The tick besides holds one paged kernel a layer and no
     array of the gathered view's size (slots x max_len x units): the
-    view, its re-lay and the dense attention over it are gone."""
+    view, its re-lay and the dense attention over it are gone. The PLAIN
+    tick (K = 1) holds no update either: its kernels take the pools and
+    hand them back, written where they lie."""
     import re
 
     progs = serve_programs
@@ -190,9 +200,11 @@ def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
         tuple(shape), dt, sharding=chip_kernels)
     pool = sds(progs.cache_shape, jnp.float32)
     S, Wt = progs.num_slots, progs.table_width
-    if family == "decode":
-        gkey = "decode:2"
-        data = [sds((S, 2), jnp.int32), sds((S,), jnp.int32),
+    tick = family.startswith("decode")
+    if tick:
+        K = 2 if family == "decode_k2" else 1
+        family, gkey = "decode", f"decode:{K}"
+        data = [sds((S, K), jnp.int32), sds((S,), jnp.int32),
                 sds((S, Wt), jnp.int32)]
     else:
         gkey = f"{family}:128"
@@ -207,17 +219,22 @@ def test_pool_updates_compile_in_place(chip_kernels, serve_programs, family):
         *args, donate=progs._donate(family)).compile().as_text()
     shape = "f32[%s]" % ",".join(str(d) for d in progs.cache_shape)
     ops = re.findall(r"= %s\S* ([\w\-]+)\(" % re.escape(shape), text)
-    inside = {"parameter", "get-tuple-element", "bitcast", "scatter",
-              "dynamic-update-slice", "fusion", "while"}
+    inside = {"parameter", "get-tuple-element", "bitcast"}
+    if gkey != "decode:1":
+        inside |= {"scatter", "dynamic-update-slice", "fusion", "while"}
     assert ops and set(ops) <= inside, sorted(set(ops) - inside)
     # every pool-shaped fusion is an update fused with what feeds it
     for name in re.findall(r"= %s\S* fusion\(.*calls=%%([\w.\-]+)"
                            % re.escape(shape), text):
         body = text.split("%" + name + " (", 1)[1].split("\n}", 1)[0]
         assert re.search(r" (scatter|dynamic-update-slice)\(", body), name
-    if family != "decode":
+    if not tick:
         return
-    assert len(re.findall(r"%mxtpu_paged_decode[.\d]* = ", text)) == 2
+    calls = re.findall(r"%mxtpu_paged_decode[.\d]* = (.+?) custom-call\(",
+                       text)
+    assert len(calls) == 2
+    # the plain tick's kernels hand both pools back beside the output
+    assert all(c.count(shape) == (2 if K == 1 else 0) for c in calls), calls
     view = S * (Wt - 1) * 128 * 16 * 64
     sized = [ln.strip()[:120] for ln in text.splitlines()
              for m in [re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]",
@@ -488,20 +505,34 @@ def test_granite_serving_programs_compile_at_the_cells_widths(
         assert ma.temp_size_in_bytes < 2**30
 
 
+@pytest.mark.parametrize("stores", [False, True], ids=["reads", "stores"])
 def test_paged_decode_compiles_at_32_query_on_8_kv_heads_bfloat16(
-        chip_kernels):
+        chip_kernels, stores):
     """``mxtpu_paged_decode`` with ``group`` 4 and heads of 128 on a
-    bfloat16 pool of 512 pages: one kernel, no float32 copy of the pool."""
+    bfloat16 pool of 512 pages: one kernel, no float32 copy of the pool.
+    Handed the slots' new rows it takes the donated pools as its outputs:
+    no copy of a pool, no temporary."""
+    import re
+
     sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=chip_kernels)
     pool = sds((512, 1, 8, 128, 128), jnp.bfloat16)
-    text = jax.jit(lambda q, k, v, lay, tab, pos: pk._paged_decode_tpu(
-        q, k, v, lay, tab, pos, 0.0078125)).lower(
+    rows = [sds((32, 1, 8, 128), jnp.bfloat16)] * 2 if stores else []
+    compiled = jax.jit(
+        lambda q, k, v, lay, tab, pos, *rows: pk._paged_decode_tpu(
+            q, k, v, lay, tab, pos, 0.0078125, *rows),
+        donate_argnums=(1, 2) if stores else ()).lower(
             sds((32, 1, 32, 128), jnp.bfloat16), pool, pool,
             sds((), jnp.int32), sds((32, 17), jnp.int32),
-            sds((32,), jnp.int32)).compile().as_text()
+            sds((32,), jnp.int32), *rows).compile()
+    text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert "f32[512,1,8,128,128]" not in text
+    if stores:
+        ma = compiled.memory_analysis()
+        assert ma.alias_size_in_bytes == 2 * 512 * 8 * 128 * 128 * 2
+        assert ma.temp_size_in_bytes < 2**20
+        assert not re.search(r"= bf16\[512,\S* (copy|fusion)\(", text)
 
 
 # -- the A.X-K1 serving cell's shapes: bfloat16, 64 slots ---------------------

@@ -630,17 +630,17 @@ PA_TABLE = [[3], [5, 1], [0, 6, 2], [], [4]]
 PA_POS = [PA_P - 1, PA_P, 2 * PA_P + 37, 11, 0]
 
 
-def _paged_case(K, hq=4, hkv=4, seed=7):
+def _paged_case(K, hq=4, hkv=4, seed=7, table=PA_TABLE, pos=PA_POS,
+                pages=PA_PAGES, dtype="float32"):
     rs = onp.random.RandomState(seed)
-    shape = (PA_PAGES, PA_LAYERS, hkv, PA_D, PA_P)
-    tab = onp.full((len(PA_TABLE), PA_W + 1), PA_PAGES, dtype="int32")
-    for r, ids in enumerate(PA_TABLE):
+    shape = (pages, PA_LAYERS, hkv, PA_D, PA_P)
+    tab = onp.full((len(table), PA_W + 1), pages, dtype="int32")
+    for r, ids in enumerate(table):
         tab[r, :len(ids)] = ids
-    return (rs.standard_normal((len(PA_TABLE), K, hq, PA_D))
-            .astype("float32"),
-            rs.standard_normal(shape).astype("float32"),
-            rs.standard_normal(shape).astype("float32"),
-            tab, onp.array(PA_POS, "int32"))
+    return tuple(mx.np.array(a).astype(dtype).asnumpy() for a in (
+        rs.standard_normal((len(table), K, hq, PA_D)),
+        rs.standard_normal(shape), rs.standard_normal(shape))) \
+        + (tab, onp.array(pos, "int32"))
 
 
 def _dense_attention(q, kp, vp, layer, tab, pos):
@@ -650,14 +650,15 @@ def _dense_attention(q, kp, vp, layer, tab, pos):
     op's contract a position in an unmapped page counts for nothing (slot
     0's second query stands on one), and an inactive slot is zeros."""
     S, K, hq, D = q.shape
+    num_pages, W = kp.shape[0], tab.shape[1] - 1
     g = hq // kp.shape[2]
     out = onp.zeros((S, K, hq * D))
     for s in range(S):
-        ids = [int(i) for i in tab[s, :PA_W]]
-        if ids[0] >= PA_PAGES:
+        ids = [int(i) for i in tab[s, :W]]
+        if ids[0] >= num_pages:
             continue
-        pages = [min(i, PA_PAGES - 1) for i in ids]
-        mapped = onp.repeat(onp.array(ids) < PA_PAGES, PA_P)
+        pages = [min(i, num_pages - 1) for i in ids]
+        mapped = onp.repeat(onp.array(ids) < num_pages, PA_P)
         # (Hkv, D, W*P) -> heads repeated for the group
         kv = [onp.repeat(onp.concatenate(
             [pool[i, layer].astype("float64") for i in pages], -1), g, 0)
@@ -672,7 +673,7 @@ def _dense_attention(q, kp, vp, layer, tab, pos):
     return out
 
 
-def _paged_op(case, layer, body, monkeypatch):
+def _steer_paged_op(body, monkeypatch):
     """``npx.paged_decode_attention`` through the Pallas kernel in interpret
     mode or through the plain body (the per-op jit forgets its traces
     first, so neither body is served the other's)."""
@@ -683,11 +684,37 @@ def _paged_op(case, layer, body, monkeypatch):
         monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     else:
         monkeypatch.delenv("MXTPU_PALLAS_INTERPRET", raising=False)
+
+
+def _paged_op(case, layer, body, monkeypatch):
+    _steer_paged_op(body, monkeypatch)
     q, kp, vp, tab, pos = case
     return mx.npx.paged_decode_attention(
         mx.np.array(q), mx.np.array(kp), mx.np.array(vp),
         mx.np.array(layer, dtype="int32"), mx.np.array(tab),
         mx.np.array(pos)).asnumpy()
+
+
+# The tick STORES its rows too. Each case: (K, table rows, positions, then
+# ``_paged_case``'s keywords); what a case is for stands in its name. The
+# slots of "the_slots_above" are PA_TABLE's: page ends, a page's first cell,
+# mid-page, inactive, position 0.
+PA_LAST = 8       # a pool of 9 pages: the last one is a live slot's
+PA_STORES = {
+    "the_slots_above": (1, PA_TABLE, PA_POS, {}),
+    # slot 0's row opens page 6, which holds NaN in every cell (it was just
+    # mapped: nothing of it is the slot's yet); slot 1's opens its table
+    "a_page_just_mapped_holding_nan": (1, [[2, 6], [4]], [PA_P, 0], {}),
+    "a_pages_last_cell": (1, [[5], [0, 3]], [PA_P - 1, 2 * PA_P - 1], {}),
+    # an idle slot's table row is all sentinel, which the kernel's index map
+    # clamps to the pool's last page: that page is slot 1's, being written
+    "an_idle_slot_beside_the_pools_last_page": (
+        1, [[], [1, PA_LAST], []], [7, PA_P + 5, 0], {"pages": PA_LAST + 1}),
+    "a_position_past_the_table": (1, [[0, 1, 2], [3]], [3 * PA_P, 9], {}),
+    "four_query_heads_a_kv_head_bfloat16": (
+        1, PA_TABLE, PA_POS, {"hq": 8, "hkv": 2, "dtype": "bfloat16"}),
+    "a_draft_of_two_rows_written_first": (2, PA_TABLE, PA_POS, {}),
+}
 
 
 @pytest.mark.parametrize("body", ["kernel", "reference"])
@@ -700,6 +727,64 @@ def test_paged_decode_attention_matches_the_dense_view(monkeypatch, K, body):
         assert got.shape == want.shape
         onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
         assert (got[3] == 0).all(), "an inactive slot returns zeros"
+
+
+@pytest.mark.parametrize("body", ["kernel", "reference"])
+@pytest.mark.parametrize("name", list(PA_STORES))
+def test_a_tick_that_stores_its_rows_matches_write_then_dense_view(
+        monkeypatch, name, body):
+    """``TickView.attend`` of a layer: BOTH pools afterwards are, bit for
+    bit and on every page of every layer, what ``_write_rows`` makes of
+    the old ones; no page but a live slot's own is touched; the output is
+    the dense view's over the written pools. Only a draft's rows (K > 1)
+    are written by the view itself."""
+    K, table, pos, kw = PA_STORES[name]
+    q, kp, vp, tab, pos = _paged_case(K, table=table, pos=pos, **kw)
+    if "nan" in name:
+        kp[6], vp[6] = onp.nan, onp.nan
+    S, dtype = len(table), kp.dtype.name
+    rs = onp.random.RandomState(11)
+    rows = [mx.np.array(rs.standard_normal((S, K, kp.shape[2] * PA_D)))
+            .astype(dtype) for _ in range(2)]
+    own = {int(tab[s, pos[s] // PA_P]) for s in range(S)
+           if pos[s] // PA_P < PA_W} - {kp.shape[0]}
+    written = []
+    write_rows = kv._write_rows
+    monkeypatch.setattr(
+        kv, "_write_rows", lambda *a: written.append(1) or write_rows(*a))
+    _steer_paged_op(body, monkeypatch)
+    for layer in range(PA_LAYERS):
+        view = kv.TickView(
+            mx.np.zeros((S, K), dtype="int32"), mx.np.array(pos),
+            mx.np.array(tab), mx.np.array(kp), mx.np.array(vp))
+        want = [write_rows(pool, kv._layer_id(layer), view._page_id,
+                           view._hits, r.reshape(S, K, -1, PA_D)).asnumpy()
+                for pool, r in zip((view.k_pool, view.v_pool), rows)]
+        del written[:]
+        got = view.attend(layer, mx.np.array(q).reshape(S, K, -1), *rows)
+        assert len(written) == (0 if K == 1 else 2)
+        for pool, new, old in zip((view.k_pool, view.v_pool), want,
+                                  (kp, vp)):
+            pool = pool.asnumpy()
+            onp.testing.assert_array_equal(pool, new)
+            differs = (pool != old) & ~(onp.isnan(pool) & onp.isnan(old))
+            touched = set(onp.nonzero(differs.any((1, 2, 3, 4)))[0])
+            assert touched and (K > 1 or touched <= own), (touched, own)
+            assert not differs[:, 1 - layer].any()
+        out = got.asnumpy().astype("float64")
+        tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" \
+            else dict(rtol=2e-2, atol=2e-2)
+        onp.testing.assert_allclose(
+            out, _dense_attention(q, *want, layer, tab, pos), **tol)
+
+
+def test_the_op_stores_the_rows_of_a_plain_tick_only():
+    """A draft's rows can straddle two pages: the op refuses them by name."""
+    q, kp, vp, tab, pos = (mx.np.array(a) for a in _paged_case(2))
+    rows = mx.np.zeros((len(PA_TABLE), 2, 4, PA_D))
+    with pytest.raises(MXNetError, match="K = 1 tick only"):
+        mx.npx.paged_decode_attention(q, kp, vp, mx.np.array(0, dtype="int32"),
+                                      tab, pos, k=rows, v=rows)
 
 
 def test_paged_kernel_is_the_body_under_interpret(monkeypatch):
@@ -751,14 +836,12 @@ def test_paged_kernel_shares_a_page_among_grouped_heads(monkeypatch):
     onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
-def test_engine_on_the_paged_kernel_equals_uncached_greedy(monkeypatch):
-    """The tick through the Pallas kernel (interpret mode, speculation on,
-    pages of 128): the served tokens are those of the uncached loop. Only
-    this op is steered (the plain body is swapped for the kernel), so no
-    other op's per-process jit sees interpret mode."""
-    from mxnet_tpu.ops import pallas_kernels as pk
-    from mxnet_tpu.ops.registry import get_op
-
+@pytest.mark.parametrize("speculate_k", [1, 2])
+def test_engine_on_the_paged_kernel_equals_uncached_greedy(
+        paged_kernel_interpreted, speculate_k):
+    """The tick through the Pallas kernel (interpret mode, pages of 128),
+    storing its own rows (K = 1) or reading what the view wrote (a draft
+    of 2): the served tokens are those of the uncached loop."""
     mx.random.seed(17)
     model = gpt_tiny(vocab_size=VOCAB, dropout=0.0, num_layers=2, units=32,
                      num_heads=4, max_length=256)
@@ -767,31 +850,19 @@ def test_engine_on_the_paged_kernel_equals_uncached_greedy(monkeypatch):
     prompts = [rs.randint(1, VOCAB, n).tolist() for n in (3, 11, 16)]
     want = [model.generate(p, max_new_tokens=7, temperature=0.0,
                            use_cache=False)[len(p):] for p in prompts]
-    runs = []
-
-    def through_the_kernel(*args):
-        runs.append(1)
-        with monkeypatch.context() as mp:
-            mp.setattr(pk, "_interpret", lambda: True)
-            return pk._paged_decode_tpu(*args)
-
-    monkeypatch.setattr(pk, "_paged_decode_reference", through_the_kernel)
-    op = get_op("paged_decode_attention")
-    op._fn_cache.clear()
+    eng = DecodeEngine(model, num_slots=2, max_len=256,
+                       max_prompt_len=16, prefill_batch=1,
+                       page_tokens=128, speculate_k=speculate_k,
+                       prefix_cache=False, max_wait_us=0,
+                       cache_dir=False)
     try:
-        eng = DecodeEngine(model, num_slots=2, max_len=256,
-                           max_prompt_len=16, prefill_batch=1,
-                           page_tokens=128, speculate_k=2,
-                           prefix_cache=False, max_wait_us=0,
-                           cache_dir=False)
-        try:
-            streams = [eng.submit(p, max_new_tokens=7) for p in prompts]
-            got = [st.result(timeout=300) for st in streams]
-        finally:
-            eng.close()
+        streams = [eng.submit(p, max_new_tokens=7) for p in prompts]
+        got = [st.result(timeout=300) for st in streams]
     finally:
-        op._fn_cache.clear()
-    assert runs and got == want
+        eng.close()
+    stored = paged_kernel_interpreted
+    assert stored and set(stored) == {speculate_k == 1}
+    assert got == want
 
 
 def _hlo_instructions(text):

@@ -294,6 +294,30 @@ def test_a_model_with_recurrent_state_defined_here_is_served(leaky_lm):
     assert stats["steps"] == sum(map(len, prompts)) + 7 * 7
 
 
+@pytest.mark.parametrize("which", ["tiny_lm", "leaky_lm"])
+def test_a_plain_tick_stores_its_rows_through_the_paged_kernel(
+        which, request, paged_kernel_interpreted):
+    """K = 1 on pages of 128 positions: the tick's op takes the Pallas
+    kernel (interpreted), which stores each slot's new row itself. More
+    requests than slots, with and without recurrent state beside the
+    pages: the tokens are the full forward's."""
+    model = request.getfixturevalue(which)
+    rs = onp.random.RandomState(6)
+    prompts = [rs.randint(1, VOCAB, n).tolist() for n in (4, 13, 1, 16, 7)]
+    want = [_full_forward_greedy(model, p, 8) for p in prompts]
+    eng = DecodeEngine(model, num_slots=2, max_len=MAX_LEN,
+                       max_prompt_len=16, prefill_batch=2,
+                       page_tokens=128, speculate_k=1,
+                       prefix_cache=False, cache_dir=False)
+    try:
+        streams = [eng.submit(p, max_new_tokens=8) for p in prompts]
+        got = [s.result(timeout=300) for s in streams]
+    finally:
+        eng.close()
+    assert paged_kernel_interpreted and all(paged_kernel_interpreted)
+    assert got == want
+
+
 def test_a_cache_names_its_operands_and_an_empty_state_adds_none(
         gpt, leaky_lm):
     """The donated operands of every program are what the cache names. A
