@@ -206,11 +206,11 @@ class _Flight:
     stream)] as dispatched: the slot may be another request's by the time
     the tokens are read. ``ends`` maps a slot to why its request ends with
     this program's token(s) ("done" | "truncated" | "starved"), for what
-    was known at dispatch. ``span`` is the dispatch span (the wait's timer
-    sample covers both). Of a tick besides: the ``drafts`` it verifies
-    (K > 1), the slots the pool ``starved``, the share of the slots it ran
-    with (``occupancy``) and whether it went out while an earlier tick's
-    tokens were unread (``overlapped``)."""
+    was known at dispatch. ``span`` is the dispatch leaf, the program's
+    call alone (the wait's timer sample covers both). Of a tick besides:
+    the ``drafts`` it verifies (K > 1), the slots the pool ``starved``, the
+    share of the slots it ran with (``occupancy``) and whether it went out
+    while an earlier tick's tokens were unread (``overlapped``)."""
 
     __slots__ = ("tick", "out", "rows", "ends", "span", "drafts", "starved",
                  "occupancy", "overlapped")
@@ -819,7 +819,7 @@ class DecodeEngine:
                     rids=" ".join(str(s.rid) for s, _ in sub),
                     queue_wait_ms_max=max(
                         t_q - s.t_submit for s, _ in sub) * 1e3)
-        with span("serve.prefill.dispatch", batch=B, length=T) as sp:
+        with span("serve.prefill.stage", batch=B, length=T):
             args = [jax.device_put(tokens), jax.device_put(valid)]
             if ext:
                 args.append(jax.device_put(start))
@@ -830,7 +830,11 @@ class DecodeEngine:
                 args.append(jax.device_put(onp.asarray(
                     slots + [self.num_slots] * (B - len(slots)), "int32")))
             args += [jax.device_put(table), *cache.operands()]
+            n_operands = self.programs.n_operands(key, len(args))
+        with span("serve.prefill.dispatch", batch=B, length=T,
+                  operands=n_operands) as sp:
             outs = self._run_retry(key, args, point="decode.prefill")
+        with span("serve.prefill.account", batch=B):
             cache.rebind(outs[1:])
             if self._depth:
                 # the first tokens go from this program's output to the
@@ -936,13 +940,17 @@ class DecodeEngine:
                     drafts[sid] = d
                     tokens[sid, 1:] = d
                 tokens = jax.device_put(tokens)
-        with span("serve.tick.dispatch", live=len(live)) as sp:
+        with span("serve.tick.stage", live=len(live)):
             # lengths and table are COPIED: the host's arrays move on
             # before the device has taken these
-            outs = self._run_retry(("decode", K), [
-                tokens, jax.device_put(cache.lengths.copy()),
-                jax.device_put(cache.table.copy()), *cache.operands()],
-                point="decode.tick")
+            key = ("decode", K)
+            args = [tokens, jax.device_put(cache.lengths.copy()),
+                    jax.device_put(cache.table.copy()), *cache.operands()]
+            n_operands = self.programs.n_operands(key, len(args))
+        with span("serve.tick.dispatch", live=len(live),
+                  operands=n_operands) as sp:
+            outs = self._run_retry(key, args, point="decode.tick")
+        with span("serve.tick.account") as acc:
             cache.rebind(outs[1:])
             rec = _Flight(True, outs[0],
                           [(sid, sid, self._slot_req[sid]) for sid in live],
@@ -955,6 +963,7 @@ class DecodeEngine:
                 self._next_tok = outs[0]
                 for _, sid, stream in rec.rows:
                     self._advance(rec, sid, stream, 1)
+            acc.note(ended=len(rec.ends))
         self._inflight.append(rec)
 
     def _advance(self, rec, sid, stream, m):

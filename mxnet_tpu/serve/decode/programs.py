@@ -575,6 +575,14 @@ class DecodePrograms:
             return jax.jit(
                 fn, donate_argnums=argnums).lower(*args).compile()
 
+    def n_operands(self, key, n_datas):
+        """How long the list is that ``run`` hands the executable for
+        ``n_datas`` data operands: they, the param tail, and a PRNG key
+        for rng graphs."""
+        ck = self._cop_key(key)
+        return n_datas + len(self._graph_params[ck]) \
+            + bool(self._cops[ck]._uses_rng)
+
     def run(self, key, datas):
         """Call a compiled program with raw device operands; appends the
         param tail (and a PRNG key for rng graphs) in trace order."""

@@ -44,12 +44,21 @@ SPANS = {
     "serve.prefill.host": "building tokens/valid/start/table and the "
                           "slots' page-table rows (batch, length, rids, "
                           "queue_wait_ms_max)",
-    "serve.prefill.dispatch": "device_put of the prefill operands, the "
-                              "program call, with speculate_k == 1 the "
-                              "place program that hands the first tokens "
-                              "to the next tick on the device, and the "
-                              "slots' bookkeeping: lengths, radix insert "
-                              "(batch, length)",
+    "serve.prefill.stage": "device_put of the prefill's tokens, valid, "
+                           "start, slot ids and table: host state into "
+                           "operands (batch, length)",
+    "serve.prefill.dispatch": "the prefill program's call alone: site, "
+                              "admission check, fault point, the operand "
+                              "list with the parameter tail, the "
+                              "executable until it returns its output "
+                              "arrays (batch, length, operands = the "
+                              "list's length)",
+    "serve.prefill.account": "rebinding the cache to the program's "
+                             "outputs, with speculate_k == 1 the place "
+                             "program that hands the first tokens to the "
+                             "next tick on the device, and the slots' "
+                             "bookkeeping: lengths, radix insert, "
+                             "one-token ends (batch)",
     "serve.wait_prefill": "reading a prefill's first tokens back from the "
                           "device: with speculate_k == 1 AFTER the tick "
                           "that follows it was dispatched",
@@ -60,13 +69,19 @@ SPANS = {
                        "starved)",
     "serve.tick.draft": "draft proposal and the token operand, only "
                         "when speculate_k > 1",
-    "serve.tick.dispatch": "two device_puts and the tick program call; "
-                           "with speculate_k == 1 the token operand is "
-                           "the tick before's output, still on the "
-                           "device, and every row's one token is "
-                           "accounted here (lengths, which requests end, "
-                           "their slots and pages given back). One a tick "
-                           "(live)",
+    "serve.tick.stage": "the host copies of lengths and table and their "
+                        "device_puts: host state into operands; with "
+                        "speculate_k == 1 the token operand is the tick "
+                        "before's output, still on the device (live)",
+    "serve.tick.dispatch": "the tick program's call alone: site, "
+                           "admission check, fault point, the operand "
+                           "list with the parameter tail, the executable "
+                           "until it returns its output arrays. One a "
+                           "tick (live, operands = the list's length)",
+    "serve.tick.account": "rebinding the cache to the program's outputs, "
+                          "the flight record, and with speculate_k == 1 "
+                          "every row's one token: lengths, which requests "
+                          "end, their slots and pages given back (ended)",
     "serve.wait_tick": "reading a tick's tokens back from the device: "
                        "with speculate_k == 1 those of the tick BEFORE "
                        "the one just dispatched, which runs meanwhile",

@@ -20,8 +20,16 @@ VOCAB, MAX_LEN = 50, 64
 STEP_SPANS = ["train.assemble", "train.key", "train.schedule",
               "train.dispatch", "train.writeback", "train.commit",
               "train.wait_health", "train.health", "train.mark"]
-TICK_SPANS = ["serve.tick.grow", "serve.tick.draft", "serve.tick.dispatch",
+TICK_SPANS = ["serve.tick.grow", "serve.tick.draft", "serve.tick.stage",
+              "serve.tick.dispatch", "serve.tick.account",
               "serve.wait_tick", "serve.tick.commit"]
+# what the two dispatch spans were until ISSUE 38: stage, call, account
+TICK_CALL = ["serve.tick.stage", "serve.tick.dispatch",
+             "serve.tick.account"]
+PREFILL_CALL = ["serve.prefill.host", "serve.prefill.stage",
+                "serve.prefill.dispatch", "serve.prefill.account"]
+PREFILL_READ = ["serve.wait_prefill", "serve.prefill.commit"]
+PROMPTS = ([1, 2, 3], [4, 5, 6, 7, 8], [9, 10])
 
 
 @pytest.fixture(autouse=True)
@@ -46,6 +54,18 @@ def net():
 def engine(net):
     eng = DecodeEngine(net, num_slots=4, max_len=MAX_LEN, max_prompt_len=16,
                        prefill_batch=2, page_tokens=8, speculate_k=2,
+                       prefix_cache=False, cache_dir=False)
+    eng.warmup()
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def engine_k1(net):
+    """One tick in flight: the wait reads the tick BEFORE the one just
+    dispatched, and a row's token is accounted at dispatch."""
+    eng = DecodeEngine(net, num_slots=4, max_len=MAX_LEN, max_prompt_len=16,
+                       prefill_batch=2, page_tokens=8, speculate_k=1,
                        prefix_cache=False, cache_dir=False)
     eng.warmup()
     yield eng
@@ -88,47 +108,107 @@ def _host_threads(trace_dir):
     return threads
 
 
-@pytest.fixture(scope="module")
-def recorded(engine, step, tmp_path_factory):
-    """One profiler session over three requests and three step calls, as
-    the benchmark opens it: host tracer on, no Python call tracing."""
+def _session(trace_dir, work):
+    """``work()`` under one profiler session as the benchmark opens it:
+    host tracer on, no Python call tracing. Returns (work's result, the
+    host threads)."""
     import jax
 
-    trace_dir = str(tmp_path_factory.mktemp("spans_trace"))
-    x, y = _batch()
-    step(x, y)                       # compiles outside the session
-    with span("test.before_the_session"):
-        pass
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 2
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     try:
-        streams = [engine.submit(p, max_new_tokens=5)
-                   for p in ([1, 2, 3], [4, 5, 6, 7, 8], [9, 10])]
-        tokens = [s.result(timeout=120) for s in streams]
-        losses = [float(step(x, y).asnumpy()) for _ in range(3)]
+        out = work()
     finally:
         jax.profiler.stop_trace()
-    threads = _host_threads(trace_dir)
-
-    def thread_of(name):
-        return max(threads, key=lambda t: sum(n == name for n, *_ in t))
-
-    return {"engine": thread_of("serve.tick.dispatch"),
-            "caller": thread_of("train.dispatch"), "threads": threads,
-            "streams": streams, "tokens": tokens, "losses": losses}
+    return out, _host_threads(trace_dir)
 
 
-@pytest.mark.parametrize("thread,family", [("engine", "serve."),
-                                           ("caller", "train.")])
-def test_the_two_loops_emit_only_names_of_the_inventory(recorded, thread,
-                                                       family):
-    names = {n for n, *_ in recorded[thread]}
+def _thread_of(threads, name):
+    return max(threads, key=lambda t: sum(n == name for n, *_ in t))
+
+
+def _serve(engine):
+    """Three requests through ``engine``; every compiled program notes the
+    length of the list it is really called with."""
+    handed = []
+    programs = engine.programs._programs
+
+    def noting(key, prog):
+        def call(*args):
+            handed.append((key[0], len(args)))
+            return prog(*args)
+        return call
+
+    real = dict(programs)
+    for key, prog in real.items():
+        programs[key] = noting(key, prog)
+    try:
+        ticks0 = engine.stats()["ticks"]
+        streams = [engine.submit(p, max_new_tokens=5) for p in PROMPTS]
+        tokens = [s.result(timeout=120) for s in streams]
+    finally:
+        programs.update(real)
+    return {"streams": streams, "tokens": tokens, "handed": handed,
+            "ticks": engine.stats()["ticks"] - ticks0}
+
+
+@pytest.fixture(scope="module")
+def recorded(engine, step, tmp_path_factory):
+    """One profiler session over three requests and three step calls."""
+    x, y = _batch()
+    step(x, y)                       # compiles outside the session
+    with span("test.before_the_session"):
+        pass
+
+    def work():
+        out = _serve(engine)
+        out["losses"] = [float(step(x, y).asnumpy()) for _ in range(3)]
+        return out
+
+    out, threads = _session(str(tmp_path_factory.mktemp("spans_trace")),
+                            work)
+    return dict(out, threads=threads,
+                engine=_thread_of(threads, "serve.tick.dispatch"),
+                caller=_thread_of(threads, "train.dispatch"))
+
+
+@pytest.fixture(scope="module")
+def recorded_k1(engine_k1, tmp_path_factory):
+    """The same three requests through the engine that keeps one tick in
+    flight, in a session of its own."""
+    out, threads = _session(str(tmp_path_factory.mktemp("spans_trace_k1")),
+                            lambda: _serve(engine_k1))
+    return dict(out, threads=threads,
+                engine=_thread_of(threads, "serve.tick.dispatch"))
+
+
+@pytest.fixture
+def served(request):
+    """(the recording, its engine's speculate_k) of either engine."""
+    k = request.param
+    return request.getfixturevalue("recorded" if k == 2
+                                   else "recorded_k1"), k
+
+
+BOTH_ENGINES = pytest.mark.parametrize("served", [2, 1], indirect=True,
+                                       ids=["k2", "k1_in_flight"])
+
+
+@pytest.mark.parametrize("recording,thread,family", [
+    ("recorded", "engine", "serve."), ("recorded", "caller", "train."),
+    ("recorded_k1", "engine", "serve.")])
+def test_the_two_loops_emit_only_names_of_the_inventory(request, recording,
+                                                       thread, family):
+    rec = request.getfixturevalue(recording)
+    names = {n for n, *_ in rec[thread]}
     assert names and names <= set(SPANS), names - set(SPANS)
     assert all(n.startswith(family) for n in names)
+    if family == "serve.":
+        assert set(TICK_CALL + PREFILL_CALL) <= names
     # nothing of the program is in the trace under another thread
-    everything = {n for t in recorded["threads"] for n, *_ in t}
+    everything = {n for t in rec["threads"] for n, *_ in t}
     assert everything <= set(SPANS), everything - set(SPANS)
 
 
@@ -142,18 +222,81 @@ def test_a_tick_is_these_spans_in_this_order(recorded):
     names = [n for n, *_ in recorded["engine"] if n in TICK_SPANS]
     assert len(names) >= 2 * len(TICK_SPANS)
     assert names == TICK_SPANS * (len(names) // len(TICK_SPANS))
-    for stage in ("serve.admit.prepare", "serve.prefill.host",
-                  "serve.prefill.dispatch", "serve.wait_prefill",
-                  "serve.prefill.commit", "serve.expire"):
+    for stage in ("serve.admit.prepare", "serve.wait_prefill",
+                  "serve.prefill.commit", "serve.expire", *PREFILL_CALL):
         assert any(n == stage for n, *_ in recorded["engine"]), stage
     assert all(len(t) == 5 for t in recorded["tokens"])
 
 
-@pytest.mark.parametrize("thread", ["engine", "caller"])
-def test_spans_of_one_thread_are_flat_leaves(recorded, thread):
+def test_a_tick_in_flight_is_read_after_the_next_went_out(recorded_k1):
+    """speculate_k == 1: no draft; a tick is staged, called and accounted,
+    and only then is the tick BEFORE it read back and committed. The last
+    tick of a busy period is read with nothing behind it."""
+    flight = [n for n in TICK_SPANS if n != "serve.tick.draft"]
+    call, read = flight[:4], flight[4:]
+    names = [n for n, *_ in recorded_k1["engine"] if n in TICK_SPANS]
+    ticks = []
+    for n in names:
+        if n == flight[0]:
+            ticks.append([])
+        ticks[-1].append(n)
+    assert len(ticks) == recorded_k1["ticks"] >= 5
+    assert ticks[0] == call          # nothing before it to read
+    for t in ticks[1:]:
+        assert t[:4] == call and t[4:] in (read, read * 2), t
+    assert names.count("serve.wait_tick") == len(ticks)
+    assert all(len(t) == 5 for t in recorded_k1["tokens"])
+
+
+@BOTH_ENGINES
+def test_a_program_call_is_staged_called_and_accounted(served):
+    """The six leaves tile what the two dispatch spans covered: around each
+    call of a program its stage and its account, with nothing between."""
+    rec, k = served
+    names = [n for n, *_ in rec["engine"]]
+    for first, order in (("serve.tick.stage", TICK_CALL),
+                         ("serve.prefill.host", PREFILL_CALL)):
+        at = [i for i, n in enumerate(names) if n == first]
+        assert at
+        for i in at:
+            assert names[i:i + len(order)] == order
+    # without a tick in flight a prefill is read at once; with one, only
+    # after the tick that follows it went out
+    after = [names[i + 1] for i, n in enumerate(names)
+             if n == "serve.prefill.account"]
+    reads = [names[i:i + 2] for i, n in enumerate(names)
+             if n == PREFILL_READ[0]]
+    assert reads and all(r == PREFILL_READ for r in reads)
+    if k == 1:
+        assert "serve.wait_prefill" not in after
+        for j in (i for i, n in enumerate(names)
+                  if n == "serve.wait_prefill"):
+            since = names[:j][::-1].index("serve.prefill.account")
+            assert "serve.tick.dispatch" in names[j - since:j]
+    else:
+        assert set(after) == {"serve.wait_prefill"}
+
+
+@BOTH_ENGINES
+def test_there_is_exactly_one_dispatch_span_a_tick(served):
+    rec, _ = served
+    names = [n for n, *_ in rec["engine"]]
+    assert rec["ticks"] >= 3
+    for leaf in TICK_CALL:
+        assert names.count(leaf) == rec["ticks"], leaf
+    prefills = names.count("serve.prefill.dispatch")
+    assert 2 <= prefills <= 3       # three prompts, two a prefill at most
+    assert names.count("serve.prefill.stage") == prefills
+    assert names.count("serve.prefill.account") == prefills
+
+
+@pytest.mark.parametrize("recording,thread", [
+    ("recorded", "engine"), ("recorded", "caller"),
+    ("recorded_k1", "engine")])
+def test_spans_of_one_thread_are_flat_leaves(request, recording, thread):
     """No span encloses or overlaps another: a span's duration is its self
     time."""
-    events = recorded[thread]
+    events = request.getfixturevalue(recording)[thread]
     for (_, _, end, _), (name, start, _, _) in zip(events, events[1:]):
         assert start >= end, name
 
@@ -169,16 +312,44 @@ def test_a_prefill_span_carries_the_ids_of_its_streams(recorded):
         assert st["queue_wait_ms_max"] >= 0.0
 
 
-def test_attributes_known_inside_a_span_reach_the_event(recorded):
+def _stats_by_name(events):
     by_name = {}
-    for n, _, _, st in recorded["engine"]:
+    for n, _, _, st in events:
         by_name.setdefault(n, []).append(st)
+    return by_name
+
+
+def test_attributes_known_inside_a_span_reach_the_event(recorded):
+    by_name = _stats_by_name(recorded["engine"])
     assert sum(st["tokens"] for st in by_name["serve.tick.commit"]) >= 12
     assert all(st["live"] >= 1 for st in by_name["serve.tick.dispatch"])
     assert all("starved" in st for st in by_name["serve.admit.prepare"])
     taken = sum(st["n"] for n in ("serve.gather", "serve.wait_queue")
                 for st in by_name.get(n, []))
     assert taken == 3
+
+
+@BOTH_ENGINES
+def test_the_leaves_carry_the_attributes_of_the_inventory(served):
+    """``operands`` is the length of the list the executable was really
+    called with; ``ended`` the requests the account ended (with a draft
+    the commit decides that, after the read-back)."""
+    rec, k = served
+    by_name = _stats_by_name(rec["engine"])
+    for kind, leaf in (("decode", "serve.tick.dispatch"),
+                       ("prefill", "serve.prefill.dispatch")):
+        handed = [n for key, n in rec["handed"] if key == kind]
+        assert [st["operands"] for st in by_name[leaf]] == handed
+        assert min(handed) > 20      # the parameter tail is most of it
+    for leaf in TICK_CALL:
+        assert all(1 <= st["live"] <= 3 for st in by_name[leaf]
+                   if leaf != "serve.tick.account"), leaf
+    ended = [st["ended"] for st in by_name["serve.tick.account"]]
+    assert sum(ended) == (len(PROMPTS) if k == 1 else 0)
+    for leaf in PREFILL_CALL[1:]:
+        assert all(st["batch"] in (1, 2) for st in by_name[leaf]), leaf
+    for leaf in PREFILL_CALL[1:3]:
+        assert all(st["length"] >= 8 for st in by_name[leaf]), leaf
 
 
 def test_without_a_session_a_span_leaves_no_event_and_costs_little(recorded):
@@ -196,7 +367,10 @@ def test_without_a_session_a_span_leaves_no_event_and_costs_little(recorded):
     assert tm.timer("test.cost").count == 0   # telemetry is off
 
 
-def test_with_telemetry_on_the_old_timer_names_still_fill(engine, step):
+@pytest.mark.parametrize("which", ["engine", "engine_k1"])
+def test_with_telemetry_on_the_old_timer_names_still_fill(request, which,
+                                                          step):
+    engine = request.getfixturevalue(which)
     x, y = _batch(1)
     tm.enable()
     ticks0 = engine.stats()["ticks"]
@@ -210,6 +384,11 @@ def test_with_telemetry_on_the_old_timer_names_still_fill(engine, step):
     assert tm.timer("train_step.call").count == 2
     assert tm.timer("serve.decode_tick.call").total >= \
         tm.timer("serve.tick.dispatch").total
+    # the leaves around the call are timers of their own names
+    for leaf in TICK_CALL:
+        assert tm.timer(leaf).count == ticks, leaf
+    for leaf in PREFILL_CALL:
+        assert tm.timer(leaf).count == 1, leaf
     rows = tm.step_report()
     assert len(rows) == 2
     host = rows[-1]["host_time"]
@@ -220,7 +399,8 @@ def test_with_telemetry_on_the_old_timer_names_still_fill(engine, step):
     assert "train.dispatch" not in host
     spans = [e for e in tm.events() if e.get("kind") == "span"]
     assert {"train_step.call", "serve.decode_tick.call",
-            "serve.tick.commit"} <= {e["name"] for e in spans}
+            "serve.tick.commit", "serve.tick.stage",
+            "serve.tick.account"} <= {e["name"] for e in spans}
 
 
 def test_program_timer_tells_a_compile_from_a_call():
