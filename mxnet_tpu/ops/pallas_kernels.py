@@ -1706,7 +1706,10 @@ def _mla_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, *rest, num_pages,
     ``page_refs``: that many (R, P) pages of one layer; ``o_ref`` (K*H,
     value_dim). Online softmax in float32; ``m_ref``, ``l_ref`` (K*H, 1),
     ``acc_ref`` (K*H, value_dim). Scores ``q @ page`` and sums ``p @
-    page[:value_dim]^T`` run on the matrix unit in the pool's type."""
+    page[:value_dim]^T`` run on the matrix unit in the pool's type. Only
+    the slot's last live page can hold positions past the newest one, so
+    only it is zeroed there; every other page goes to both products
+    straight from its block."""
     del lay_ref                       # the index maps read it
     page_refs = rest[:pages_a_step]
     o_ref, acc_ref, m_ref, l_ref = rest[pages_a_step:]
@@ -1723,30 +1726,39 @@ def _mla_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, *rest, num_pages,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def page_body(j, page):                                       # (R, P)
+        kpos = j * p + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+        s = jnp.dot(q_ref[...], page,
+                    preferred_element_type=jnp.float32) * scale
+        qk = jax.lax.broadcasted_iota(jnp.int32, (kh, 1), 0) // heads
+        ok = kpos <= pos + qk                                     # (KH, P)
+        s = jnp.where(ok, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            pr.astype(page.dtype), page[:value_dim],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
     for i, page_ref in enumerate(page_refs):
         j = jg * pages_a_step + i
+        live = (j < w) & (j * p <= last) \
+            & (tab_ref[si, jnp.minimum(j, w - 1)] < num_pages)
+        # only the slot's last live page holds positions past its length
+        edge = j * p + p - 1 > last
 
-        @pl.when((j < w) & (j * p <= last)
-                 & (tab_ref[si, jnp.minimum(j, w - 1)] < num_pages))
+        @pl.when(live & jnp.logical_not(edge))
         def _page(j=j, page_ref=page_ref):
+            page_body(j, page_ref[...])
+
+        @pl.when(live & edge)
+        def _edge_page(j=j, page_ref=page_ref):
             kpos = j * p + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
             # past the slot's length a page may hold anything: 0 x NaN
-            page = jnp.where(kpos <= last, page_ref[...], 0)      # (R, P)
-            s = jnp.dot(q_ref[...], page,
-                        preferred_element_type=jnp.float32) * scale
-            qk = jax.lax.broadcasted_iota(jnp.int32, (kh, 1), 0) // heads
-            ok = kpos <= pos + qk                                 # (KH, P)
-            s = jnp.where(ok, s, _NEG_INF)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            pr = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1,
-                                                      keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                pr.astype(page.dtype), page[:value_dim],
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+            page_body(j, jnp.where(kpos <= last, page_ref[...], 0))
 
     @pl.when(jg == pl.num_programs(1) - 1)
     def _finish():

@@ -444,44 +444,80 @@ def test_the_16_shares_of_a_layer_add_up_to_the_whole():
 
 
 # -- the kernels, interpreted -------------------------------------------------
-def _latent_case(seed, s=3, k=1, h=4, r=192, v=128, p=128, w=3, dtype="float32"):
+def _latent_case(seed, k=1, h=4, r=192, v=128, p=128, w=3, dtype="float32",
+                 lengths=(300, 130, 5)):
+    """Slot i's live pages are its own block of ``w``, slot 0's out of
+    order; page ``len(lengths) * w`` belongs to no slot."""
     rng = onp.random.RandomState(seed)
+    s = len(lengths)
     pages = s * w
     pool = jnp.asarray(rng.randn(pages + 1, 2, 1, r, p) * 0.5, dtype)
     q = jnp.asarray(rng.randn(s, k, h, r) * 0.5, dtype)
     table = onp.full((s, w + 1), pages + 1, "int32")
-    lengths = [300, 130, 5][:s]
     for i, n in enumerate(lengths):
         live = -(-(n + k) // p)
-        table[i, :live] = rng.permutation(pages)[:live] if i == 0 \
+        table[i, :live] = rng.permutation(w)[:live] if i == 0 \
             else onp.arange(i * w, i * w + live)
     return q, pool, jnp.asarray(table), jnp.asarray(lengths, jnp.int32), v
 
 
-@pytest.mark.parametrize("dtype, k", [("float32", 1), ("bfloat16", 1),
-                                      ("float32", 2)])
-def test_the_absorbed_decode_kernel_equals_the_plain_body(dtype, k,
-                                                          monkeypatch):
+ABSORBED = {
+    # id: keywords of _latent_case, and whether every column past a slot's
+    # last live page maps the spare page, full of NaN
+    "float32-1": (dict(dtype="float32", k=1), False),
+    "bfloat16-1": (dict(dtype="bfloat16", k=1), False),
+    "float32-2": (dict(dtype="float32", k=2), False),
+    # length + K ends on a page: no page holds a position past it
+    "ends_on_a_page": (dict(k=1, lengths=(255, 127, 383)), False),
+    # slot 0 fills its row but its last position; slot 1 fills all of it
+    "every_column_live": (dict(k=1, lengths=(382, 383, 20)), False),
+    # queries at 127 | 128 and 255 | 256: the newest opens a page of one row
+    "k2_opens_a_page": (dict(k=2, lengths=(127, 255, 30)), False),
+    "nan_past_the_length": (dict(k=1, lengths=(300, 130, 5)), True),
+    "bfloat16_cell_widths": (dict(dtype="bfloat16", k=1, h=64, r=576,
+                                  v=512, w=6, lengths=(700, 130, 5)), False),
+}
+
+
+@pytest.mark.parametrize("case", list(ABSORBED))
+def test_the_absorbed_decode_kernel_equals_the_plain_body(case, monkeypatch):
     """``mxtpu_mla_decode`` interpreted, against the gather + softmax of the
-    same numbers: pages out of order, a slot inside its first page, an
-    unmapped tail, NaN past a slot's length."""
-    q, pool, table, pos, v = _latent_case(0, k=k, dtype=dtype)
-    pool = pool.at[int(table[2, 0]), :, :, :, 5 + k:].set(jnp.nan)
+    same numbers and against the definition, every slot and query: pages
+    out of order, a slot inside its first page, an unmapped tail, NaN past
+    each slot's length in its last live page."""
+    kw, nan_tail = ABSORBED[case]
+    k, dtype = kw["k"], kw.get("dtype", "float32")
+    q, pool, table, pos, v = _latent_case(0, **kw)
+    s, _, h, _ = q.shape
+    p, spare = pool.shape[-1], pool.shape[0] - 1
+    live = [-(-(int(n) + k) // p) for n in pos]
+    for i, n in enumerate(pos):
+        if (int(n) + k) % p:
+            pool = pool.at[int(table[i, live[i] - 1]), :, :, :,
+                           (int(n) + k) % p:].set(jnp.nan)
+    if nan_tail:
+        pool = pool.at[spare].set(jnp.nan)
+        table = jnp.where(jnp.arange(table.shape[1])[None, :]
+                          < jnp.asarray(live)[:, None], table, spare)
+        table = table.at[:, -1].set(spare + 1)
     want = pk._mla_decode_reference(q, pool, jnp.int32(1), table, pos, v, 0.2)
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     got = pk.mla_decode_attention(q, pool, 1, table, pos, v, 0.2)
-    assert got.shape == (3, k, 4, 128) and got.dtype == q.dtype
+    assert got.shape == (s, k, h, v) and got.dtype == q.dtype
     tol = 2e-2 if dtype == "bfloat16" else 2e-5
     assert not bool(jnp.isnan(got.astype(jnp.float32)).any())
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                  - want.astype(jnp.float32)))) < tol
-    # against the definition, slot 1: softmax over its 130 + k positions
-    rows = jnp.concatenate([pool[int(t), 1, 0].T for t in table[1, :2]])
-    rows = rows[:130 + k].astype(jnp.float32)
-    s = (q[1, k - 1].astype(jnp.float32) @ rows.T) * 0.2
-    direct = jax.nn.softmax(s, axis=-1) @ rows[:, :128]
-    assert float(jnp.max(jnp.abs(got[1, k - 1].astype(jnp.float32)
-                                 - direct))) < 5 * tol
+    # against the definition: query kk of slot i sees positions <= n + kk
+    for i, n in enumerate(pos):
+        rows = jnp.concatenate([pool[int(t), 1, 0].T
+                                for t in table[i, :live[i]]])
+        for kk in range(k):
+            seen = rows[:int(n) + kk + 1].astype(jnp.float32)
+            sc = (q[i, kk].astype(jnp.float32) @ seen.T) * 0.2
+            direct = jax.nn.softmax(sc, axis=-1) @ seen[:, :v]
+            assert float(jnp.max(jnp.abs(got[i, kk].astype(jnp.float32)
+                                         - direct))) < 5 * tol
 
 
 def test_a_slot_with_no_page_attends_nothing(monkeypatch):
