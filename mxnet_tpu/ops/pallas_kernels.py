@@ -1699,74 +1699,108 @@ def _mla_decode_reference(q, pool, layer, page_table, positions, value_dim,
     return out.astype(q.dtype)
 
 
-def _mla_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, *rest, num_pages,
-                       heads, value_dim, scale, pages_a_step):
-    """Grid (slot, group of ``pages_a_step`` logical pages). ``q_ref``
-    (K*H, R): the slot's absorbed queries, query k's heads in rows k*H ..;
-    ``page_refs``: that many (R, P) pages of one layer; ``o_ref`` (K*H,
-    value_dim). Online softmax in float32; ``m_ref``, ``l_ref`` (K*H, 1),
-    ``acc_ref`` (K*H, value_dim). Scores ``q @ page`` and sums ``p @
-    page[:value_dim]^T`` run on the matrix unit in the pool's type. Only
-    the slot's last live page can hold positions past the newest one, so
-    only it is zeroed there; every other page goes to both products
-    straight from its block."""
-    del lay_ref                       # the index maps read it
-    page_refs = rest[:pages_a_step]
-    o_ref, acc_ref, m_ref, l_ref = rest[pages_a_step:]
-    si, jg = pl.program_id(0), pl.program_id(1)
-    kh = q_ref.shape[0]
-    p = page_refs[0].shape[-1]
-    pos = pos_ref[si]
-    last = pos + kh // heads - 1      # the newest position any query sees
+def _mla_decode_kernel(tab_ref, pos_ref, lay_ref, q_ref, pool_ref, o_ref,
+                       buf_ref, sem, half_ref, *, num_pages, heads, value_dim,
+                       scale, pages_a_step):
+    """Grid (slot,). ``q_ref`` (K*H, R): the slot's absorbed queries, query
+    k's heads in rows k*H ..; ``pool_ref`` the whole pool where it lies;
+    ``o_ref`` (K*H, value_dim). A loop over the slot's live pages only, in
+    groups of ``pages_a_step``: each group is copied into one half of
+    ``buf_ref`` (2, n, R, P) while the group before it is weighed from the
+    other, and a slot's last group starts the next slot's first;
+    ``half_ref`` (1,) holds the half the next group to weigh lies in.
+    Online softmax in float32, updated once a group. Scores ``q @ page``
+    and sums ``p @ page[:value_dim]^T`` run on the matrix unit in the
+    pool's type."""
+    si, slots = pl.program_id(0), pl.num_programs(0)
+    n = pages_a_step
+    kh, p = q_ref.shape[0], buf_ref.shape[-1]
+    kq = kh // heads
     w = tab_ref.shape[1] - 1
 
-    @pl.when(jg == 0)
-    def _start():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def live_pages(s):              # the pages holding positions <= newest
+        return jnp.minimum((pos_ref[s] + kq - 1) // p + 1, w)
 
-    def page_body(j, page):                                       # (R, P)
-        kpos = j * p + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
-        s = jnp.dot(q_ref[...], page,
-                    preferred_element_type=jnp.float32) * scale
-        qk = jax.lax.broadcasted_iota(jnp.int32, (kh, 1), 0) // heads
-        ok = kpos <= pos + qk                                     # (KH, P)
-        s = jnp.where(ok, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    def copies(s, g, half):
+        """(mapped, copy) for each page of group ``g`` of slot ``s``."""
+        out = []
+        for i in range(n):
+            j = g * n + i
+            page = tab_ref[s, jnp.minimum(j, w - 1)]
+            out.append(((j < live_pages(s)) & (page < num_pages),
+                        pltpu.make_async_copy(
+                            pool_ref.at[jnp.minimum(page, num_pages - 1),
+                                        lay_ref[0], 0],
+                            buf_ref.at[half, i], sem.at[half, i])))
+        return out
+
+    def start(s, g, half):
+        for mapped, copy in copies(s, g, half):
+            pl.when(mapped)(copy.start)
+
+    @pl.when(si == 0)
+    def _first():
+        half_ref[0] = 0
+        start(0, 0, 0)
+
+    pos = pos_ref[si]
+    last = pos + kq - 1               # the newest position any query sees
+    # at least one group, even where nothing is live: its last group hands
+    # the copies on to the next slot
+    groups = jnp.maximum((live_pages(si) + n - 1) // n, 1)
+    qk = jax.lax.broadcasted_iota(jnp.int32, (kh, 1), 0) // heads
+
+    def weigh(g, carry):
+        m_prev, l_prev, acc = carry
+        half = half_ref[0]
+
+        @pl.when(g + 1 < groups)
+        def _next_group():
+            start(si, g + 1, 1 - half)
+
+        @pl.when((g + 1 == groups) & (si + 1 < slots))
+        def _next_slot():
+            start(si + 1, 0, 1 - half)
+
+        oks, ss, pages = [], [], []
+        for i, (mapped, copy) in enumerate(copies(si, g, half)):
+            pl.when(mapped)(copy.wait)
+            kpos = (g * n + i) * p \
+                + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+            # the newest position this page may show; -1 if it shows none
+            seen = jnp.where(mapped, last, -1)
+            # past the slot's length, or in a page not copied, a buffer may
+            # hold anything: 0 x NaN
+            page = jnp.where(kpos <= seen, buf_ref[half, i], 0)   # (R, P)
+            ok = kpos <= jnp.minimum(pos + qk, seen)              # (KH, P)
+            s = jnp.dot(q_ref[...], page,
+                        preferred_element_type=jnp.float32) * scale
+            oks.append(ok)
+            ss.append(jnp.where(ok, s, _NEG_INF))
+            pages.append(page)
+        m_new = functools.reduce(
+            jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in ss],
+            m_prev)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pr.astype(page.dtype), page[:value_dim],
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        l_new, acc = l_prev * alpha, acc * alpha
+        for ok, s, page in zip(oks, ss, pages):
+            pr = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            l_new = l_new + jnp.sum(pr, axis=-1, keepdims=True)
+            acc = acc + jax.lax.dot_general(
+                pr.astype(page.dtype), page[:value_dim],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        half_ref[0] = 1 - half
+        return m_new, l_new, acc
 
-    for i, page_ref in enumerate(page_refs):
-        j = jg * pages_a_step + i
-        live = (j < w) & (j * p <= last) \
-            & (tab_ref[si, jnp.minimum(j, w - 1)] < num_pages)
-        # only the slot's last live page holds positions past its length
-        edge = j * p + p - 1 > last
-
-        @pl.when(live & jnp.logical_not(edge))
-        def _page(j=j, page_ref=page_ref):
-            page_body(j, page_ref[...])
-
-        @pl.when(live & edge)
-        def _edge_page(j=j, page_ref=page_ref):
-            kpos = j * p + jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
-            # past the slot's length a page may hold anything: 0 x NaN
-            page_body(j, jnp.where(kpos <= last, page_ref[...], 0))
-
-    @pl.when(jg == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)) \
-            .astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, groups, weigh,
+        (jnp.full((kh, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((kh, 1), jnp.float32),
+         jnp.zeros((kh, value_dim), jnp.float32)))
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-_MLA_PAGES_A_STEP = 4   # a grid step costs about what a page's bytes cost
+_MLA_PAGES_A_STEP = 4   # pages weighed a softmax update
 
 
 def _mla_decode_tpu(q, pool, layer, page_table, positions, value_dim, scale):
@@ -1775,17 +1809,7 @@ def _mla_decode_tpu(q, pool, layer, page_table, positions, value_dim, scale):
     w = page_table.shape[1] - 1
     n = min(_MLA_PAGES_A_STEP, w)
 
-    def page(i):
-        def index(si, jg, tab, pos, lay):
-            # past the slot's last live page the block index repeats, and a
-            # repeated index moves no bytes; the sentinel id (one past the
-            # pool) clamps into it
-            last = jnp.clip((pos[si] + kq - 1) // p, 0, w - 1)
-            return (jnp.minimum(tab[si, jnp.minimum(jg * n + i, last)],
-                                num_pages - 1), lay[0], 0, 0, 0)
-        return index
-
-    def slot(si, jg, tab, pos, lay):
+    def slot(si, tab, pos, lay):
         return (si, 0, 0)
 
     itemsize = pool.dtype.itemsize
@@ -1795,16 +1819,18 @@ def _mla_decode_tpu(q, pool, layer, page_table, positions, value_dim, scale):
         name="mxtpu_mla_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(s, -(-w // n)),
-            in_specs=[pl.BlockSpec((None, kq * h, r), slot)]
-            + [pl.BlockSpec((None, None, None, r, p), page(i))
-               for i in range(n)],
+            grid=(s,),
+            in_specs=[pl.BlockSpec((None, kq * h, r), slot),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, kq * h, value_dim), slot),
             scratch_shapes=[
-                pltpu.VMEM((kq * h, value_dim), jnp.float32),    # acc
-                pltpu.VMEM((kq * h, 1), jnp.float32),            # m
-                pltpu.VMEM((kq * h, 1), jnp.float32),            # l
+                pltpu.VMEM((2, n, r, p), pool.dtype),            # pages
+                pltpu.SemaphoreType.DMA((2, n)),
+                pltpu.SMEM((1,), jnp.int32),                     # half
             ]),
+        # one slot after another: a slot's last group starts the next's copy
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         out_shape=jax.ShapeDtypeStruct((s, kq * h, value_dim), q.dtype),
         cost_estimate=pl.CostEstimate(
             flops=2 * s * kq * h * (r + value_dim) * w * p,
@@ -1812,5 +1838,5 @@ def _mla_decode_tpu(q, pool, layer, page_table, positions, value_dim, scale):
             transcendentals=s * kq * h * w * p),
         interpret=_interpret(),
     )(page_table, positions, layer.reshape(1),
-      q.reshape(s, kq * h, r).astype(pool.dtype), pool, *([pool] * (n - 1)))
+      q.reshape(s, kq * h, r).astype(pool.dtype), pool)
     return out.reshape(s, kq, h, value_dim)

@@ -476,6 +476,12 @@ ABSORBED = {
     "nan_past_the_length": (dict(k=1, lengths=(300, 130, 5)), True),
     "bfloat16_cell_widths": (dict(dtype="bfloat16", k=1, h=64, r=576,
                                   v=512, w=6, lengths=(700, 130, 5)), False),
+    # 3, 1, 2 and 1 groups of four pages: the copies alternate halves of
+    # the buffer across groups and slots
+    "groups_across_slots": (dict(k=1, w=9, lengths=(1100, 130, 600, 5)),
+                            True),
+    "k2_groups_across_slots": (dict(k=2, w=9, lengths=(1023, 0, 510, 1150)),
+                               False),
 }
 
 
