@@ -1,7 +1,7 @@
 """Operations and bytes of A.X-K1 serving, counted from its shapes: the
-numerators of ``mfu_active.serve_mla``, ``tick_hbm_share.serve_mla`` and
-``mla_decode_roofline.serve_mla``, and what the other readers of this
-configuration need to know of the run.
+numerators of ``mfu_active.serve``, ``tick_hbm_share.serve`` and
+``mla_decode_roofline.serve_mla`` in this configuration's cell, and what the
+other readers of this configuration need to know of the run.
 
 As ``granite_hybrid_cost``: the count is by part. Every matrix a token
 multiplies outside the routed experts (latent attention's five, the dense
@@ -13,10 +13,13 @@ value of ``v_head_dim`` a head: what the absorbed form multiplies more, 576 +
 512 a head, is the program's choice and not counted). 2 flops a multiply-add,
 forward only.
 """
+import sys
+
+from chipbench import task_cost
 from chipbench.granite_hybrid_cost import stats_window, window_means
 
 __all__ = ["note_run", "last_run", "stats_window", "window_means",
-           "attention_params", "matrix_params", "expert_params",
+           "attention_params", "matrix_params", "expert_params", "n_routed",
            "window_flops", "tick_bytes", "latent_row", "mla_decode_floor_s",
            "kernel_seconds"]
 
@@ -30,6 +33,7 @@ def note_run(cfg, experts_held, itemsize, net=None):
     is held so that it outlives the runner."""
     _LAST.update(cfg=dict(cfg), experts_held=tuple(experts_held),
                  itemsize=int(itemsize), net=net)
+    task_cost.note(sys.modules[__name__])
 
 
 def last_run():
@@ -97,14 +101,17 @@ def window_flops(cfg, tokens, pairs_here, prompt_tokens, prefills,
     return matrices + experts + attention
 
 
-def tick_bytes(cfg, itemsize, experts_touched, pages_live, page_tokens):
+def tick_bytes(cfg, itemsize, experts_touched, pages_live, page_tokens,
+               live_slots=0):
     """Bytes ONE tick cannot avoid moving: the weights outside the routed
     experts once (the head's slice with them), the held experts that got a
     token (``experts_touched``: summed over the routed layers, from the
     counter), the live latent rows read once a layer. Activations, the
     router's float32 copies, the rows written and whatever the compiler adds
     are NOT counted: the share of the memory's rate this gives cannot pass
-    100."""
+    100. ``live_slots`` is the shared reader's argument
+    (``chipbench/task_cost.py``): a latent cache keeps no state a slot
+    beside its pages, so it adds nothing."""
     weights = (matrix_params(cfg) + expert_params(cfg) * experts_touched) \
         * itemsize
     rows = pages_live * page_tokens * latent_row(cfg)[0] * itemsize \

@@ -1,6 +1,7 @@
 """Operations and bytes of Granite-4.0-H serving, counted from its shapes:
-the numerators of ``mfu_active.serve`` and ``tick_hbm_share.serve``, and what
-the other readers of this configuration need to know of the run.
+the numerators of ``mfu_active.serve`` and ``tick_hbm_share.serve`` in this
+configuration's cell, and what the other readers of this configuration need
+to know of the run.
 
 ``2 x parameters x tokens`` does not fit a routed model: a token multiplies
 only the experts it was routed to, and of those only the ones held here. So
@@ -12,6 +13,9 @@ multiply-adds a state element and token: decay, the outer product's add, the
 contraction with C; the chunked form's extra products are the program's, not
 the model's), and causal attention. 2 flops a multiply-add, forward only.
 """
+import sys
+
+from chipbench import task_cost
 
 _LAST = {}
 _STATE_MADDS = 3      # a state element and token: decay, add, contract
@@ -23,6 +27,7 @@ def note_run(cfg, experts_held, itemsize, net=None):
     is held so that it outlives the runner."""
     _LAST.update(cfg=dict(cfg), experts_held=tuple(experts_held),
                  itemsize=int(itemsize), net=net)
+    task_cost.note(sys.modules[__name__])
 
 
 def last_run():
@@ -61,6 +66,11 @@ def state_elements(cfg):
 
 def n_layers(cfg, kind):
     return sum(k == kind for k in cfg["layer_types"])
+
+
+def n_routed(cfg):
+    """Every layer held routes its tokens."""
+    return len(cfg["layer_types"])
 
 
 def window_flops(cfg, tokens, pairs_here, prompt_tokens, prefills,

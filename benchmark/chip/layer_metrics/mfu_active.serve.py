@@ -1,16 +1,18 @@
-"""The window's model flops (``chipbench/granite_hybrid_cost.py``: every
-matrix a token multiplies outside the routed experts, the head's slice
-included; the token-expert pairs computed here, from the program's counter;
-the state-space recurrence; causal attention; prompt tokens and generated
-tokens both) over the window's seconds and the device's bf16 peak: an
-end-to-end utilisation, the share of the whole serving step's peak. None
-where the program keeps no such counters or the task noted no run."""
-from chipbench import granite_hybrid_cost as cost, peaks
+"""The window's model flops, counted by the cost module of the cell's task
+(``chipbench/task_cost.py``: every matrix a token multiplies outside the
+routed experts, the head's slice included; the token-expert pairs computed
+here, from the program's counter; the recurrence or attention at the model's
+own widths; prompt tokens and generated tokens both) over the window's
+seconds and the device's bf16 peak: an end-to-end utilisation, the share of
+the whole serving step's peak. None where the program keeps no such counters
+or no task noted a run."""
+from chipbench import peaks, task_cost
 
 
 def read(obs):
-    run, window = cost.last_run(), cost.stats_window(obs)
-    if not run or not window or "moe_pairs_here" not in window[1]:
+    cost, run = task_cost.noted()
+    window = cost.stats_window(obs) if run else None
+    if not window or "moe_pairs_here" not in window[1]:
         return None
     first, last = window
     delta = {k: last[k] - first[k] for k in (

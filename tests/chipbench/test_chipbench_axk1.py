@@ -18,10 +18,12 @@ from chipbench_paths import BENCH
 
 CELL = "axk1-tiny.decode-tiny-64"
 REAL = "A.X-K1.decode-saturated-64"
-NEW_METRICS = ("mfu_active.serve_mla", "tick_hbm_share.serve_mla",
-               "attn_time_share.serve_mla", "mla_decode_roofline.serve_mla",
+# the metrics this cell brought, and the shared serving metrics whose
+# readers count by the cost module of the cell's task or by its scopes
+NEW_METRICS = ("mfu_active.serve", "tick_hbm_share.serve",
+               "attn_time_share.serve", "mla_decode_roofline.serve_mla",
                "cache_bytes_per_token.serve_mla",
-               "experts_time_share.serve_mla")
+               "experts_time_share.serve")
 # the accepted serving metrics whose readers know no model and always find
 # something to read in a traced run of this cell
 SHARED_METRICS = (
@@ -127,10 +129,9 @@ def test_the_real_cell_is_in_the_manifest_as_the_issue_gives_it(manifest):
     cell = harness.find_cell(manifest, REAL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "A.X-K1", "decode-saturated-64", 1)
-    assert manifest["configs"][-1]["name"] == "A.X-K1"
-    assert manifest["configs"][-1]["source"] == \
+    configs = {c["name"]: c for c in manifest["configs"]}
+    assert configs["A.X-K1"]["source"] == \
         "https://huggingface.co/skt/A.X-K1/blob/main/config.json"
-    assert manifest["workloads"][-1] == cell
     mix = harness.resolve(manifest, REAL, BENCH)["traffic"]
     assert mix["runner"] == "serve_decode" and mix["clients"] == 64
     assert mix["engine"] == {
@@ -149,17 +150,19 @@ def test_the_real_cell_is_in_the_manifest_as_the_issue_gives_it(manifest):
     from mxnet_tpu.serve.bucketing import bucket_ladder
 
     assert bucket_ladder(2048, min_bucket=256) == [256, 512, 1024, 2048]
+    # looked up by name: later cells and metrics are appended, and a later
+    # metric may list this cell too
     listed = {m["name"] for m in manifest["per_layer"]
               if REAL in m.get("workloads", ())}
-    assert listed == set(NEW_METRICS) | set(SHARED_METRICS)
+    assert set(NEW_METRICS) | set(SHARED_METRICS) <= listed
     for m in manifest["end_to_end"]:
         if m["name"] in ("serve_tokens_per_s",):
-            assert m["workloads"][-1] == REAL
+            assert REAL in m["workloads"]
         if m["name"] in ("itl_ms_p95", "train_tokens_per_s"):
             assert REAL not in m["workloads"]
     for m in manifest["per_layer"]:
         if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [REAL]
+            assert REAL in m["workloads"]
             assert m["moves"] == "serve_tokens_per_s"
             assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
                                                m["name"] + ".py"))
@@ -418,10 +421,10 @@ def test_every_new_reader_returns_a_number(run, monkeypatch):
               for name in NEW_METRICS}
     assert all(isinstance(v, float) and v > 0 for v in values.values()), \
         values
-    assert values["attn_time_share.serve_mla"] \
-        + values["experts_time_share.serve_mla"] < 100.0
-    assert values["mfu_active.serve_mla"] < 100.0
-    assert values["tick_hbm_share.serve_mla"] < 100.0
+    assert values["attn_time_share.serve"] \
+        + values["experts_time_share.serve"] < 100.0
+    assert values["mfu_active.serve"] < 100.0
+    assert values["tick_hbm_share.serve"] < 100.0
     # three layers of one 24-wide float32 row a position, one pool
     assert values["cache_bytes_per_token.serve_mla"] == 3 * 24 * 4
     # the kernel's floor: the live pages' rows once a layer over the HBM
@@ -442,7 +445,7 @@ def test_the_roofline_reader_finds_nothing_without_the_kernel(run,
     traced = _stand_in_trace(obs, monkeypatch, kernel=False)
     assert found["readers"]["mla_decode_roofline.serve_mla"] \
         .read(traced) is None
-    assert found["readers"]["attn_time_share.serve_mla"].read(traced) > 0
+    assert found["readers"]["attn_time_share.serve"].read(traced) > 0
 
 
 def test_the_new_readers_return_nothing_on_a_program_without_the_counters(

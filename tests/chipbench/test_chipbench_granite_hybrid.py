@@ -19,6 +19,14 @@ REAL = "granite-4.0-h-small.decode-saturated-32"
 NEW_METRICS = ("mfu_active.serve", "tick_hbm_share.serve",
                "ssm_time_share.serve", "experts_time_share.serve",
                "held_experts_touched_share.serve")
+# the serving metrics of other layers that list the cell
+SHARED_METRICS = (
+    "slot_occupancy.serve", "kv_pages_live_share.serve",
+    "tick_device_ms.serve", "warmup_s.serve", "pallas_time_share.serve",
+    "device_idle_share.serve", "peak_hbm_gib.serve",
+    "engine_host_ms_per_tick.serve", "engine_stage_ms_per_tick.serve",
+    "engine_launch_ms_per_tick.serve", "engine_account_ms_per_tick.serve",
+    "launch_exposed_ms.serve", "readback_ms.serve")
 
 
 def _real_config():
@@ -134,18 +142,17 @@ def test_the_real_cell_is_in_the_manifest_as_the_issue_gives_it(manifest):
     listed = {m["name"] for m in manifest["per_layer"]
               if REAL in m.get("workloads", ())}
     assert set(NEW_METRICS) <= listed
-    # every serving metric the benchmark had lists the cell too, but the
-    # tail and the one metric that moves it: at saturation ``itl_ms_p95``
-    # (a tick plus a 1024-token prefill) spread 1.7% over six seeds on the
-    # chip, past half its bound, so the cell does not report it
+    # the serving metrics the benchmark had list the cell too, looked up by
+    # name (a metric a later configuration adds asks nothing of this cell),
+    # but the tail and the one metric that moves it: at saturation
+    # ``itl_ms_p95`` (a tick plus a 1024-token prefill) spread 1.7% over six
+    # seeds on the chip, past half its bound, so the cell does not report it
     # (CHANGES.md, PR 34); nor ``idle_attributed_share.serve``, whose
     # reader finds no idle gap to split where a tick is always in flight
     # behind a 23 ms one (the driver's traced run read none, busy 3.0005 of
     # 3.0035 s) and then leaves the metric out of the line
-    serving = {m["name"] for m in manifest["per_layer"]
-               if m["name"].endswith(".serve")}
-    assert serving - listed == {"prefill_share.serve",
-                                "idle_attributed_share.serve"}
+    assert set(SHARED_METRICS) <= listed
+    assert not listed & {"prefill_share.serve", "idle_attributed_share.serve"}
     for m in manifest["end_to_end"]:
         if m["name"] == "serve_tokens_per_s":
             assert REAL in m["workloads"]
@@ -154,10 +161,12 @@ def test_the_real_cell_is_in_the_manifest_as_the_issue_gives_it(manifest):
     moved = {m["moves"] for m in manifest["per_layer"]
              if REAL in m.get("workloads", ())}
     assert moved == {"serve_tokens_per_s", "setup_s"}
-    # each new metric moves what the cell reports, and lists this cell only
+    # each new metric moves what the cell reports and lists this cell; other
+    # configurations' cells may list it too (the readers count by the cost
+    # module of the cell's task, ``chipbench/task_cost.py``)
     for m in manifest["per_layer"]:
         if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [REAL]
+            assert REAL in m["workloads"]
             assert m["moves"] == "serve_tokens_per_s"
 
 
